@@ -163,8 +163,8 @@ def parse_args(argv=None):
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.device:
-        from benchmarks._env import ensure_device_or_cpu
-        ensure_device_or_cpu()
+        from benchmarks._env import require_tpu
+        require_tpu()
     result = asyncio.run(run_bench(args))
     if args.json:
         print(json.dumps(result))

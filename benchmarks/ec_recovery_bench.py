@@ -14,8 +14,8 @@ Phases (all timed separately, MB/s of logical stripe data):
               recovered chains (the resync-with-decode path)
 
     python -m benchmarks.ec_recovery_bench --stripes 24 --json
-    (--device runs RS on the accelerator; default numpy keeps the bench
-     honest on machines where the chip is tunneled/absent)
+    (--device runs RS on the TPU and fails without one; the default
+     numpy codec runs anywhere)
 
 With --device the bench also runs a decode microbench on synthetic
 survivors: the fused word-packed decode+verify launch
@@ -179,7 +179,7 @@ async def _run(args, cluster: LocalCluster, k: int, m: int,
         "repair_read_amplification": round(
             report.bytes_read / max(report.bytes_repaired, 1), 3),
         # survivor-read balance achieved by the k-subset planner
-        # (1.0 = perfectly flat; VERDICT r2 asked this to drop toward 1)
+        # (1.0 = perfectly flat)
         "survivor_read_imbalance": round(
             report.max_chain_reads / report.min_chain_reads, 3)
         if report.min_chain_reads else None,
@@ -189,18 +189,14 @@ async def _run(args, cluster: LocalCluster, k: int, m: int,
     }
 
 
-def _decode_microbench(args, platform: str | None) -> dict | None:
+def _decode_microbench(args) -> dict | None:
     """Kernel-level decode throughput on synthetic survivors (no cluster
     IO in the way).  Times the fused word-packed decode+verify launch —
     reconstruct of the 2 lost shards AND CRC32C of all k+|want| shards
     in ONE kernel pass — and, with --decode-ab, the byte-plane
     bit-matmul reconstruct for comparison.  GB/s counts survivor bytes
     in per launch (n*k*L), the same convention as the encode bench.
-
-    On CPU (no accelerator) the Pallas kernels run under the
-    interpreter, so absolute numbers are meaningless; the metric still
-    records them (with "interpret": true) so the path stays exercised.
-    """
+    Runs only with --device, which main() holds to a TPU."""
     if not args.device:
         return None
     import jax
@@ -214,19 +210,15 @@ def _decode_microbench(args, platform: str | None) -> dict | None:
 
     k, m = args.k, args.m
     rs_code = default_rs(k, m)
-    interpret = platform == "cpu"
-    # interpret mode walks the grid in python — shrink the problem so the
-    # metric line still appears in CI logs without minutes of warmup
-    L = min(args.chunk_size, 64 << 10) if interpret else args.chunk_size
-    L -= L % 512
-    n = 1 if interpret else max(1, args.decode_batch)
+    L = args.chunk_size - args.chunk_size % 512
+    n = max(1, args.decode_batch)
     present = tuple(range(2, k + m))     # drop shards 0 and 1 (double erasure)
     want = (0, 1)
     rng = np.random.default_rng(7)
     survivors = rng.integers(0, 256, (n, k, L), dtype=np.uint8)
     words = np.ascontiguousarray(survivors).view(np.uint32).reshape(
         n, k, L // 4)
-    iters = 1 if interpret else 20
+    iters = 20
 
     def gbps(fn, x):
         out = jax.block_until_ready(fn(x))       # compile + warm
@@ -237,19 +229,17 @@ def _decode_microbench(args, platform: str | None) -> dict | None:
         dt = (time.perf_counter() - t0) / iters
         return round(n * k * L / dt / 1e9, 3)
 
-    res: dict = {"L": L, "batch": n, "interpret": interpret}
+    res: dict = {"L": L, "batch": n}
     if rs_code.raid6:
         fused = jax.jit(make_stripe_decode_step_words(
-            L // 4, present, want, k=k, m=m, interpret=interpret))
+            L // 4, present, want, k=k, m=m))
         res["fused_decode_verify_GB_s"] = gbps(fused, words)
         rec_w = jax.jit(make_rs_reconstruct_words_pallas(
-            present, want, rs_code, block_w=pick_block(L // 4, 16384),
-            interpret=interpret))
+            present, want, rs_code, block_w=pick_block(L // 4, 16384)))
         res["word_reconstruct_GB_s"] = gbps(rec_w, words)
     if args.decode_ab or not rs_code.raid6:
         rec_b = jax.jit(make_rs_reconstruct_pallas(
-            present, want, rs_code, block_t=pick_block(L, 32768),
-            interpret=interpret))
+            present, want, rs_code, block_t=pick_block(L, 32768)))
         res["byteplane_reconstruct_GB_s"] = gbps(rec_b, survivors)
     return res
 
@@ -275,14 +265,13 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    platform = None
     if args.device:
-        from benchmarks._env import ensure_device_or_cpu
-        platform = ensure_device_or_cpu()
+        from benchmarks._env import require_tpu
+        platform = require_tpu()
     result = asyncio.run(run_bench(args))
-    if platform is not None:
+    if args.device:
         result["platform"] = platform
-    micro = _decode_microbench(args, platform)
+    micro = _decode_microbench(args)
     if micro is not None:
         result["decode_microbench"] = micro
     if args.json:

@@ -8,8 +8,7 @@ a per-volume WRITE-BANDWIDTH cap (`rate_mbps`, the cloud-disk discipline
 — volumes meter MB/s, and you scale aggregate bandwidth by adding
 volumes).  A hot range pinned to one group is capped at one volume's
 budget; the distributor's split-at-traffic-median + move-to-idle-group
-genuinely doubles the aggregate budget.  The note in BENCH_e2e.json
-states this model explicitly.
+genuinely doubles the aggregate budget.
 
 Cells (fresh engines each):
   static     whole keyspace pinned to group 0, no distributor — the

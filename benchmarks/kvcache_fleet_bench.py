@@ -26,7 +26,7 @@ how far N private windows over-admit (time-bucketed sum of concurrent
 holders across processes).
 
     python -m benchmarks.kvcache_fleet_bench --procs 6 --sessions 512 \
-        --turns 2 --admission-ab --json   # the BENCH_e2e.json config
+        --turns 2 --admission-ab --json   # the admission A/B
     python -m benchmarks.kvcache_fleet_bench --procs 2 --sessions 8 \
         --turns 1 --prompts 16 --blocks 4 --json    # smoke (CI)
 """

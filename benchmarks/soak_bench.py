@@ -5,7 +5,7 @@ one JSON blob with per-workload p50/p99/throughput, Jain fairness, the
 gate verdicts, and the worst-p99 tail-sampled trace.
 
     python -m benchmarks.soak_bench --config configs/soak.toml \
-        --cells both --repeat 3 --json      # the BENCH_e2e.json entry
+        --cells both --repeat 3 --json      # median-of-3 cells
     make soak-smoke                          # ~20 s harness proof
 
 Cells repeat `--repeat` times; scalar metrics report the median run

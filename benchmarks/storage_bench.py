@@ -98,7 +98,7 @@ async def run_bench(args) -> dict:
     # warm the codec path (device backends compile per shape bucket; the
     # persistent cache makes this a one-time cost per machine) and populate
     # the keyspace for read mode
-    if args.checksum_backend in ("tpu", "device") and not args.mgmtd:
+    if args.checksum_backend == "tpu" and not args.mgmtd:
         for node in env.nodes:
             if hasattr(node.codec, "warmup"):
                 await asyncio.to_thread(node.codec.warmup, [args.chunk_size])

@@ -61,10 +61,7 @@ def setup_logging(cfg: LogConfig, name: str) -> None:
 
 def parse_overrides(pairs: list[str]) -> dict:
     """--set a.b=3 style overrides; values parsed as TOML scalars."""
-    try:
-        import tomllib
-    except ImportError:
-        import tomli as tomllib  # type: ignore[no-redef]
+    import tomllib
     out = {}
     for pair in pairs:
         key, eq, raw = pair.partition("=")
@@ -145,10 +142,7 @@ class ApplicationBase:
         if args.config:
             # apply ONLY the keys present in the file — dumping a parsed
             # config object would clobber template values with defaults
-            try:
-                import tomllib
-            except ImportError:
-                import tomli as tomllib  # type: ignore[no-redef]
+            import tomllib
             with open(args.config, "rb") as f:
                 base.update(tomllib.load(f), hot_only=False)
         if args.set:

@@ -222,6 +222,10 @@ class DevCluster:
             port_file=port_path,
             monitor_address=self.monitor_address,
             metrics_period_s=2.0,
+            # checksum_backend stays "cpu": every node is its own process,
+            # and at most one process per chip may run "tpu" (libtpu lets
+            # one process hold a chip; StorageServer.start claims it, so a
+            # second "tpu" node on the same chip fails at start)
             service=StorageConfig(heartbeat_period_s=0.3,
                                   resync_period_s=0.3),
             log=LogConfig(file=self._path(f"{name}.log"))))

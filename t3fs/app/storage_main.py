@@ -58,6 +58,9 @@ async def serve(cfg: StorageMainConfig, app: ApplicationBase) -> None:
 def main(argv: list[str] | None = None) -> None:
     app = ApplicationBase("storage", StorageMainConfig)
     cfg = app.boot(argv)
+    if cfg.service.checksum_backend == "tpu":
+        from t3fs.storage.codec_backend import enable_persistent_cache
+        enable_persistent_cache()
     asyncio.run(serve(cfg, app))
 
 

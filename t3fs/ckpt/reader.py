@@ -129,7 +129,7 @@ class CheckpointReader:
         lay = manifest.layout
         k, m, cs = lay.k, lay.m, lay.chunk_size
         flayout = lay.data_file_layout()
-        bufs = {lf.path: bytearray(lf.nbytes) for lf in selected}
+        bufs = {lf.path: np.zeros(lf.nbytes, np.uint8) for lf in selected}
         # stripes whose data chains are all serving ride the batched
         # healthy path; the rest go straight to reconstruction (burning
         # the patient client's retry budget on a routed-out chain first
@@ -165,24 +165,33 @@ class CheckpointReader:
                 flayout, ranges,
                 hedging=None if self.read_hedging == "inherit"
                 else self.read_hedging)
-            for (inode, offset, length), lf, (data, results) in zip(
-                    ranges, range_leaf, out):
-                pieces = flayout.chunk_span(offset, length)
-                pos = 0
-                bad_stripes: set[int] = set()
-                for (idx, coff, span), r in zip(pieces, results):
-                    stripe, j = divmod(idx, k)
-                    want = lf.stripe_crcs(lay, stripe)[j]
-                    stored_len = min(cs, lf.nbytes - idx * cs)
-                    whole = coff == 0 and span == stored_len
-                    if (r.status.code != int(StatusCode.OK)
-                            or (whole and int(r.checksum) != want)):
-                        bad_stripes.add(stripe)
-                    elif stripe not in bad_stripes:
-                        bufs[lf.path][offset + pos:offset + pos + span] = \
-                            data[pos:pos + span]
-                    pos += span
-                degraded.extend((lf, s) for s in sorted(bad_stripes))
+
+            def place() -> list[tuple[CkptLeaf, int]]:
+                # runs in a thread: the numpy copies release the GIL, so
+                # placing a GiB of healthy reads leaves the loop free
+                bad: list[tuple[CkptLeaf, int]] = []
+                for (inode, offset, length), lf, (data, results) in zip(
+                        ranges, range_leaf, out):
+                    src = np.frombuffer(data, np.uint8)
+                    dst = bufs[lf.path][offset:offset + length]
+                    pieces = flayout.chunk_span(offset, length)
+                    pos = 0
+                    bad_stripes: set[int] = set()
+                    for (idx, coff, span), r in zip(pieces, results):
+                        stripe, j = divmod(idx, k)
+                        want = lf.stripe_crcs(lay, stripe)[j]
+                        stored_len = min(cs, lf.nbytes - idx * cs)
+                        whole = coff == 0 and span == stored_len
+                        if (r.status.code != int(StatusCode.OK)
+                                or (whole and int(r.checksum) != want)):
+                            bad_stripes.add(stripe)
+                        elif stripe not in bad_stripes:
+                            dst[pos:pos + span] = src[pos:pos + span]
+                        pos += span
+                    bad.extend((lf, s) for s in sorted(bad_stripes))
+                return bad
+
+            degraded.extend(await asyncio.to_thread(place))
 
         window = asyncio.Semaphore(self.window)
 
@@ -190,13 +199,12 @@ class CheckpointReader:
             async with window:
                 content = await self._read_stripe_verified(lay, lf, stripe)
             off = stripe * k * cs
-            bufs[lf.path][off:off + len(content)] = content
+            bufs[lf.path][off:off + len(content)] = \
+                np.frombuffer(content, np.uint8)
 
         await asyncio.gather(*(fix(lf, s) for lf, s in degraded))
-        return {lf.path: np.frombuffer(bytes(bufs[lf.path]),
-                                       dtype=np.dtype(lf.dtype)
-                                       ).reshape(lf.shape)
-                for lf in selected}
+        return {lf.path: bufs[lf.path].view(np.dtype(lf.dtype))
+                .reshape(lf.shape) for lf in selected}
 
     async def _read_stripe_verified(self, lay: ECLayout, lf: CkptLeaf,
                                     stripe: int) -> bytes:

@@ -53,8 +53,7 @@ class SaveStats:
 @dataclass
 class _LeafPlan:
     path: str
-    arr: np.ndarray
-    data: bytes
+    data: np.ndarray          # the leaf's bytes: a flat uint8 view
     entry: CkptLeaf = None
     crcs: list[int] = field(default_factory=list)   # filled per stripe
 
@@ -79,17 +78,24 @@ class CheckpointWriter:
         """Save `tree` as checkpoint `step`.  `resume=True` (default) makes
         an interrupted save restartable: already-committed shards are
         detected by CRC probe and skipped.  `on_stripe(done, total)` fires
-        after each stripe settles (progress/interruption hook)."""
+        after each stripe settles (progress/interruption hook).  Leaves are
+        read while their stripes stream: do not update a numpy leaf in
+        place before save returns (JAX arrays are immutable)."""
         lay = self.layout
         k, m, cs = lay.k, lay.m, lay.chunk_size
         stripe_bytes = k * cs
         leaves, treedef = flatten_tree(tree)
+        # host arrays, C-ordered: a JAX leaf comes back from the device and
+        # a strided one is copied, in a thread (a GiB copied on the loop
+        # stalls every heartbeat and lease it carries for seconds).  The
+        # bytes are read stripe by stripe, copied into each encode batch
+        arrs = await asyncio.to_thread(
+            lambda: [np.asarray(leaf, order="C") for _, leaf in leaves])
         plans: list[_LeafPlan] = []
-        for path, leaf in leaves:
-            arr = np.asarray(leaf)
-            data = arr.tobytes()
-            num_stripes = -(-len(data) // stripe_bytes) if data else 0
-            plan = _LeafPlan(path=path, arr=arr, data=data)
+        for (path, _), arr in zip(leaves, arrs):
+            data = arr.reshape(-1).view(np.uint8)
+            num_stripes = -(-len(data) // stripe_bytes) if len(data) else 0
+            plan = _LeafPlan(path=path, data=data)
             plan.entry = CkptLeaf(
                 path=path, dtype=str(arr.dtype), shape=list(arr.shape),
                 nbytes=len(data),

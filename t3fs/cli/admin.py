@@ -1059,7 +1059,9 @@ async def ckpt_verify(ctx: AdminContext, args) -> None:
     from t3fs.ckpt import CheckpointReader
     from t3fs.client.ec_client import ECStorageClient
     fs = await ctx.fs()
-    ec = ECStorageClient(await ctx.storage_client())
+    # host codec: on a storage host the storage node owns the chip, and a
+    # second process that touches JAX there would contend for it
+    ec = ECStorageClient(await ctx.storage_client(), use_device_codec=False)
     try:
         reader = CheckpointReader(ec, fs, args.directory)
         rep = await reader.scrub(args.step, repair=args.repair)

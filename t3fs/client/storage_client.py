@@ -38,6 +38,28 @@ from t3fs.utils.status import Status, StatusCode, StatusError, make_error
 
 log = logging.getLogger("t3fs.client")
 
+# read_file_ranges yields to the event loop after assembling this many bytes
+_YIELD_BYTES = 32 << 20
+# payload bytes per batch_read RPC: a node's share of a large batch goes out
+# as several RPCs, so no single response is joined, received and split in
+# one stretch of the event loop
+_RPC_READ_BYTES = 16 << 20
+
+
+def _split_reads(idxs: list[int], ios: list[ReadIO]) -> list[list[int]]:
+    """Cut one node's reads into runs of at most _RPC_READ_BYTES payload
+    bytes each (an IO of unknown length, 0, counts as nothing)."""
+    out: list[list[int]] = [[]]
+    size = 0
+    for i in idxs:
+        n = 0 if ios[i].no_payload else ios[i].length
+        if out[-1] and size + n > _RPC_READ_BYTES:
+            out.append([])
+            size = 0
+        out[-1].append(i)
+        size += n
+    return out
+
 
 class TargetSelection(enum.IntEnum):
     LOAD_BALANCE = 0
@@ -659,14 +681,16 @@ class StorageClient:
                                       f"n={len(hedged) - won}")
                 READ_STATS.hedge(address, won=won, wasted=len(hedged) - won)
 
+            rpcs = [(a, part) for a, idxs in groups.items()
+                    for part in _split_reads(idxs, ios)]
             if hedging:
                 # tokens accrue per primary read issued; hedges spend them
                 self._hedge_budget.earn(sum(len(v) for v in groups.values()))
                 await asyncio.gather(*[hedged_group(a, idxs)
-                                       for a, idxs in groups.items()])
+                                       for a, idxs in rpcs])
             else:
                 await asyncio.gather(*[read_group(a, idxs)
-                                       for a, idxs in groups.items()])
+                                       for a, idxs in rpcs])
             pending = [i for i in pending
                        if results[i] is not None
                        and results[i].status.code != int(StatusCode.OK)
@@ -732,17 +756,25 @@ class StorageClient:
             bounds.append((start, len(ios)))
         results, payloads = await self.batch_read(ios, hedging=hedging)
         out: list[tuple[bytes, list[IOResult]]] = []
+        copied = 0
         for pieces, (lo, hi) in zip(all_pieces, bounds):
-            data = bytearray()
+            parts: list[bytes] = []
             for (idx, coff, span), r, p in zip(pieces, results[lo:hi],
                                                payloads[lo:hi]):
                 if r.status.code == int(StatusCode.CHUNK_NOT_FOUND):
-                    data += b"\x00" * span  # hole
+                    parts.append(bytes(span))  # hole
                 else:
-                    data += p
+                    parts.append(p)
                     if len(p) < span:
-                        data += b"\x00" * (span - len(p))  # short tail
-            out.append((bytes(data), results[lo:hi]))
+                        parts.append(bytes(span - len(p)))  # short tail
+            data = b"".join(parts)
+            out.append((data, results[lo:hi]))
+            # a GiB of ranges is seconds of copying: let the loop breathe
+            # between ranges instead of stalling it in one stretch
+            copied += len(data)
+            if copied >= _YIELD_BYTES:
+                copied = 0
+                await asyncio.sleep(0)
         return out
 
     async def _call_chain_head(self, chain_id: int, method: str, req,

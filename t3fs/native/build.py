@@ -1,8 +1,14 @@
-"""Build + load the t3fs native library (g++ -> .so, cached by mtime)."""
+"""Build + load the t3fs native library (g++ -> .so).
+
+The artifact's name carries a hash of the sources and the build flags, so
+a .so left over from other sources (an older checkout, another machine's
+copy of the tree) is never loaded: a mismatch builds afresh."""
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -11,7 +17,7 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["chunk_engine.cpp", "usrbio.cpp", "aio_reader.cpp",
             "net_pump.cpp"]
-_LIB = os.path.join(_DIR, "libt3fs_native.so")
+_LIB_STEM = os.path.join(_DIR, "libt3fs_native")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 
@@ -34,7 +40,7 @@ if platform.machine() in ("x86_64", "AMD64"):
 _SANITIZE = os.environ.get("T3FS_SANITIZE", "")
 
 
-def _flags_and_lib() -> tuple[list[str], str]:
+def _flags_and_variant() -> tuple[list[str], str]:
     if _SANITIZE and _SANITIZE not in ("thread", "address"):
         # an unknown value must not silently build UNinstrumented code
         # while the test harness believes it is in sanitizer mode
@@ -44,8 +50,8 @@ def _flags_and_lib() -> tuple[list[str], str]:
         flags = [f if f != "-O2" else "-O1" for f in _CXXFLAGS]
         flags.append(f"-fsanitize={_SANITIZE}")
         flags.append("-fno-omit-frame-pointer")
-        return flags, _LIB.replace(".so", f".{_SANITIZE[0]}san.so")
-    return _CXXFLAGS, _LIB
+        return flags, f".{_SANITIZE[0]}san"
+    return _CXXFLAGS, ""
 
 
 def _sources() -> list[str]:
@@ -53,13 +59,21 @@ def _sources() -> list[str]:
             if os.path.exists(os.path.join(_DIR, s))]
 
 
+def _artifact(flags: list[str], variant: str, srcs: list[str]) -> str:
+    h = hashlib.sha256("\0".join(flags + _LDLIBS).encode())
+    for src in srcs:
+        h.update(b"\0" + os.path.basename(src).encode() + b"\0")
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return f"{_LIB_STEM}{variant}-{h.hexdigest()[:16]}.so"
+
+
 def build(force: bool = False) -> str:
-    flags, lib = _flags_and_lib()
+    flags, variant = _flags_and_variant()
     srcs = _sources()
+    lib = _artifact(flags, variant, srcs)
     if not force and os.path.exists(lib):
-        lib_mtime = os.path.getmtime(lib)
-        if all(os.path.getmtime(s) <= lib_mtime for s in srcs):
-            return lib
+        return lib
     tmp = lib + f".tmp.{os.getpid()}"
     cmd = ["g++", *flags, "-o", tmp, *srcs, *_LDLIBS]
     try:
@@ -67,6 +81,13 @@ def build(force: bool = False) -> str:
     except subprocess.CalledProcessError as e:
         raise RuntimeError(f"native build failed:\n{e.stderr}") from e
     os.replace(tmp, lib)
+    # drop artifacts of other sources; a process that mapped one keeps it
+    for old in glob.glob(f"{_LIB_STEM}{variant}-*.so"):
+        if old != lib:
+            try:
+                os.unlink(old)
+            except FileNotFoundError:
+                pass
     return lib
 
 

@@ -181,6 +181,7 @@ def make_msr_encode_step(code: MSRCode, chunk_len: int,
             parity = np.asarray(parity)
         return parity, np.asarray(crcs)
 
+    run.program = step     # the jitted device program (AOT compile tests)
     return run
 
 
@@ -280,6 +281,7 @@ def make_msr_repair_step(code: MSRCode, f: int, chunk_len: int,
             rebuilt = np.asarray(rebuilt)
         return rebuilt, np.asarray(crcs)
 
+    run.program = step     # the jitted device program (AOT compile tests)
     return run
 
 

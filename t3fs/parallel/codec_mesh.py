@@ -30,18 +30,6 @@ from t3fs.ops.jax_codec import (
 )
 from t3fs.ops.rs import default_rs
 
-# jax.shard_map is the public name from 0.6; older jax (0.4.x) ships it
-# under jax.experimental, where check_vma is spelled check_rep
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map as _xshard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-        kw = {} if check_vma is None else {"check_rep": check_vma}
-        return _xshard_map(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
 
 def make_mesh(n_devices: int | None = None, dp: int | None = None) -> Mesh:
     """Build a (dp, cp) mesh over the available devices, favoring cp (the
@@ -123,7 +111,7 @@ def make_sharded_encode_step(mesh: Mesh, chunk_len: int, k: int = 8, m: int = 2,
         crcs = crc_combine(raw, n, k + m)
         return parity, crcs
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=P("dp", None, "cp"),
         out_specs=(P("dp", None, "cp"), P("dp", None)),
@@ -188,7 +176,7 @@ def make_sharded_encode_step_words(mesh: Mesh, chunk_words: int,
         crcs = crc_combine(bits, n, k + m)
         return parity, crcs
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=P("dp", None, "cp"),
         out_specs=(P("dp", None, "cp"), P("dp", None)),
@@ -256,7 +244,7 @@ def make_sharded_reconstruct_step_words(mesh: Mesh, chunk_len: int,
             crcs = crc_combine(raw_bits(words), n, w)
             return rebuilt, crcs
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=P("dp", None, "cp"),
         out_specs=(P("dp", None, "cp"), P("dp", None)),
@@ -296,7 +284,7 @@ def make_sharded_reconstruct_step(mesh: Mesh, chunk_len: int,
         crcs = crc_combine(raw, n, len(want))
         return rebuilt, crcs
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=P("dp", None, "cp"),
         out_specs=(P("dp", None, "cp"), P("dp", None)),

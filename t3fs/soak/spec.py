@@ -136,10 +136,7 @@ def load_spec(text_or_path: str) -> SoakSpec:
     """Parse a scenario TOML: ``[[workload]]`` / ``[[fault]]`` arrays
     splice into WorkloadSpec/FaultSpec lists, everything else is plain
     SoakSpec fields.  Workloads without a name get `kind` or `kindN`."""
-    try:
-        import tomllib
-    except ImportError:                      # Python < 3.11
-        import tomli as tomllib  # type: ignore[no-redef]
+    import tomllib
     if "\n" not in text_or_path and text_or_path.endswith(".toml"):
         with open(text_or_path, "rb") as f:
             d = tomllib.load(f)

@@ -27,6 +27,15 @@ from t3fs.utils.tracing import TraceConfig, configure as configure_tracing
 
 log = logging.getLogger("t3fs.storage")
 
+# payload sizes whose device CRC kernels a starting node precompiles
+WARMUP_PAYLOAD_SIZES = [64 << 10, 512 << 10, 1 << 20, 4 << 20]
+
+
+def _log_warmup_failure(fut) -> None:
+    if not fut.cancelled() and fut.exception() is not None:
+        log.error("codec warmup failed; writes of that size will fail too",
+                  exc_info=fut.exception())
+
 
 @dataclass
 class StorageConfig(ConfigBase):
@@ -40,7 +49,7 @@ class StorageConfig(ConfigBase):
     maintenance_period_s: float = citem(30.0, validator=lambda v: v > 0)
     # the codec seam (BASELINE north star): cpu | tpu | null
     checksum_backend: str = citem(
-        "cpu", hot=False, validator=lambda v: v in ("cpu", "tpu", "device", "null"))
+        "cpu", hot=False, validator=lambda v: v in ("cpu", "tpu", "null"))
     # io_uring read pipeline (AioReadWorker analog); auto-disables when the
     # kernel lacks io_uring
     aio_read: bool = citem(True, hot=False)
@@ -185,6 +194,10 @@ class StorageServer:
 
     async def start(self) -> None:
         configure_tracing(self.cfg.trace)
+        if hasattr(self.node.codec, "claim_device"):
+            # take the chip now: a node that cannot (no TPU, or another
+            # process holds it) fails here, not on its first large write
+            self.node.codec.claim_device()
         # before the first heartbeat: local_states must cover adopted
         # targets or mgmtd briefly sees them missing
         self._discover_targets()
@@ -218,8 +231,8 @@ class StorageServer:
             # first write doesn't eat a ~10s kernel compile on the hot path
             # (results persist in the on-disk jax cache across restarts)
             self._warmup_task = asyncio.get_running_loop().run_in_executor(
-                None, self.node.codec.warmup,
-                [64 << 10, 512 << 10, 1 << 20, 4 << 20])
+                None, self.node.codec.warmup, WARMUP_PAYLOAD_SIZES)
+            self._warmup_task.add_done_callback(_log_warmup_failure)
         log.info("storage node %d up at %s", self.node_id, self.server.address)
 
     async def stop(self) -> None:

@@ -7,6 +7,7 @@ here in-process for tests, with fast failure-detection knobs.
 
 from __future__ import annotations
 
+import asyncio
 import os
 import shutil
 import tempfile
@@ -40,7 +41,8 @@ class LocalCluster:
                  seed_read_priors: bool = True,
                  kv_shards: int = 0,
                  with_kv_distributor: bool = False,
-                 kv_distributor_cfg: dict | None = None):
+                 kv_distributor_cfg: dict | None = None,
+                 checksum_backend="cpu"):
         self.num_nodes = num_nodes
         self.replicas = replicas
         self.num_chains = num_chains
@@ -56,6 +58,10 @@ class LocalCluster:
         # tests exercise the fragment path
         self.write_pipeline = write_pipeline
         self.stream_threshold = stream_threshold
+        # every storage node's codec seam: "cpu", "tpu", or a factory
+        # returning a ChecksumBackend (one per node, see
+        # make_checksum_backend)
+        self.checksum_backend = checksum_backend
         # TraceConfig every storage node installs on (re)start.  Without
         # this, StorageServer.start()'s process-wide configure_tracing
         # resets sampling to the zero default — including on a mid-test
@@ -108,6 +114,11 @@ class LocalCluster:
         return f"{self._tmp.name}/node{node_id}"
 
     async def start(self) -> None:
+        # a fresh checkout builds the native library on first use (g++,
+        # tens of seconds): do it before mgmtd's lease clock starts, not
+        # inside a running node where it would stall every lease
+        from t3fs.native import load_library
+        await asyncio.to_thread(load_library)
         if self.with_monitor:
             from t3fs.monitor.reporter import MonitorReporter
             from t3fs.monitor.service import MonitorCollectorServer
@@ -166,7 +177,6 @@ class LocalCluster:
 
         # wait until every storage node has pulled the installed chains so
         # first writes don't race routing propagation
-        import asyncio
         want = self.mgmtd.state.routing().version
         for _ in range(100):
             if all(ss.mgmtd.routing().version >= want
@@ -282,6 +292,7 @@ class LocalCluster:
                                0.15, self.mgmtd_cfg.heartbeat_timeout_s / 6),
                            resync_period_s=0.1,
                            write_pipeline=self.write_pipeline,
+                           checksum_backend=self.checksum_backend,
                            default_root=self.node_root(node_id),
                            discover_targets=True)
         if self.stream_threshold is not None:
@@ -342,7 +353,6 @@ class LocalCluster:
         and every client/server reconnects on its next call.  Mid-flight
         admin ops fail with a transient RPC error — exactly the window
         the migration service's resumable-job path must survive."""
-        import asyncio
         if self.mgmtd is not None:
             await self.kill_mgmtd()
         host, port = self._mgmtd_addr
@@ -411,7 +421,6 @@ class LocalCluster:
         restart it, and wait until every affected chain has a head again.
         Replicated chains refill via CRAQ resync; single-replica EC
         chains come back empty — scrub/repair's job to heal."""
-        import asyncio
         routing = self.mgmtd.state.routing()
         affected = {c.chain_id: c.chain_ver
                     for c in routing.chains.values()

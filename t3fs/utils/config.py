@@ -9,10 +9,7 @@ rules, and returns what changed so services can react (onConfigUpdated).
 
 from __future__ import annotations
 
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: the API-compatible backport
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from dataclasses import dataclass, field, fields, is_dataclass
 from typing import Any, Callable
 

@@ -5,6 +5,7 @@ switch); the CPU path replaced is folly::crc32c (fbs/storage/Common.h:158).
 """
 
 import asyncio
+import os
 
 import numpy as np
 import pytest
@@ -42,7 +43,7 @@ def test_null_backend():
 def test_device_backend_batches_concurrent_payloads():
     async def body():
         b = DeviceChecksumBackend(min_device_bytes=0, max_wait_us=2000,
-                                  max_batch=16)
+                                  max_batch=16, interpret_on_cpu=True)
         try:
             # mixed lengths -> multiple buckets in one flush; includes
             # non-segment-multiple lengths (front-padding path)
@@ -60,7 +61,7 @@ def test_device_backend_batches_concurrent_payloads():
 
 def test_device_backend_small_payload_host_path():
     async def body():
-        b = DeviceChecksumBackend()  # default threshold: small stays on host
+        b = DeviceChecksumBackend(interpret_on_cpu=True)  # small stays on host
         data = b"123456789"
         assert await b.payload_crc(data) == 0xE3069283
         assert b.batched_items == 0
@@ -70,7 +71,8 @@ def test_device_backend_small_payload_host_path():
 def test_close_fails_inflight_futures():
     async def body():
         # huge wait window so items sit in the batch when close() lands
-        b = DeviceChecksumBackend(min_device_bytes=0, max_wait_us=10_000_000)
+        b = DeviceChecksumBackend(min_device_bytes=0, max_wait_us=10_000_000,
+                                  interpret_on_cpu=True)
         data = rng.integers(0, 256, 1024, dtype=np.uint8).tobytes()
         task = asyncio.ensure_future(b.payload_crc(data))
         await asyncio.sleep(0.05)  # worker collects the item, waits for more
@@ -92,7 +94,7 @@ def test_payload_crc_after_close_fails_fast():
     from t3fs.utils.status import StatusError
 
     async def body():
-        b = DeviceChecksumBackend(min_device_bytes=0)
+        b = DeviceChecksumBackend(min_device_bytes=0, interpret_on_cpu=True)
         await b.close()
         with pytest.raises(StatusError, match="closed"):
             await b.payload_crc(b"x" * 1024)
@@ -151,3 +153,65 @@ def test_factory():
     assert make_checksum_backend(lambda: NullChecksumBackend()).name == "null"
     with pytest.raises(ValueError):
         make_checksum_backend("bogus")
+
+
+@pytest.mark.skipif(bool(os.environ.get("T3FS_ON_DEVICE")),
+                    reason="needs a platform that is not a TPU")
+def test_tpu_backend_refuses_to_interpret_on_cpu():
+    """checksum_backend="tpu" never falls back to the Pallas interpreter:
+    off a TPU it fails at claim_device (storage node start) and on the
+    first device-sized payload alike."""
+    from t3fs.storage.server import StorageServer
+
+    b = make_checksum_backend("tpu")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        b.claim_device()
+
+    async def body():
+        try:
+            with pytest.raises(RuntimeError, match="needs a TPU"):
+                await b.payload_crc(b"x" * b.min_device_bytes)
+            ss = StorageServer(1, "127.0.0.1:1", checksum_backend="tpu")
+            with pytest.raises(RuntimeError, match="needs a TPU"):
+                await ss.start()
+            await ss.stop()
+        finally:
+            await b.close()
+    run(body())
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "default"])
+def test_compile_cache_placement(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, holds every persisted program;
+    unset, the cache is the fixed in-checkout DEFAULT_CACHE_DIR."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from t3fs.storage.codec_backend import (DEFAULT_CACHE_DIR,\n"
+        "    enable_persistent_cache)\n"
+        "enable_persistent_cache()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(DEFAULT_CACHE_DIR)\n")
+    if env_dir:
+        script += (
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n")
+    out = subprocess.run([sys.executable, "-c", script], env=env, cwd=tmp_path,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    used, default = out.stdout.split()
+    assert default == os.path.join(repo, ".jax_cache")
+    if env_dir:
+        assert used == str(tmp_path / "cache")
+        assert os.listdir(used), "no compiled program landed in the env dir"
+    else:
+        assert used == default

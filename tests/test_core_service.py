@@ -5,11 +5,7 @@ hotUpdateConfig, getLastConfigUpdateRecord) + fbs/core user ctrl.
 """
 
 import asyncio
-
-try:
-    import tomllib
-except ImportError:
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from dataclasses import dataclass
 
 import pytest

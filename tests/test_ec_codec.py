@@ -132,7 +132,8 @@ def test_warmup_decode_precompiles_recv_keys(interpret_env):
     async def body():
         codec = ECCodec()
         try:
-            codec.warmup_decode(patterns, L, k=k, m=m)
+            assert codec.warmup_decode(patterns, L, k=k, m=m,
+                                       batch_sizes=(1,)) == 2
             for present, want in patterns:
                 assert ("recv", present, want, k, m, L) in codec._fns
             # warmed compiles ran the real fn, so counts reflect them

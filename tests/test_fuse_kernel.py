@@ -442,7 +442,7 @@ def test_mount_renameat2_flags():
 
 
 def test_mount_enforces_posix_permissions():
-    """VERDICT r2 missing #1 / weak #5: EACCES asserted via the REAL
+    """EACCES asserted via the REAL
     mount — a non-root subprocess (allow_other mount option) is denied by
     the server-side mode-bit checks; root bypasses."""
     import subprocess

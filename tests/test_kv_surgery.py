@@ -1,5 +1,5 @@
-"""Online shard split/move (VERDICT r2 missing #4: the static ShardMap
-could never rebalance a hot range without downtime).
+"""Online shard split/move (a static ShardMap could never rebalance a hot
+range without downtime).
 
 Reference role: FoundationDB's online range movement behind
 src/fdb/FDBKVEngine.h.

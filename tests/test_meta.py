@@ -327,7 +327,7 @@ def test_idempotent_remove_and_rename_replay():
 
 def test_concurrent_create_stress_two_servers():
     """Hammer one KV from two meta stores: every logical request applies
-    exactly once even with client-level replays (the VERDICT item-6 gate)."""
+    exactly once even with client-level replays."""
     async def body():
         from t3fs.kv.engine import MemKVEngine
         kv = MemKVEngine()

@@ -115,3 +115,19 @@ def test_native_block_reuse(tmp_path):
     # 19 COW rewrites of one chunk must not allocate 19 blocks' worth of space
     assert e.stats().allocated_bytes <= 3 * 4096
     e.close()
+
+
+def test_native_artifact_keyed_on_sources_and_flags(tmp_path):
+    """The .so name hashes the sources and flags: a library built from
+    other sources (a stale or foreign file) is never the one loaded."""
+    from t3fs.native import build as nb
+    src = tmp_path / "a.cpp"
+    src.write_text("int x;")
+    a = nb._artifact(["-O2"], "", [str(src)])
+    assert nb._artifact(["-O2"], "", [str(src)]) == a
+    src.write_text("int y;")
+    b = nb._artifact(["-O2"], "", [str(src)])
+    c = nb._artifact(["-O1"], "", [str(src)])
+    assert len({a, b, c}) == 3
+    assert all(p.startswith(nb._LIB_STEM + "-") and p.endswith(".so")
+               for p in (a, b, c))

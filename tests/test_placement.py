@@ -39,7 +39,7 @@ def test_select_skips_overloaded_chains():
     assert 3 not in chains  # third chain on node 1 must be skipped
 
 
-# ---- recovery-traffic balancing (BIBD objective, VERDICT item 10 gate) ----
+# ---- recovery-traffic balancing (BIBD objective) ----
 
 def test_build_chain_table_10x50_balanced():
     """10-node/50-chain topology: reconstruction load within the integer

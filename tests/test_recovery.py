@@ -148,7 +148,7 @@ def test_disk_failure_offline_replace_resync(write_pipeline,
     """Disk dies under a LIVE node mid-writes: write error marks the target
     OFFLINE, heartbeats propagate, mgmtd pulls it from the chain with no
     acked-write loss; operator 'replaces the disk' and the target resyncs
-    back to serving (VERDICT item 8 gate; StorageOperator.cc:604-606 +
+    back to serving (StorageOperator.cc:604-606 +
     worker/CheckWorker analogs)."""
     async def body():
         cluster = LocalCluster(num_nodes=3, replicas=3,
@@ -206,7 +206,7 @@ def test_disk_failure_offline_replace_resync(write_pipeline,
 
 
 def test_partitioned_head_self_fences():
-    """VERDICT r2 missing #3 (reference suicide.cc at lease/2): a storage
+    """Reference suicide.cc at lease/2: a storage
     node partitioned from mgmtd stops acking writes BEFORE mgmtd's
     heartbeat timeout can promote a successor — a stale head can never
     keep acknowledging data the reshaped chain won't have."""

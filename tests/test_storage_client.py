@@ -539,10 +539,19 @@ def test_packed_ver_memo_dies_with_the_connection():
     _a.run(body())
 
 
-def test_read_file_ranges_out_of_order_and_overlapping():
+@pytest.mark.parametrize("split", [False, True],
+                         ids=["one-rpc", "4KiB-rpcs"])
+def test_read_file_ranges_out_of_order_and_overlapping(monkeypatch, split):
     """One batch_read fan-out serves many ranges regardless of order or
     overlap; per-range (bytes, per-piece IOResults) stay aligned with the
-    request list (ckpt resharded-restore leans on this)."""
+    request list (ckpt resharded-restore leans on this).  `split` caps
+    each batch_read RPC at one chunk and yields after every range, so a
+    node's reads go out as several RPCs and must still land in order."""
+    if split:
+        from t3fs.client import storage_client
+        monkeypatch.setattr(storage_client, "_RPC_READ_BYTES", 4096)
+        monkeypatch.setattr(storage_client, "_YIELD_BYTES", 1)
+
     async def body():
         fabric = StorageFabric(num_nodes=2, replicas=2)
         await fabric.start()

@@ -51,7 +51,8 @@ def _checksum_backend(request, monkeypatch):
         monkeypatch.setattr(
             StorageFabric, "default_checksum_backend",
             staticmethod(lambda: DeviceChecksumBackend(
-                min_device_bytes=0, max_wait_us=200)))
+                min_device_bytes=0, max_wait_us=200,
+                interpret_on_cpu=True)))
 
 
 @pytest.fixture(autouse=True, params=["off", "overlap", "streamed"])
