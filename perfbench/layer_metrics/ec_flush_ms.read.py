@@ -1,0 +1,8 @@
+"""Mean time ECCodec's codec thread spends on one decode launch, in ms:
+stacking, launch, the result pull and the per-row split (ec.flush spans)."""
+
+from perfbench.span_tags import DECODE_OPS, ec_flush_ms
+
+
+def read(r):
+    return ec_flush_ms(r, DECODE_OPS)

@@ -1,0 +1,8 @@
+"""Mean wait of one stripe (decode) in ECCodec's batcher, from submit
+to the start of its flush on the codec thread, in ms (ec.flush spans)."""
+
+from perfbench.span_tags import DECODE_OPS, ec_queue_ms
+
+
+def read(r):
+    return ec_queue_ms(r, DECODE_OPS)

@@ -1340,7 +1340,8 @@ def render_trace(spans: list[dict]) -> str:
         bits.append(f"{s['dur_s'] * 1e3:.2f}ms")
         if s.get("status"):
             bits.append(f"status={s['status']}")
-        for k in ("wire_s", "queue_s", "apply_s", "forward_s"):
+        for k in ("send_s", "wire_s", "frame_s", "queue_s", "crc_s",
+                  "apply_s", "engine_s", "forward_s"):
             if k in tags:
                 bits.append(f"{k[:-2]}={tags[k] * 1e3:.2f}ms")
         return "  ".join(bits)

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Callable
@@ -61,6 +62,7 @@ class _Pending:
     rows: np.ndarray             # one request's shards (k, L)
     future: asyncio.Future
     loop: asyncio.AbstractEventLoop
+    t0: float = 0.0              # perf_counter at _submit entry (sampling)
 
 
 def _set_result_safe(fut: asyncio.Future, value) -> None:
@@ -75,6 +77,7 @@ def _set_exception_safe(fut: asyncio.Future, err) -> None:
 
 from t3fs.ops.blocks import batch_ladder, pad_batch
 from t3fs.ops.blocks import pick_block as _pick_block
+from t3fs.utils import tracing
 from t3fs.utils.aio import reap_task
 
 
@@ -217,7 +220,8 @@ class ECCodec:
         if self._worker is None or self._worker.done():
             self._worker = loop.create_task(self._worker_loop())
         fut = loop.create_future()
-        await self._q.put((key, _Pending(rows, fut, loop)))
+        t0 = time.perf_counter() if tracing.sampling_on() else 0.0
+        await self._q.put((key, _Pending(rows, fut, loop, t0)))
         return await fut
 
     async def _worker_loop(self) -> None:
@@ -265,8 +269,15 @@ class ECCodec:
 
     def _flush(self, groups: dict[tuple, list[_Pending]]) -> None:
         """Device work, runs on the codec thread: one kernel call per
-        (shape-key) group covering every stacked request."""
+        (shape-key) group covering every stacked request.  A launch that
+        wins a head-sampling decision also finishes an `ec.flush` span:
+        the codec thread's time in it (stacking, launch, result pull,
+        per-row split), tagged with its rows' summed queue wait (submit
+        to this flush starting)."""
+        t_start = time.perf_counter()
         for key, items in groups.items():
+            sampled = tracing.head_sample()
+            t0 = time.perf_counter() if sampled else 0.0
             fn = self._fn(key)
             rows = items[0].rows
             n = pad_batch(len(items), self.max_batch, PAD_BASE)
@@ -282,6 +293,12 @@ class ECCodec:
                        else np.asarray(out)[i])
                 it.loop.call_soon_threadsafe(
                     _set_result_safe, it.future, res)
+            if sampled:
+                flush_s = time.perf_counter() - t0
+                tracing.record_root(
+                    "ec.flush", time.time() - flush_s, flush_s, op=key[0],
+                    rows=len(items),
+                    queue_s=sum(t_start - it.t0 for it in items if it.t0))
 
     # --- kernel selection + jit cache ---
 

@@ -193,6 +193,10 @@ class Connection:
         self._waiters[uuid] = fut
         try:
             await self._send_frame(packet, payload, FLAG_IS_REQ)
+            sp = tracing.current_span()
+            if sp is not None and sp.name == "rpc." + method:
+                # serde, envelope CRC, the send-lock wait, write and drain
+                sp.set_tag("send_s", time.time() - packet.ts_client_called)
             try:
                 rsp, rsp_payload = await asyncio.wait_for(fut, timeout)
             except asyncio.TimeoutError:
@@ -221,6 +225,7 @@ class Connection:
         try:
             while True:
                 head = await self.reader.readexactly(HEADER_SIZE)
+                head_ts = time.time()
                 msg_len, payload_len, flags, msg_crc = unpack_header(head)
                 msg = await self.reader.readexactly(msg_len) if msg_len else b""
                 payload = await self.reader.readexactly(payload_len) if payload_len else b""
@@ -236,7 +241,7 @@ class Connection:
                     await asyncio.to_thread(check_msg_crc, msg, msg_crc)
                 else:
                     check_msg_crc(msg, msg_crc)
-                self._dispatch_packet(serde.loads(msg), payload)
+                self._dispatch_packet(serde.loads(msg), payload, head_ts)
         except (asyncio.IncompleteReadError, ConnectionResetError, BrokenPipeError):
             pass
         except asyncio.CancelledError:
@@ -249,13 +254,16 @@ class Connection:
             if not self._closed:
                 self._spawn(self.close(), f"close-{self.name}")
 
-    def _dispatch_packet(self, packet: MessagePacket,
-                         payload: bytes) -> None:
+    def _dispatch_packet(self, packet: MessagePacket, payload: bytes,
+                         head_ts: float = 0.0) -> None:
         """Post-decode dispatch shared by the asyncio read loop and the
         native-pump path: spawn the handler for requests (stamping the
-        receive time), wake the waiter for responses."""
+        receive time), wake the waiter for responses.  head_ts is when
+        the read loop had the frame's header (0: the native pump, which
+        reads frames off the loop)."""
         if packet.is_req:
-            self._spawn(self._handle_request(packet, payload, time.time()),
+            self._spawn(self._handle_request(packet, payload, time.time(),
+                                             head_ts),
                         f"req-{packet.method}")
         else:
             fut = self._waiters.get(packet.uuid)
@@ -263,7 +271,8 @@ class Connection:
                 fut.set_result((packet, payload))
 
     async def _handle_request(self, packet: MessagePacket, payload: bytes,
-                              recv_ts: float = 0.0) -> None:
+                              recv_ts: float = 0.0,
+                              head_ts: float = 0.0) -> None:
         rsp = MessagePacket(uuid=packet.uuid, method=packet.method, is_req=False)
         rsp.ts_server_received = recv_ts or time.time()
         rsp.ts_server_started = time.time()   # gap = server-side queueing
@@ -272,12 +281,16 @@ class Connection:
         if packet.sampled and packet.trace_id:
             # server span: the handler (and anything it calls, including
             # downstream RPCs) runs inside it.  wire_s spans both clocks
-            # (skew rides in it); queue_s is same-clock loop queueing.
+            # (skew rides in it); queue_s is same-clock loop queueing;
+            # frame_s, header to dispatch, is the body read, its CRC
+            # check and serde.loads.
             scope = tracing.server_scope(
                 packet.method, packet.trace_id, packet.parent_span_id,
                 addr=self.local_address,
                 wire_s=max(0.0, rsp.ts_server_received - packet.ts_client_called),
                 queue_s=rsp.ts_server_started - rsp.ts_server_received)
+            if head_ts:
+                scope.span.set_tag("frame_s", rsp.ts_server_received - head_ts)
         else:
             scope = tracing.server_scope(packet.method, 0, 0)   # no-op
         with scope as sp:

@@ -297,6 +297,7 @@ def make_rs_encode_words_pallas(rs: RSCode | None = None, block_w: int = 16384,
             out_specs=pl.BlockSpec((1, 2, rows, COLS),
                                    lambda i, j: (i, 0, j, 0)),
             interpret=interpret,
+            name="rs_encode_words",
         )(v)
         return out.reshape(n, 2, W)
 
@@ -354,6 +355,7 @@ def make_crc_seg_words_pallas(block_r: int = 512, interpret: bool = False):
             ],
             out_specs=pl.BlockSpec((block_r, 32), lambda i: (i, 0)),
             interpret=interpret,
+            name="crc32c_seg_words",
         )(rows, Mj)
 
     return seg_crc
@@ -560,6 +562,7 @@ def make_rs_reconstruct_words_pallas(present: tuple[int, ...],
             out_specs=pl.BlockSpec((1, nwant, rows, COLS),
                                    lambda i, j: (i, 0, j, 0)),
             interpret=interpret,
+            name="rs_decode_words",
         )(v)
         return out.reshape(n, nwant, W)
 

@@ -30,6 +30,7 @@ import functools
 import logging
 import os
 import threading
+import time
 from collections import defaultdict
 from concurrent.futures import CancelledError, ThreadPoolExecutor
 from dataclasses import dataclass
@@ -39,6 +40,7 @@ import numpy as np
 from t3fs.ops.blocks import batch_ladder, pad_batch
 from t3fs.ops.codec import crc32c as cpu_crc32c, crc32c_combine
 from t3fs.ops.crc32c import default_matrices
+from t3fs.utils import tracing
 from t3fs.utils.aio import reap_task
 
 log = logging.getLogger("t3fs.storage.codec")
@@ -102,6 +104,10 @@ class _Pending:
     data: bytes
     future: asyncio.Future
     loop: asyncio.AbstractEventLoop
+    # perf_counter at payload_crc entry and at the batch's launch, read
+    # only for a caller under a span (0.0 otherwise)
+    t0: float = 0.0
+    t_launch: float = 0.0
 
 
 class DeviceChecksumBackend(ChecksumBackend):
@@ -163,9 +169,19 @@ class DeviceChecksumBackend(ChecksumBackend):
         if self._worker is None or self._worker.done():
             self._worker = asyncio.get_running_loop().create_task(
                 self._worker_loop())
-        fut = asyncio.get_running_loop().create_future()
-        await self._q.put(_Pending(data, fut, asyncio.get_running_loop()))
-        return await fut
+        loop = asyncio.get_running_loop()
+        sp = tracing.current_span()
+        item = _Pending(data, loop.create_future(), loop,
+                        time.perf_counter() if sp is not None else 0.0)
+        await self._q.put(item)
+        crc = await item.future
+        if sp is not None and item.t_launch:
+            # entry to launch (the batcher queue); launch to the caller
+            # resuming (the double-buffer hold, H2D, kernel, D2H and the
+            # hop back to the loop)
+            sp.set_tag("crc_queue_s", item.t_launch - item.t0)
+            sp.set_tag("crc_result_s", time.perf_counter() - item.t_launch)
+        return crc
 
     async def close(self) -> None:
         self._closed = True
@@ -227,6 +243,10 @@ class DeviceChecksumBackend(ChecksumBackend):
                     groups[self._bucket_words(len(item.data))].append(item)
                 self.batches += len(groups)
                 self.batched_items += len(batch)
+                if any(item.t0 for item in batch):   # a caller is traced
+                    t_launch = time.perf_counter()
+                    for item in batch:
+                        item.t_launch = t_launch
                 try:
                     dispatched = await loop.run_in_executor(
                         self._pool, self._dispatch, groups)

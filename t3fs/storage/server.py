@@ -22,6 +22,7 @@ from t3fs.net.client import Client
 from t3fs.net.server import Server
 from t3fs.storage.resync import ResyncWorker
 from t3fs.storage.service import StorageNode, StorageService
+from t3fs.utils import loop_meter
 from t3fs.utils.config import ConfigBase, citem, cobj
 from t3fs.utils.tracing import TraceConfig, configure as configure_tracing
 
@@ -194,6 +195,9 @@ class StorageServer:
 
     async def start(self) -> None:
         configure_tracing(self.cfg.trace)
+        # the loop's busy/wait split (loop.* gauges, loop.wait spans);
+        # once per loop, however many nodes share it
+        loop_meter.install()
         if hasattr(self.node.codec, "claim_device"):
             # take the chip now: a node that cannot (no TPU, or another
             # process holds it) fails here, not on its first large write

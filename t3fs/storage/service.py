@@ -388,8 +388,8 @@ class StorageService:
             return result
         finally:
             if sp is not None:
-                for k in ("target_id", "apply_s", "forward_s",
-                          "forward_status"):
+                for k in ("target_id", "apply_s", "crc_s", "engine_s",
+                          "forward_s", "forward_status"):
                     if k in trace:
                         sp.set_tag(k, trace[k])
                 sp.set_tag("chunk", str(io.chunk_id))
@@ -532,7 +532,9 @@ class StorageService:
                 # fragment CRCs rolled up at reassembly — no second pass
                 payload_crc = stream_crc
             elif not overlap:
+                t_crc = _time.perf_counter()
                 payload_crc = await node.codec.payload_crc(payload)
+                trace["crc_s"] = _time.perf_counter() - t_crc
                 # else: computed under the overlap window below
 
         fwd_task: asyncio.Task | None = None
@@ -546,9 +548,13 @@ class StorageService:
         try:
             if overlap and payload_crc is None and payload and \
                     io.update_type in (UpdateType.WRITE, UpdateType.REPLACE):
+                t_crc = _time.perf_counter()
                 payload_crc = await node.codec.payload_crc(payload)
+                trace["crc_s"] = _time.perf_counter() - t_crc
+            t_engine = _time.perf_counter()
             result = await target.run_update(
                 target.replica.apply_update, io, payload, payload_crc)
+            trace["engine_s"] = _time.perf_counter() - t_engine
             trace_add("storage.update.applied", f"ver={io.update_ver}")
         except (OSError, StatusError) as e:
             if fwd_task is not None:
@@ -562,6 +568,8 @@ class StorageService:
             else:
                 result = IOResult(WireStatus(int(e.code), str(e)))
             return result  # _update_to_result records all failures
+        # apply_s holds the CRC only under overlap; off, the CRC (crc_s)
+        # ran before it and apply_s is the engine apply (engine_s)
         trace["apply_s"] = _time.perf_counter() - t_apply
 
         # forward down the chain (tail commits first); under overlap the
@@ -724,14 +732,18 @@ class StorageService:
         ios = (unpack_readios(req.packed_ios, req.packed_ver)
                if req.packed_ios else req.ios)
         sp = tracing.current_span()
+        engine_s = 0.0          # summed over the IOs
         if sp is not None:
             # total payload bytes: lets the health rollup bucket this
             # span's latency into the client's read size classes
             sp.set_tag("bytes", sum(io.length for io in ios))
 
         async def one(io: ReadIO) -> tuple[IOResult, bytes | None]:
+            nonlocal engine_s
             try:
+                t = _time.perf_counter()
                 result, data = await self._read_one(io)
+                engine_s += _time.perf_counter() - t
                 if io.no_payload:
                     return result, b""   # verify-only: status travels, bytes don't
                 if io.buf is not None:
@@ -744,6 +756,8 @@ class StorageService:
                         None if io.buf is not None else b"")
 
         pairs = await asyncio.gather(*(one(io) for io in ios))
+        if sp is not None:
+            sp.set_tag("engine_s", engine_s)
         results = [r for r, _ in pairs]
         inline_parts = [d for _, d in pairs if d is not None]
         if req.want_packed:

@@ -37,8 +37,6 @@ from typing import Any
 
 from t3fs.utils.config import ConfigBase, cchoice, citem
 
-_points: contextvars.ContextVar["Points | None"] = contextvars.ContextVar(
-    "t3fs_trace_points", default=None)
 _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "t3fs_trace_span", default=None)
 
@@ -91,6 +89,17 @@ def get_config() -> TraceConfig:
 
 def _slow_s(method: str) -> float:
     return _slow_by_method.get(method, _cfg.slow_ms / 1000.0)
+
+
+def sampling_on() -> bool:
+    """Head sampling records at all (sample_rate > 0)."""
+    return _cfg.sample_rate > 0.0
+
+
+def head_sample() -> bool:
+    """One head-sampling decision: True with probability sample_rate."""
+    rate = _cfg.sample_rate
+    return rate > 0.0 and (rate >= 1.0 or random.random() < rate)
 
 
 def _new_id() -> int:
@@ -232,8 +241,7 @@ def start_root(name: str, *, force: bool | None = None, **tags) -> _SpanScope:
     if _current.get() is not None:
         return span(name, **tags)
     if force is None:
-        rate = _cfg.sample_rate
-        if rate <= 0.0 or (rate < 1.0 and random.random() >= rate):
+        if not head_sample():
             return _SpanScope(None)
     elif not force:
         return _SpanScope(None)
@@ -334,6 +342,18 @@ class SpanBuffer:
             if self._op % 64 == 0 or self._buffered > _cfg.max_spans:
                 self._prune(now)
 
+    def on_lone_root(self, row: dict) -> None:
+        """A finished span that is a whole trace by itself (no children
+        can follow) and times no request: exported at once under
+        export=all, else forgotten — never buffered, and never promoted by
+        the tail rule, so a long idle wait cannot crowd slow requests out
+        of the export queue."""
+        with self._lock:
+            self.finished += 1
+            if _cfg.export == "all":
+                self.promoted += 1
+                self._push_export(row)
+
     def _push_export(self, row: dict) -> None:
         while len(self._export) >= _cfg.export_max:
             self._export.popleft()
@@ -396,57 +416,20 @@ def reset_tracing() -> None:
     BUFFER.reset()
 
 
-# ------------------------------------------------- legacy flat trace API
-
-@dataclass
-class Points:
-    """One request's trace: (monotonic ts, event, detail) triples."""
-    events: list[tuple[float, str, str]] = field(default_factory=list)
-    t0: float = field(default_factory=time.perf_counter)
-
-    def add(self, event: str, detail: str = "") -> None:
-        self.events.append((time.perf_counter() - self.t0, event, detail))
-
-    def spans(self) -> list[tuple[str, float]]:
-        """(event, seconds-since-previous-event) decomposition."""
-        out, prev = [], 0.0
-        for ts, event, _ in self.events:
-            out.append((event, ts - prev))
-            prev = ts
-        return out
-
-
-def start_trace() -> Points:
-    """Begin a request scope; returns the live point buffer.  The token
-    is kept so end_trace restores the OUTER scope instead of clobbering
-    it with None (nested scopes keep their enclosing trace)."""
-    p = Points()
-    p._token = _points.set(p)
-    return p
-
-
-def current_trace() -> Points | None:
-    return _points.get()
+def record_root(name: str, t0: float, dur_s: float, **tags) -> None:
+    """Finish a local span that is its own trace, timed by the caller (t0
+    wall-clock start, dur_s), from any thread: the loop meter's
+    `loop.wait` and ECCodec's `ec.flush`.  Callers make a head-sampling
+    decision first, so this costs nothing while tracing is off."""
+    BUFFER.on_lone_root({
+        "trace_id": _new_id(), "span_id": _new_id(), "parent_id": 0,
+        "name": name, "kind": "local", "t0": t0, "dur_s": dur_s,
+        "status": 0, "tags": tags, "events": [], "root": True})
 
 
 def add_event(event: str, detail: str = "") -> None:
-    """TRACING_ADD_EVENT analog — attaches to the active span AND the
-    legacy point buffer; no-op when neither scope is active."""
-    p = _points.get()
-    if p is not None:
-        p.add(event, detail)
+    """TRACING_ADD_EVENT analog: attaches to the active span; no-op when
+    no span is active (the request is unsampled or tracing is off)."""
     sp = _current.get()
     if sp is not None:
         sp.add_event(event, detail)
-
-
-def end_trace() -> Points | None:
-    p = _points.get()
-    if p is None:
-        return None
-    token = getattr(p, "_token", None)
-    if token is not None:
-        _points.reset(token)
-    else:
-        _points.set(None)
-    return p

@@ -1,7 +1,6 @@
-"""Tracing points + structured trace log -> Parquet.
+"""Structured trace log -> Parquet.
 
-Reference analogs: common/utils/Tracing.h (request-scoped points),
-src/analytics/StructuredTraceLog.h (serde objects -> Parquet row groups),
+Reference analogs: src/analytics/StructuredTraceLog.h (serde objects -> Parquet row groups),
 StorageEventTrace per update (StorageOperator.h:153).
 """
 
@@ -12,36 +11,6 @@ import tempfile
 from t3fs.analytics.trace_log import (
     StorageEventTrace, StructuredTraceLog, read_trace,
 )
-from t3fs.utils import tracing
-
-
-def test_trace_points_scoped():
-    assert tracing.current_trace() is None
-    tracing.add_event("ignored.outside.scope")  # no-op, no crash
-    p = tracing.start_trace()
-    tracing.add_event("step.a")
-    tracing.add_event("step.b", "detail")
-    got = tracing.end_trace()
-    assert got is p
-    assert [e for _, e, _ in got.events] == ["step.a", "step.b"]
-    spans = got.spans()
-    assert spans[0][0] == "step.a" and all(dt >= 0 for _, dt in spans)
-    assert tracing.current_trace() is None
-
-
-def test_trace_points_isolated_across_tasks():
-    async def task(name, n):
-        tracing.start_trace()
-        for i in range(n):
-            tracing.add_event(f"{name}.{i}")
-            await asyncio.sleep(0)
-        return tracing.end_trace()
-
-    async def body():
-        a, b = await asyncio.gather(task("a", 3), task("b", 2))
-        assert [e for _, e, _ in a.events] == ["a.0", "a.1", "a.2"]
-        assert [e for _, e, _ in b.events] == ["b.0", "b.1"]
-    asyncio.run(body())
 
 
 def test_structured_trace_log_roundtrip():

@@ -6,6 +6,7 @@ switch); the CPU path replaced is folly::crc32c (fbs/storage/Common.h:158).
 
 import asyncio
 import os
+import time
 
 import numpy as np
 import pytest
@@ -215,3 +216,46 @@ def test_compile_cache_placement(tmp_path, env_dir):
         assert os.listdir(used), "no compiled program landed in the env dir"
     else:
         assert used == default
+
+
+def test_device_backend_wait_counters(monkeypatch):
+    """A caller under a span gets crc_queue_s (entry to its launch) and
+    crc_result_s (launch to the caller resuming), inside its wall time;
+    an untraced caller's rows are never timed."""
+    from t3fs.storage import codec_backend
+    from t3fs.utils import tracing
+
+    made, real = [], codec_backend._Pending
+
+    def pending(*a):
+        made.append(real(*a))
+        return made[-1]
+
+    monkeypatch.setattr(codec_backend, "_Pending", pending)
+    datas = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+             for n in (512, 700, 2048, 4096)]
+
+    async def traced(b, d):
+        t0 = time.perf_counter()
+        with tracing.start_root("op") as sp:
+            assert await b.payload_crc(d) == crc32c_ref(d)
+        return sp.tags, time.perf_counter() - t0
+
+    async def body():
+        b = DeviceChecksumBackend(min_device_bytes=0, max_wait_us=2000,
+                                  max_batch=16, interpret_on_cpu=True)
+        try:
+            await asyncio.gather(*(b.payload_crc(d) for d in datas))
+            assert len(made) == len(datas)
+            assert all(it.t0 == it.t_launch == 0.0 for it in made)
+
+            tracing.configure(tracing.TraceConfig(sample_rate=1.0))
+            got = await asyncio.gather(*(traced(b, d) for d in datas))
+            assert b.batched_items == 2 * len(datas)
+            for tags, wall in got:
+                assert tags["crc_queue_s"] >= 0 and tags["crc_result_s"] > 0
+                assert tags["crc_queue_s"] + tags["crc_result_s"] <= wall
+        finally:
+            tracing.reset_tracing()
+            await b.close()
+    run(body())

@@ -144,3 +144,47 @@ def test_warmup_decode_precompiles_recv_keys(interpret_env):
         codec.warmup_decode(patterns, L, k=k, m=m)
 
     asyncio.run(body())
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["off", "sampled"])
+def test_ec_codec_wait_counters(sampled):
+    """While head sampling is on, each launch finishes one `ec.flush`
+    span: the codec thread's time in it, inside the wall time, tagged
+    with its rows and their summed queue wait (submit to flush).  Off,
+    no span is recorded and no row is timed."""
+    from t3fs.utils import tracing
+
+    k, m, L = 8, 2, 2048
+    stripes = [rng.integers(0, 256, (k, L), dtype=np.uint8)
+               for _ in range(3)]
+
+    async def body():
+        codec = ECCodec(max_wait_us=2000)
+        try:
+            t0 = asyncio.get_running_loop().time()
+            await asyncio.gather(*(codec.encode_verified(s, k, m)
+                                   for s in stripes))
+            return codec, asyncio.get_running_loop().time() - t0
+        finally:
+            await codec.close()
+
+    if sampled:
+        tracing.configure(tracing.TraceConfig(sample_rate=1.0,
+                                              export="all"))
+    try:
+        codec, wall = asyncio.run(body())
+        flushes = [s for s in tracing.BUFFER.drain()
+                   if s["name"] == "ec.flush"]
+        finished = tracing.BUFFER.stats()["finished"]
+    finally:
+        tracing.reset_tracing()
+    assert codec.batched_items == len(stripes)
+    if not sampled:
+        assert flushes == [] and finished == 0
+        return
+    assert len(flushes) == codec.batches
+    assert sum(s["tags"]["rows"] for s in flushes) == len(stripes)
+    assert {s["tags"]["op"] for s in flushes} == {"encv"}
+    assert 0 < sum(s["dur_s"] for s in flushes) <= wall
+    assert 0 < sum(s["tags"]["queue_s"] for s in flushes) \
+        <= len(stripes) * wall

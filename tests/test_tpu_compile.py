@@ -110,3 +110,29 @@ def test_msr_repair_program_1mib(compile_v5e):
     code = default_msr()
     run = make_msr_repair_step(code, 0, MiB, use_pallas=True)
     compile_v5e(run.program, (N, code.d, MiB // 2 // 4))
+
+
+@pytest.mark.parametrize("kernel, program", [
+    ("crc32c_seg_words", "raw_crc"),
+    ("rs_encode_words", "encode"),
+    ("rs_decode_words", "decode"),
+])
+def test_pallas_kernels_carry_their_names(compile_v5e, kernel, program):
+    """Each served Pallas call is named, so a profile's operation names
+    tell the CRC, encode and decode kernels apart inside one jitted step;
+    the jitted programs keep the names the benchmark's roofline readers
+    match (`jit_raw_crc`, `jit_step`)."""
+    from t3fs.ops.pallas_codec import (make_crc32c_words_raw,
+                                       make_stripe_decode_step_words,
+                                       make_stripe_encode_step_words)
+    W = 64 << 10                  # one 256 KiB chunk: names, not speed
+    fn, shape, module = {
+        "raw_crc": (make_crc32c_words_raw(W), (4, W), "jit_raw_crc"),
+        "encode": (make_stripe_encode_step_words(W), (2, 8, W), "jit_step"),
+        "decode": (make_stripe_decode_step_words(W, _present((0, 1)),
+                                                 (0, 1)),
+                   (2, 8, W), "jit_step"),
+    }[program]
+    text = compile_v5e(fn, shape).as_text()
+    assert f"HloModule {module}," in text
+    assert f"%{kernel}" in text

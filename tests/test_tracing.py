@@ -28,6 +28,10 @@ from t3fs.utils.tracing import (
 )
 
 
+# slack between two perf_counter intervals read a few calls apart
+EPS_S = 1e-3
+
+
 def run(coro):
     return asyncio.run(coro)
 
@@ -78,25 +82,12 @@ def test_nested_start_root_joins_active_trace():
 
 def test_add_event_attaches_to_active_span_and_points():
     configure(TraceConfig(sample_rate=1.0, export="all"))
-    points = tracing.start_trace()
+    tracing.add_event("outside")          # no active span: a no-op
     with tracing.start_root("op") as sp:
         tracing.add_event("both", "detail")
-    tracing.end_trace()
-    assert [e[1] for e in points.events] == ["both"]
-    assert [e[1] for e in sp.events] == ["both"]
-
-
-def test_legacy_point_scope_nesting_restores_outer():
-    outer = tracing.start_trace()
-    inner = tracing.start_trace()
-    inner.add("inner.ev")
-    assert tracing.end_trace() is inner
-    # the satellite fix: end_trace restores the OUTER scope via the
-    # contextvar token instead of setting None
-    assert tracing.current_trace() is outer
-    tracing.add_event("outer.ev")
-    assert tracing.end_trace() is outer
-    assert [e[1] for e in outer.events] == ["outer.ev"]
+    assert [e[1:] for e in sp.events] == [("both", "detail")]
+    assert not any(hasattr(tracing, n)
+                   for n in ("Points", "current_trace", "end_trace"))
 
 
 # ---- head sampling: off means zero overhead ----
@@ -229,12 +220,86 @@ def test_chain_write_is_one_trace_across_all_hops():
     # the server spans (the tail's forward_s times the no-successor probe)
     assert all("apply_s" in s["tags"] for s in servers)
     assert all("forward_s" in s["tags"] for s in servers)
+    # ... and the finer split: each client leg's send, each hop's frame
+    # read, CRC and engine apply.  The default pipeline (off) awaits the
+    # CRC before the apply interval, which then holds the engine alone.
+    legs = [r for r in trace if r["kind"] == "client"
+            and r["name"].startswith("rpc.Storage.")]
+    assert len(legs) == 3
+    assert all(0 <= c["tags"]["send_s"] <= c["dur_s"] for c in legs)
+    for s in servers:
+        t = s["tags"]
+        assert 0 <= t["frame_s"] <= t["wire_s"] + EPS_S
+        assert t["crc_s"] >= 0
+        assert 0 <= t["engine_s"] <= t["apply_s"] + EPS_S
 
     text = render_trace(trace)
     assert f"trace {tid:#x}" in text
     assert text.count("[server]") == 3
-    for token in ("wire=", "queue=", "apply=", "forward="):
+    for token in ("send=", "wire=", "frame=", "queue=", "crc=", "apply=",
+                  "engine=", "forward="):
         assert token in text
+
+
+def _sampled_chain_write(**fabric_kw) -> list[dict]:
+    """One sampled 3-replica chain write; the server spans of its trace."""
+    configure(TraceConfig(sample_rate=1.0, export="all"))
+
+    async def body():
+        fabric = StorageFabric(num_nodes=3, replicas=3, **fabric_kw)
+        await fabric.start()
+        try:
+            sc = StorageClient(lambda: fabric.routing, client=fabric.client)
+            lay = FileLayout(chunk_size=4096, chains=[fabric.chain_id])
+            await sc.write_file_range(lay, inode=7, offset=0,
+                                      data=b"x" * 1000)
+        finally:
+            await fabric.stop()
+
+    run(body())
+    return [r for r in _drain_all() if r["kind"] == "server"]
+
+
+def test_overlap_write_apply_holds_crc_and_engine():
+    # under the overlap pipeline a non-tail hop awaits its CRC inside the
+    # apply interval, so crc_s and engine_s are its parts
+    servers = _sampled_chain_write(write_pipeline="overlap")
+    assert len(servers) == 3
+    forwarding = [s["tags"] for s in servers
+                  if "forward_status" in s["tags"]]
+    assert len(forwarding) == 2                      # head and mid
+    for t in forwarding:
+        assert t["crc_s"] + t["engine_s"] <= t["apply_s"] + EPS_S
+
+
+def test_unsampled_chain_write_ships_default_envelopes(monkeypatch):
+    sent = []
+    real = Connection._send_frame
+
+    async def spy(self, packet, payload, flags):
+        sent.append(packet)
+        await real(self, packet, payload, flags)
+
+    monkeypatch.setattr(Connection, "_send_frame", spy)
+
+    async def body():
+        fabric = StorageFabric(num_nodes=3, replicas=3)
+        await fabric.start()
+        try:
+            sc = StorageClient(lambda: fabric.routing, client=fabric.client)
+            lay = FileLayout(chunk_size=4096, chains=[fabric.chain_id])
+            await sc.write_file_range(lay, inode=7, offset=0,
+                                      data=b"x" * 1000)
+        finally:
+            await fabric.stop()
+
+    run(body())
+    writes = [p for p in sent if p.method.startswith("Storage.")]
+    assert writes
+    assert all(p.trace_id == 0 and p.parent_span_id == 0 and not p.sampled
+               for p in sent)
+    # no span opened anywhere, so no tag was set on one
+    assert BUFFER.stats()["finished"] == 0
 
 
 # ---- tail sampling ----
@@ -287,6 +352,23 @@ def test_late_spans_of_promoted_trace_export_directly():
     rows = _drain_all()
     assert {r["name"] for r in rows} == {"root.op", "late.leg"}
     assert rows[-1]["trace_id"] == root.trace_id
+
+
+def test_lone_root_spans_skip_the_buffer():
+    # record_root: a finished span that is a whole trace (loop.wait,
+    # ec.flush) exports at once under export=all, else is forgotten: the
+    # tail rule is for requests, and a long idle wait is none
+    configure(TraceConfig(sample_rate=1.0, export="tail", slow_ms=100.0))
+    tracing.record_root("loop.wait", 1.0, 0.001, wait_s=0.5)
+    tracing.record_root("loop.wait", 2.0, 0.2, wait_s=0.7)
+    assert BUFFER.stats()["buffered"] == 0
+    assert BUFFER.pending_export() == 0
+    assert BUFFER.stats()["finished"] == 2
+    configure(TraceConfig(sample_rate=1.0, export="all"))
+    tracing.record_root("ec.flush", 3.0, 0.2, rows=1)
+    (row,) = _drain_all()
+    assert row["root"] and row["parent_id"] == 0 and row["kind"] == "local"
+    assert (row["t0"], row["dur_s"], row["tags"]) == (3.0, 0.2, {"rows": 1})
 
 
 # ---- SpanBuffer bounds ----
