@@ -14,7 +14,11 @@ what the harness does not pass to a metric reader:
   anchor), and the loop's wait inside each of the ten longest idle gaps;
 - with --trace 0 --sample-rate R: the process's tracing set to head
   sampling at R with tail export from the window's start on, so the
-  rate at R costs what tracing costs a deployment that samples R.
+  rate at R costs what tracing costs a deployment that samples R;
+- the asyncio frame reader's counters over the window (`net.rx.frames`,
+  `net.rx.direct_bodies`, `net.rx.recv_calls`, absent where the program
+  has no such counters): reads per frame, and direct body reads per
+  acknowledged operation.
 
     python3 benchmarks/perfbench_host_probe.py --workload craq3_4m_write \\
         --seed 7 --seconds 20 --trace 1 --out probe.json
@@ -107,8 +111,14 @@ def main(argv=None) -> int:
         os.makedirs(cache, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache)
 
-    got: dict = {"loop": []}
+    got: dict = {"loop": [], "rx": []}
     counters = harness._counters
+    try:
+        from t3fs.net import conn as net_conn
+        rx = {k: getattr(net_conn, f"RX_{k.upper()}")
+              for k in ("frames", "direct_bodies", "recv_calls")}
+    except AttributeError:
+        rx = {}
 
     def counters_and_loop(dep):
         # called at the window's edges (and the profile window's): the
@@ -119,6 +129,9 @@ def main(argv=None) -> int:
         m = loop_meter.install()
         got["loop"].append((m.busy_s, m.wait_s, m.recorded_wait_s,
                             m.iterations))
+        # each collect returns the count since the one before: the
+        # first call's is before the window
+        got["rx"].append({k: c.collect()["value"] for k, c in rx.items()})
         return counters(dep)
 
     harness._counters = counters_and_loop
@@ -174,10 +187,20 @@ def main(argv=None) -> int:
         probe["loop_busy_share"] = 100 * busy / (busy + wait)
         probe["loop_wait_coverage"] = (rw1 - rw0) / wait if wait else None
         probe["loop_iterations_per_s"] = (i1 - i0) / (busy + wait)
+    if rx and len(got["rx"]) >= 2:
+        for k in rx:
+            probe[f"rx_{k}"] = sum(d[k] for d in got["rx"][1:])
+        if probe["rx_frames"]:
+            probe["rx_recv_calls_per_frame"] = (probe["rx_recv_calls"]
+                                                / probe["rx_frames"])
     r = got.get("r")
     if r is not None:
         op = "read" if r.op_stats("read") else "write"
         probe["MBps"] = readings.mb_per_s(r, op)
+        acked = sum(s.ok for s in r.op_stats(op))
+        if rx and acked:
+            probe["rx_direct_bodies_per_op"] = (probe["rx_direct_bodies"]
+                                                / acked)
         if "summary" in got:
             waits = [s for s in r.spans if s["name"] == "loop.wait"]
             probe.update(_trace_numbers(got, waits))

@@ -66,15 +66,16 @@ class Client:
                 return conn
             host, port = address.rsplit(":", 1)
             try:
-                reader, writer = await asyncio.wait_for(
-                    asyncio.open_connection(host, int(port)), self.connect_timeout)
+                _, conn = await asyncio.wait_for(
+                    asyncio.get_running_loop().create_connection(
+                        lambda: Connection(
+                            self.dispatcher, name=f"cli->{address}",
+                            compress_threshold=self.compress_threshold),
+                        host, int(port)),
+                    self.connect_timeout)
             except (OSError, asyncio.TimeoutError) as e:
                 raise make_error(StatusCode.RPC_CONNECT_FAILED,
                                  f"connect {address}: {e}") from None
-            conn = Connection(reader, writer, self.dispatcher,
-                              name=f"cli->{address}",
-                              compress_threshold=self.compress_threshold)
-            conn.start()
             self._conns[address] = conn
             self._epochs[address] = self._epochs.get(address, 0) + 1
             return conn

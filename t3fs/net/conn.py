@@ -5,6 +5,13 @@ common/net/Processor.h:28-50 (decode -> dispatch), common/net/Waiter
 (uuid -> coroutine wakeup).  Unlike the reference's client->server-only RPC
 plus one-sided RDMA verbs, a t3fs connection lets EITHER side issue requests:
 that is the TCP emulation of RDMA READ/WRITE (see net/__init__ docstring).
+
+A Connection is the asyncio protocol of its socket.  Inbound frames are
+cut out of a per-connection staging buffer, several per ``recv_into``;
+a msg or payload past ``BULK_BYTES`` gets a buffer of its own, and the
+kernel writes the part of it not yet staged straight there (see
+``get_buffer``), so a multi-MiB body costs no pause/resume cycles of the
+transport and no copies beyond its staged prefix.
 """
 
 from __future__ import annotations
@@ -23,25 +30,45 @@ from t3fs.net.wire import (
 from t3fs.net.rpcstats import RPC_STATS, SERVER_STATS
 from t3fs.ops.codec import crc32c
 from t3fs.utils import serde, tracing
+from t3fs.utils.metrics import CountRecorder
 from t3fs.utils.status import Status, StatusCode, StatusError, make_error
 
 log = logging.getLogger("t3fs.net")
+
+# inbound engagement, every asyncio Connection of the process together:
+# recv_calls / frames is the reads per frame; a large body read straight
+# into its own buffer counts once in direct_bodies
+RX_FRAMES = CountRecorder("net.rx.frames")
+RX_DIRECT_BODIES = CountRecorder("net.rx.direct_bodies")
+RX_RECV_CALLS = CountRecorder("net.rx.recv_calls")
 
 # handler(body, payload, conn) -> (rsp_body, rsp_payload)
 Handler = Callable[[object, bytes, "Connection"], Awaitable[tuple[object, bytes]]]
 
 
-class Connection:
-    """One duplex framed stream; safe for concurrent calls."""
+class Connection(asyncio.BufferedProtocol):
+    """One duplex framed stream; safe for concurrent calls.  It is the
+    protocol of its asyncio transport: ``loop.create_connection`` and
+    ``loop.create_server`` take a factory that returns a Connection."""
 
     _uuid_counter = itertools.count(1)
 
-    def __init__(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter,
-                 dispatcher: dict[str, Handler] | None = None, name: str = "?",
+    # frames past this size compress/decompress in a worker thread so a
+    # multi-MiB zlib pass never stalls the event loop (heartbeats, other
+    # conns); below it the thread hop costs more than the compression
+    OFFLOAD_BYTES = 1 << 20
+    # a payload past this ships as a write of its own, and a msg or
+    # payload past it arrives into a buffer of its own (no staging copy)
+    BULK_BYTES = 64 << 10
+    # inbound staging per connection: small frames are parsed out of it
+    # several per recv_into.  Twice BULK_BYTES, so a part that waits
+    # staged always fits once the staged bytes move to the front
+    STAGE_BYTES = 2 * BULK_BYTES
+
+    def __init__(self, dispatcher: dict[str, Handler] | None = None,
+                 name: str = "?",
                  on_close: Callable[["Connection"], None] | None = None,
                  compress_threshold: int = 0, compress_level: int = 1):
-        self.reader = reader
-        self.writer = writer
         self.dispatcher = dispatcher if dispatcher is not None else {}
         self.name = name
         self.on_close = on_close
@@ -57,12 +84,27 @@ class Connection:
         self._waiters: dict[int, asyncio.Future] = {}
         self._send_lock = asyncio.Lock()
         self._closed = False
-        self._loop_task: asyncio.Task | None = None
         # asyncio holds only weak refs to tasks; keep handlers alive here
         self._tasks: set[asyncio.Task] = set()
-
-    def start(self) -> None:
-        self._loop_task = asyncio.create_task(self._read_loop(), name=f"conn-{self.name}")
+        self.transport: asyncio.Transport | None = None
+        self._lost: asyncio.Future | None = None   # done at connection_lost
+        self._write_paused = False
+        self._drain_waiter: asyncio.Future | None = None
+        # inbound framing (get_buffer, buffer_updated, _parse): the staged
+        # bytes are _stage[_lo:_hi]; _frame is the current frame's
+        # [head_ts, msg_len, payload_len, flags, msg_crc] once its header
+        # is parsed, _parts its msg and payload as they complete; _part
+        # is a part the socket reads straight into, _part_n its fill
+        self._stage: bytearray | None = None
+        self._stage_mv: memoryview | None = None
+        self._lo = self._hi = 0
+        self._frame: list | None = None
+        self._parts: list = []
+        self._part: bytearray | None = None
+        self._part_n = 0
+        # a frame is being verified off the loop: reading is paused and
+        # later frames wait staged, so dispatch keeps the wire's order
+        self._held = False
 
     @property
     def closed(self) -> bool:
@@ -78,18 +120,17 @@ class Connection:
         if self._closed:
             return
         self._closed = True
-        if self._loop_task:
-            self._loop_task.cancel()
         if self.on_close is not None:
             try:
                 self.on_close(self)
             except Exception:
                 pass
-        try:
-            self.writer.close()
-            await self.writer.wait_closed()
-        except Exception:
-            pass
+        if self.transport is not None:
+            try:
+                self.transport.close()
+                await self._lost
+            except Exception:
+                pass
         err = make_error(StatusCode.RPC_SEND_FAILED, f"connection {self.name} closed")
         for fut in self._waiters.values():
             if not fut.done():
@@ -101,10 +142,52 @@ class Connection:
                 fut.exception()
         self._waiters.clear()
 
-    # frames past this size compress/decompress in a worker thread so a
-    # multi-MiB zlib pass never stalls the event loop (heartbeats, other
-    # conns); below it the thread hop costs more than the compression
-    OFFLOAD_BYTES = 1 << 20
+    # --- transport callbacks ---
+
+    def connection_made(self, transport: asyncio.Transport) -> None:
+        self.transport = transport
+        self._lost = asyncio.get_running_loop().create_future()
+        self._stage = bytearray(self.STAGE_BYTES)
+        self._stage_mv = memoryview(self._stage)
+        if self.name.endswith("<-"):
+            # accepted by Server, which names it "srv<-": add the peer
+            self.name += str(transport.get_extra_info("peername"))
+
+    def connection_lost(self, exc: Exception | None) -> None:
+        if not self._lost.done():
+            self._lost.set_result(None)
+        waiter, self._drain_waiter = self._drain_waiter, None
+        if waiter is not None and not waiter.done():
+            if exc is None:
+                waiter.set_result(None)
+            else:
+                waiter.set_exception(exc)
+        # EOF or reset, mid-frame or not; a frame held off the loop is
+        # dispatched first, and closes the connection after it
+        if not self._held and not self._closed:
+            self._spawn(self.close(), f"close-{self.name}")
+
+    def pause_writing(self) -> None:
+        self._write_paused = True
+
+    def resume_writing(self) -> None:
+        self._write_paused = False
+        waiter, self._drain_waiter = self._drain_waiter, None
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
+
+    async def _drain(self) -> None:
+        """StreamWriter.drain's semantics: wait while the transport holds
+        more than its high-water mark; raise once the connection is lost."""
+        if self.transport.is_closing():
+            # let connection_lost run, so a lost connection raises below
+            await asyncio.sleep(0)
+        if self._lost.done():
+            raise ConnectionResetError("connection lost")
+        if not self._write_paused:
+            return
+        self._drain_waiter = asyncio.get_running_loop().create_future()
+        await self._drain_waiter
 
     async def _prep_frame(self, packet: MessagePacket, payload: bytes,
                           flags: int) -> tuple[bytes, bytes, bytes]:
@@ -143,19 +226,17 @@ class Connection:
                 # each attempt an immediate send when the transport buffer
                 # is empty, tripling the syscall count per frame (profiled
                 # at ~30% of client CPU on the multi-process path).  Big
-                # payloads are worth a copy-free second write.
-                if payload and len(payload) > 64 << 10:
-                    self.writer.write(head + msg)
-                    self.writer.write(payload)
-                elif payload and not isinstance(payload, bytes):
-                    # forwarded zero-copy RX memoryview: bytes.__add__
-                    # rejects it, so ship it as a second write
-                    self.writer.write(head + msg)
-                    self.writer.write(payload)
+                # payloads, and forwarded RX buffers (a bytearray, or the
+                # native pump's memoryview), are worth a copy-free second
+                # write.
+                if payload and (len(payload) > self.BULK_BYTES
+                                or not isinstance(payload, bytes)):
+                    self.transport.write(head + msg)
+                    self.transport.write(payload)
                 else:
-                    self.writer.write(head + msg + payload)
-                await self.writer.drain()
-            except (OSError, asyncio.IncompleteReadError) as e:
+                    self.transport.write(head + msg + payload)
+                await self._drain()
+            except OSError as e:
                 raise make_error(StatusCode.RPC_SEND_FAILED,
                                  f"send on {self.name}: {e}") from None
 
@@ -221,45 +302,150 @@ class Connection:
         finally:
             self._waiters.pop(uuid, None)
 
-    async def _read_loop(self) -> None:
+    # --- inbound frames ---
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        """Where the transport's next recv_into writes: the rest of a
+        part being read straight into its own buffer, else the staging
+        buffer's free tail (never empty: see _parse)."""
+        if self._part is not None:
+            return memoryview(self._part)[self._part_n:]
+        return self._stage_mv[self._hi:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        RX_RECV_CALLS.add()
+        if self._part is not None:
+            self._part_n += nbytes
+            if self._part_n < len(self._part):
+                return
+            self._parts.append(self._part)
+            self._part = None
+        else:
+            self._hi += nbytes
+        self._parse()
+
+    def _parse(self) -> None:
+        """Dispatch every frame complete in what has arrived, in order.
+        A msg or payload past BULK_BYTES gets a bytearray of exactly its
+        size, never reused (a forwarding hop hands the same object to
+        transport.write): the staged prefix is copied in, and get_buffer
+        then points the socket at the rest of it."""
         try:
-            while True:
-                head = await self.reader.readexactly(HEADER_SIZE)
-                head_ts = time.time()
-                msg_len, payload_len, flags, msg_crc = unpack_header(head)
-                msg = await self.reader.readexactly(msg_len) if msg_len else b""
-                payload = await self.reader.readexactly(payload_len) if payload_len else b""
-                if flags & FLAG_COMPRESS:
-                    # always off-thread: on-wire size says nothing about
-                    # decompressed size (a zeros-heavy 256 MiB frame can
-                    # arrive <1 MiB), and the hop is cheap vs any zlib pass
-                    def _verify_inflate(m=msg, p=payload, f=flags, c=msg_crc):
-                        check_msg_crc(m, c)   # CRC covers on-wire bytes
-                        return decompress_frame(m, p, f)
-                    msg, payload = await asyncio.to_thread(_verify_inflate)
-                elif msg_len >= self.OFFLOAD_BYTES:
-                    await asyncio.to_thread(check_msg_crc, msg, msg_crc)
+            while not self._held and self._part is None:
+                if self._frame is None:
+                    if self._hi - self._lo < HEADER_SIZE:
+                        break
+                    head_ts = time.time()
+                    # magic, header CRC and MAX_FRAME are checked here,
+                    # before anything is allocated from the lengths
+                    self._frame = [head_ts, *unpack_header(bytes(
+                        self._stage_mv[self._lo:self._lo + HEADER_SIZE]))]
+                    self._lo += HEADER_SIZE
+                    continue
+                if len(self._parts) == 2:
+                    self._complete_frame()
+                    continue
+                n = self._frame[1 + len(self._parts)]   # msg_len, payload_len
+                staged = self._hi - self._lo
+                if n > self.BULK_BYTES:
+                    part = bytearray(n)
+                    k = min(staged, n)
+                    memoryview(part)[:k] = self._stage_mv[self._lo:self._lo + k]
+                    self._lo += k
+                    if k < n:
+                        RX_DIRECT_BODIES.add()
+                        self._part, self._part_n = part, k
+                        break
+                    self._parts.append(part)
+                elif n <= staged:
+                    self._parts.append(
+                        bytes(self._stage_mv[self._lo:self._lo + n]))
+                    self._lo += n
                 else:
-                    check_msg_crc(msg, msg_crc)
-                self._dispatch_packet(serde.loads(msg), payload, head_ts)
-        except (asyncio.IncompleteReadError, ConnectionResetError, BrokenPipeError):
-            pass
-        except asyncio.CancelledError:
-            raise
+                    break
         except FrameError as e:
             log.warning("conn %s: frame error: %s", self.name, e)
+            self._abort()
+            return
         except Exception:
-            log.exception("conn %s: read loop died", self.name)
-        finally:
+            log.exception("conn %s: frame dispatch failed", self.name)
+            self._abort()
+            return
+        if self._lo == self._hi:
+            self._lo = self._hi = 0
+        elif not self._held and len(self._stage) - self._hi < self.BULK_BYTES:
+            # what is staged is less than one waiting part (<= BULK_BYTES)
+            # or header: move it to the front (a memoryview assignment
+            # memmoves), so the rest of it fits behind
+            n = self._hi - self._lo
+            self._stage_mv[:n] = self._stage_mv[self._lo:self._hi]
+            self._lo, self._hi = 0, n
+
+    def _complete_frame(self) -> None:
+        (head_ts, _, _, flags, msg_crc), (msg, payload) = \
+            self._frame, self._parts
+        self._frame, self._parts = None, []
+        RX_FRAMES.add()
+        if flags & FLAG_COMPRESS or len(msg) >= self.OFFLOAD_BYTES:
+            # verify (and inflate) in a worker thread; reading pauses so
+            # the frames behind this one wait staged, in order
+            self._held = True
+            self.transport.pause_reading()
+            self._spawn(self._verify_off_loop(head_ts, flags, msg_crc, msg,
+                                              payload),
+                        f"verify-{self.name}")
+            return
+        check_msg_crc(msg, msg_crc)
+        self._dispatch_packet(serde.loads(msg), payload, head_ts)
+
+    async def _verify_off_loop(self, head_ts: float, flags: int, msg_crc: int,
+                               msg, payload) -> None:
+        try:
+            if flags & FLAG_COMPRESS:
+                # always off-thread: on-wire size says nothing about
+                # decompressed size (a zeros-heavy 256 MiB frame can
+                # arrive <1 MiB), and the hop is cheap vs any zlib pass
+                def _verify_inflate():
+                    check_msg_crc(msg, msg_crc)   # CRC covers on-wire bytes
+                    return decompress_frame(msg, payload, flags)
+                msg, payload = await asyncio.to_thread(_verify_inflate)
+            else:
+                await asyncio.to_thread(check_msg_crc, msg, msg_crc)
             if not self._closed:
-                self._spawn(self.close(), f"close-{self.name}")
+                self._dispatch_packet(serde.loads(msg), payload, head_ts)
+        except FrameError as e:
+            log.warning("conn %s: frame error: %s", self.name, e)
+            self._abort()
+            return
+        except Exception:
+            log.exception("conn %s: frame dispatch failed", self.name)
+            self._abort()
+            return
+        finally:
+            self._held = False
+        if self._closed:
+            return
+        self._parse()
+        if self._closed or self._held:
+            return
+        if self._lost.done():
+            await self.close()      # EOF came while the frame was held
+        else:
+            self.transport.resume_reading()
+
+    def _abort(self) -> None:
+        """A frame the connection cannot get past: stop reading now, and
+        close both ways."""
+        if not self._closed:
+            self.transport.close()
+            self._spawn(self.close(), f"close-{self.name}")
 
     def _dispatch_packet(self, packet: MessagePacket, payload: bytes,
                          head_ts: float = 0.0) -> None:
-        """Post-decode dispatch shared by the asyncio read loop and the
+        """Post-decode dispatch shared by the asyncio reader and the
         native-pump path: spawn the handler for requests (stamping the
         receive time), wake the waiter for responses.  head_ts is when
-        the read loop had the frame's header (0: the native pump, which
+        the reader had the frame's header (0: the native pump, which
         reads frames off the loop)."""
         if packet.is_req:
             self._spawn(self._handle_request(packet, payload, time.time(),

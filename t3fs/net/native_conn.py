@@ -7,11 +7,12 @@ net_pump.cpp) drives RECV/SEND for every connection in the process; the
 pump thread parses t3f2 frames and verifies BOTH CRCs in C++, and the
 asyncio loop is woken once per batch of completed frames through an
 eventfd.  Python keeps serde, dispatch, and compression; it no longer
-pays per-frame readexactly/header/CRC work or a send syscall per frame.
+pays per-frame read/header/CRC work or a send syscall per frame.
 
 Opt-in per process with T3FS_NATIVE_NET=1 (checked per connection, so
-tests can flip it) — the asyncio StreamReader/Writer transport stays the
-default and the two interoperate byte-for-byte (same wire format).
+tests can flip it) — the asyncio transport, with Connection as its
+protocol, stays the default and the two interoperate byte-for-byte (same
+wire format).
 """
 
 from __future__ import annotations
@@ -289,13 +290,13 @@ class NativePump:
 class NativeConnection(Connection):
     """Connection whose wire runs through the native pump.  Reuses the
     base class's call()/waiter table and request dispatch; overrides the
-    byte-moving halves (read loop and frame send)."""
+    byte-moving halves (frame reading and frame send)."""
 
     def __init__(self, sock: socket.socket, pump: NativePump,
                  dispatcher=None, name: str = "?", on_close=None,
                  compress_threshold: int = 0, compress_level: int = 1):
-        super().__init__(None, None, dispatcher, name, on_close,
-                         compress_threshold, compress_level)
+        super().__init__(dispatcher, name, on_close, compress_threshold,
+                         compress_level)
         self.sock = sock
         self.pump = pump
         self.conn_id = 0
@@ -357,7 +358,7 @@ class NativeConnection(Connection):
                 # the pump saw the peer die before our eventfd callback
                 # ran: close NOW so the caller's retry reconnects instead
                 # of re-hitting the dead conn (the asyncio path gets the
-                # same effect from its read loop exiting).
+                # same effect from its transport's connection_lost).
                 # NOTE an end-of-tick TX-coalescing variant (batch every
                 # frame of a loop tick into one submission) measured
                 # SLOWER here: the extra payload copy into the staging

@@ -65,7 +65,8 @@ class Server:
         if native_enabled():
             await self._start_native()
             return
-        self._server = await asyncio.start_server(self._on_conn, self.host, self.port)
+        self._server = await asyncio.get_running_loop().create_server(
+            self._accept, self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
         log.info("server listening on %s:%d (%d methods)",
                  self.host, self.port, len(self.dispatcher))
@@ -121,15 +122,16 @@ class Server:
                 log.exception("native accept of %s failed", peer)
                 sock.close()
 
-    async def _on_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
-        peer = writer.get_extra_info("peername")
-        conn = Connection(reader, writer, self.dispatcher, name=f"srv<-{peer}",
+    def _accept(self) -> Connection:
+        """Protocol factory: one Connection per accepted socket (it adds
+        the peer to its name when the transport is made)."""
+        conn = Connection(self.dispatcher, name="srv<-",
                           on_close=self._conns.discard,
                           compress_threshold=self.compress_threshold)
         # server spans carry the serving node's address (tracing)
         conn.local_address = self.address
         self._conns.add(conn)
-        conn.start()
+        return conn
 
     async def stop(self) -> None:
         # close live connections BEFORE wait_closed(): since 3.12,

@@ -173,6 +173,20 @@ class ChunkReplica:
             raise make_error(StatusCode.CHECKSUM_MISMATCH,
                              f"{io.chunk_id}: payload crc {payload_crc:#x} != {io.checksum:#x}")
 
+        if io.update_type != UpdateType.TRUNCATE and io.offset == 0 \
+                and len(payload) >= (meta.length if meta else 0):
+            # the write covers the whole chunk: its content is the payload
+            # as it arrived (an RX frame's bytearray is never reused), its
+            # checksum the payload's; the old bytes are neither read nor
+            # copied
+            new = ChunkMeta(io.chunk_id, len(payload), io.update_ver,
+                            cur_commit, io.chain_ver, payload_crc,
+                            ChunkState.DIRTY)
+            self.engine.put(io.chunk_id, payload, new,
+                            io.chunk_size or len(payload))
+            return IOResult(WireStatus(), new.length, new.update_ver,
+                            new.commit_ver, new.chain_ver, new.checksum)
+
         old = self.engine.read(io.chunk_id) if meta else b""
 
         if io.update_type == UpdateType.TRUNCATE:

@@ -38,7 +38,9 @@ def _bind():
     lib.t3fs_ce_close.argtypes = [C.c_void_p]
     lib.t3fs_ce_last_error.restype = C.c_char_p
     lib.t3fs_ce_last_error.argtypes = [C.c_void_p]
-    lib.t3fs_ce_put.argtypes = [C.c_void_p, C.c_char_p, C.c_char_p,
+    # content as c_void_p: bytes, or a ctypes view over a writable
+    # buffer (an RX frame's bytearray), with no copy (_c_buffer)
+    lib.t3fs_ce_put.argtypes = [C.c_void_p, C.c_char_p, C.c_void_p,
                                 C.c_uint64, C.c_uint64, C.POINTER(_CeMeta)]
     lib.t3fs_ce_read.argtypes = [C.c_void_p, C.c_char_p, C.c_uint64,
                                  C.c_uint64, C.c_void_p,
@@ -86,20 +88,27 @@ def native_lib():
     return _libholder[0]
 
 
-def crc32c_native(data, crc: int = 0) -> int:
-    """Hardware (SSE4.2) CRC32C — the CPU-side checksum oracle/fast path.
-    Accepts any bytes-like input; bytes and writable buffers (incl. the
-    net pump's zero-copy RX memoryviews) pass WITHOUT a staging copy —
-    the old bytes(data) here was a hidden per-payload copy on the write
-    path (r5 zero-copy audit)."""
+def _c_buffer(data) -> tuple[object, int]:
+    """(c_void_p argument, length) for any bytes-like input: bytes and
+    writable buffers (an RX frame's bytearray, the net pump's zero-copy
+    memoryviews) pass WITHOUT a staging copy; only a readonly view that
+    is not bytes is copied."""
     if isinstance(data, bytes):
-        return native_lib().t3fs_crc32c(data, len(data), crc)
+        return data, len(data)
     mv = data if isinstance(data, memoryview) else memoryview(data)
     if mv.readonly:
         b = bytes(mv)
-        return native_lib().t3fs_crc32c(b, len(b), crc)
-    arr = (C.c_ubyte * mv.nbytes).from_buffer(mv)
-    return native_lib().t3fs_crc32c(arr, mv.nbytes, crc)
+        return b, len(b)
+    return (C.c_ubyte * mv.nbytes).from_buffer(mv), mv.nbytes
+
+
+def crc32c_native(data, crc: int = 0) -> int:
+    """Hardware (SSE4.2) CRC32C — the CPU-side checksum oracle/fast path.
+    Accepts any bytes-like input without a copy (_c_buffer) — the old
+    bytes(data) here was a hidden per-payload copy on the write path
+    (r5 zero-copy audit)."""
+    buf, n = _c_buffer(data)
+    return native_lib().t3fs_crc32c(buf, n, crc)
 
 
 def crc32c_combine_native(a: int, b: int, len_b: int) -> int:
@@ -235,9 +244,10 @@ class NativeChunkEngine:
 
     def put(self, chunk_id: ChunkId, content: bytes, meta: ChunkMeta,
             chunk_size: int) -> None:
-        cm = _meta_to_c(meta, length=len(content))
-        r = self._lib.t3fs_ce_put(self._handle(), chunk_id.encode(), bytes(content),
-                                  len(content), chunk_size, C.byref(cm))
+        buf, n = _c_buffer(content)
+        cm = _meta_to_c(meta, length=n)
+        r = self._lib.t3fs_ce_put(self._handle(), chunk_id.encode(), buf,
+                                  n, chunk_size, C.byref(cm))
         if r != 1:
             raise self._io_error("put failed")
 

@@ -5,8 +5,10 @@ state machine (connect/handshake/send/recv/teardown races).  Where the
 reference checks an abstract model, this simulator drives the REAL
 ``t3fs.net.conn.Connection`` on both ends of an in-memory byte pipe whose
 delivery the scheduler fully controls: bytes move only when the schedule
-pumps them, in chunk sizes the schedule picks, with optional mid-frame
-cuts and single-byte corruption.  Every interleaving the scheduler
+pumps them, in chunk sizes the schedule picks, through the protocol's own
+``get_buffer``/``buffer_updated`` (the real frame parser, as a socket
+transport's ``recv_into`` drives it), with optional mid-frame cuts and
+single-byte corruption.  Every interleaving the scheduler
 produces is one a real TCP socket could produce (arbitrary segmentation,
 torn frames, resets), so invariant violations here are real protocol bugs.
 
@@ -30,35 +32,49 @@ from t3fs.net.conn import Connection
 from t3fs.utils.status import StatusError
 
 
-class SimWriter:
-    """Just enough asyncio.StreamWriter for Connection: written bytes go
-    to an outbox the SCHEDULER pumps into the peer's reader."""
+class SimTransport:
+    """Just enough asyncio.Transport for Connection: written bytes go to
+    an outbox the SCHEDULER pumps into the peer's protocol."""
 
     def __init__(self, name: str):
         self.name = name
         self.outbox = bytearray()
-        self.closed = False
-        self.peer_reader: asyncio.StreamReader | None = None
+        self.closed = False      # close() ran: connection_lost follows
+        self.reset = False       # cut: writes fail, the protocol not told
+        self.paused = False      # the protocol paused reading
+        self.protocol: Connection | None = None
+        self.peer: SimTransport | None = None
 
-    def write(self, data: bytes) -> None:
-        if self.closed:
+    def write(self, data) -> None:
+        if self.closed or self.reset:
             raise ConnectionResetError("write after close")
         self.outbox += data
 
-    async def drain(self) -> None:
-        if self.closed:
-            raise ConnectionResetError("drain after close")
+    def is_closing(self) -> bool:
+        return self.closed
+
+    def pause_reading(self) -> None:
+        self.paused = True
+
+    def resume_reading(self) -> None:
+        self.paused = False
 
     def close(self) -> None:
+        if self.closed:
+            return
         self.closed = True
+        asyncio.get_running_loop().call_soon(self.protocol.connection_lost,
+                                             None)
         # model FIN: the peer's read side sees EOF once our end closes
-        if self.peer_reader is not None and \
-                not getattr(self.peer_reader, "_sim_eof", False):
-            self.peer_reader._sim_eof = True
-            self.peer_reader.feed_eof()
+        self.peer.feed_eof()
 
-    async def wait_closed(self) -> None:
-        return
+    def feed_eof(self) -> None:
+        """EOF reached this end (the peer's FIN, or a reset): as a socket
+        transport does, tell the protocol, then close."""
+        if self.closed:
+            return
+        if not self.protocol.eof_received():
+            self.close()
 
     def get_extra_info(self, key, default=None):
         return default
@@ -66,37 +82,41 @@ class SimWriter:
 
 @dataclass
 class SimLink:
-    """One direction of the pipe: a's outbox -> b's reader."""
-    writer: SimWriter
-    reader: asyncio.StreamReader
+    """One direction of the pipe: tx's outbox -> rx's protocol."""
+    tx: SimTransport
+    rx: SimTransport
     delivered: int = 0
 
     def pump(self, n: int) -> int:
-        """Deliver up to n pending bytes; returns bytes moved."""
-        chunk = bytes(self.writer.outbox[:n])
-        if not chunk:
+        """Deliver up to n pending bytes through ONE get_buffer /
+        buffer_updated, as one recv_into would; returns bytes moved.
+        Bytes wait in flight while the receiver has paused reading."""
+        n = min(n, len(self.tx.outbox))
+        if not n or (self.rx.paused and not self.rx.closed):
             return 0
-        del self.writer.outbox[:n]
-        if getattr(self.reader, "_sim_eof", False):
-            return 0                       # receiver already saw FIN: drop
-        self.reader.feed_data(chunk)
-        self.delivered += len(chunk)
-        return len(chunk)
+        if self.rx.closed:
+            del self.tx.outbox[:n]
+            return 0                       # receiver already closed: drop
+        buf = self.rx.protocol.get_buffer(n)
+        n = min(n, len(buf))
+        buf[:n] = self.tx.outbox[:n]
+        del self.tx.outbox[:n]
+        self.rx.protocol.buffer_updated(n)
+        self.delivered += n
+        return n
 
     def corrupt_next(self) -> bool:
         """Flip one bit of the next undelivered byte (header or body)."""
-        if not self.writer.outbox:
+        if not self.tx.outbox:
             return False
-        self.writer.outbox[0] ^= 0x40
+        self.tx.outbox[0] ^= 0x40
         return True
 
     def cut(self) -> None:
         """Drop everything in flight and EOF the receiver (TCP RST)."""
-        self.writer.outbox.clear()
-        self.writer.closed = True
-        if not getattr(self.reader, "_sim_eof", False):
-            self.reader._sim_eof = True
-            self.reader.feed_eof()
+        self.tx.outbox.clear()
+        self.tx.reset = True
+        self.rx.feed_eof()
 
 
 class SimPair:
@@ -104,24 +124,24 @@ class SimPair:
 
     def __init__(self, dispatcher_a=None, dispatcher_b=None,
                  compress_threshold: int = 0):
-        ra, rb = asyncio.StreamReader(), asyncio.StreamReader()
-        wa, wb = SimWriter("a->b"), SimWriter("b->a")
-        wa.peer_reader, wb.peer_reader = rb, ra
-        self.ab = SimLink(wa, rb)
-        self.ba = SimLink(wb, ra)
-        self.a = Connection(ra, wa, dispatcher_a, name="sim-a",
+        ta, tb = SimTransport("a->b"), SimTransport("b->a")
+        ta.peer, tb.peer = tb, ta
+        self.ab = SimLink(ta, tb)
+        self.ba = SimLink(tb, ta)
+        self.a = Connection(dispatcher_a, name="sim-a",
                             compress_threshold=compress_threshold)
-        self.b = Connection(rb, wb, dispatcher_b, name="sim-b",
+        self.b = Connection(dispatcher_b, name="sim-b",
                             compress_threshold=compress_threshold)
-        self.a.start()
-        self.b.start()
+        for conn, t in ((self.a, ta), (self.b, tb)):
+            t.protocol = conn
+            conn.connection_made(t)
 
     async def settle(self) -> None:
         """Let spawned tasks run until no link has pending bytes and the
         event loop is idle for a tick."""
         for _ in range(50):
             await asyncio.sleep(0)
-        while self.ab.writer.outbox or self.ba.writer.outbox:
+        while self.ab.tx.outbox or self.ba.tx.outbox:
             self.ab.pump(1 << 20)
             self.ba.pump(1 << 20)
             for _ in range(50):
@@ -137,17 +157,19 @@ class SimPair:
         for conn in (self.a, self.b):
             assert not conn._waiters, \
                 f"{conn.name}: leaked waiters {list(conn._waiters)}"  # C1
-            live = [t for t in conn._tasks if not t.done()
-                    and t is not conn._loop_task]
+            live = [t for t in conn._tasks if not t.done()]
             assert not live, f"{conn.name}: leaked handler tasks {live}"  # C2
 
 
 async def run_schedule(seed: int, calls: int = 20, cut_after: int | None = None,
                        corrupt_after: int | None = None,
-                       compress_threshold: int = 0) -> dict:
+                       compress_threshold: int = 0,
+                       max_payload: int = 4096) -> dict:
     """One schedule: issue `calls` concurrent echo calls in BOTH directions
     while pumping bytes in random-sized chunks; optionally cut or corrupt
-    the a->b link after N pump steps.  Returns counters for assertions."""
+    the a->b link after N pump steps.  Payloads are 1..max_payload bytes
+    (past Connection.BULK_BYTES a body is read into its own buffer).
+    Returns counters for assertions."""
     rng = random.Random(seed)
 
     async def echo(body, payload, conn):
@@ -162,7 +184,7 @@ async def run_schedule(seed: int, calls: int = 20, cut_after: int | None = None,
     async def one_call(conn, i):
         try:
             rsp, pay = await conn.call("Sim.echo", None,
-                                       payload=bytes([i % 256]) * rng.randint(1, 4096),
+                                       payload=bytes([i % 256]) * rng.randint(1, max_payload),
                                        timeout=5.0)
             return ("ok", pay)
         except StatusError as e:

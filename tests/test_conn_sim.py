@@ -7,6 +7,7 @@ import asyncio
 
 import pytest
 
+from t3fs.net.conn import Connection
 from t3fs.testing.conn_sim import SimPair, run_schedule
 
 
@@ -14,35 +15,42 @@ def run(coro):
     return asyncio.run(coro)
 
 
-def test_arbitrary_segmentation_delivers_all_frames():
-    """C4: duplex calls complete intact under 1..1M-byte delivery chunks."""
+@pytest.mark.parametrize("max_payload", [4096, 3 * Connection.BULK_BYTES])
+def test_arbitrary_segmentation_delivers_all_frames(max_payload):
+    """C4: duplex calls complete intact under 1..1M-byte delivery chunks,
+    small bodies staged and large ones read into their own buffers."""
     async def body():
         for seed in range(8):
-            r = await run_schedule(seed, calls=16)
+            r = await run_schedule(seed, calls=16, max_payload=max_payload)
             assert r["ok"] == 32 and r["err"] == 0, (seed, r)
             assert r["payload_ok"], seed
     run(body())
 
 
-def test_cut_mid_stream_fails_pending_cleanly():
+@pytest.mark.parametrize("max_payload", [4096, 3 * Connection.BULK_BYTES])
+def test_cut_mid_stream_fails_pending_cleanly(max_payload):
     """C3: a reset mid-schedule errors every unfinished call, hangs none,
-    leaks nothing."""
+    leaks nothing (with large bodies, also mid-way through one read into
+    its own buffer)."""
     async def body():
         saw_err = False
         for seed in range(8):
-            r = await run_schedule(seed, calls=16, cut_after=5)
+            r = await run_schedule(seed, calls=16, cut_after=5,
+                                   max_payload=max_payload)
             assert r["ok"] + r["err"] == 32, (seed, r)
             saw_err |= r["err"] > 0
         assert saw_err, "cut schedules never produced an error?"
     run(body())
 
 
-def test_corruption_closes_and_fails_cleanly():
+@pytest.mark.parametrize("max_payload", [4096, 3 * Connection.BULK_BYTES])
+def test_corruption_closes_and_fails_cleanly(max_payload):
     """A flipped bit in flight must surface as clean connection failure
     (header CRC / frame error), never a hang or a wrong payload."""
     async def body():
         for seed in range(8):
-            r = await run_schedule(seed, calls=12, corrupt_after=3)
+            r = await run_schedule(seed, calls=12, corrupt_after=3,
+                                   max_payload=max_payload)
             assert r["ok"] + r["err"] == 24, (seed, r)
             # only the single flipped frame may pass (payload region is
             # app-checksummed, not wire-checksummed); envelope corruption
@@ -97,7 +105,7 @@ def test_close_during_inflight_handler():
 
 def test_timeout_then_late_response_ignored():
     """A response landing after the caller timed out must be dropped
-    without touching a new call's waiter or crashing the read loop."""
+    without touching a new call's waiter or crashing the reader."""
     async def body():
         async def slow(body_, payload, conn):
             return None, b"slow-reply"

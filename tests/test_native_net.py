@@ -253,7 +253,7 @@ def test_native_server_kills_conn_on_garbage(monkeypatch):
 
 def test_native_transport_fragmented_frames(monkeypatch):
     """Frames arriving one byte at a time must reassemble in the pump's
-    staging buffer exactly like the asyncio readexactly path."""
+    staging buffer exactly like the asyncio frame reader."""
     import socket
 
     monkeypatch.setenv("T3FS_NATIVE_NET", "1")

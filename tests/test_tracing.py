@@ -99,7 +99,7 @@ def test_unsampled_root_does_no_work_and_ships_default_envelope():
         assert sp is NULL_SPAN and not sp
         assert tracing.current_span() is None
         pkt = MessagePacket(uuid=7, method="Echo.echo")
-        Connection(None, None)._stamp_trace(pkt)
+        Connection()._stamp_trace(pkt)
     # the envelope is byte-identical to one built with tracing never
     # touched: zero extra wire state for unsampled requests
     assert serde.dumps(pkt) == baseline
@@ -112,7 +112,7 @@ def test_sampled_stamp_rides_the_envelope_and_roundtrips():
     configure(TraceConfig(sample_rate=1.0))
     with tracing.start_root("client.op") as sp:
         pkt = MessagePacket(uuid=7, method="Echo.echo")
-        Connection(None, None)._stamp_trace(pkt)
+        Connection()._stamp_trace(pkt)
     assert pkt.trace_id == sp.trace_id
     assert pkt.parent_span_id == sp.span_id and pkt.sampled
     back = serde.loads(serde.dumps(pkt))
