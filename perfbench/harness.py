@@ -4,7 +4,8 @@ call it on the CPU, with the codecs under the Pallas interpreter.
 
 Set-up, in order (all of it counts in setup_s): the data from --seed;
 warm-up of the codec programs the cell runs, from the compile cache in the
-checkout; the cluster; the fill of what the traffic reads; the mix's fault;
+checkout; the cluster, whose EC layout has to store the parity code the
+configuration names; the fill of what the traffic reads; the mix's fault;
 gc.freeze().  Then the window, timed by the host clock; with --trace 1 a
 JAX profiler window of a few seconds sits in its middle and every program
 span is exported.  After the window: the device's peak memory, a read-back
@@ -98,6 +99,7 @@ async def _run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     cfg = {**cell.config, **config_overrides}
     mix = load_mix(cell.mix_path)
     ec = cfg["layout"] == "ec"
+    code = verify.ec_code(cfg) if ec else None
     cs = cfg["chunk_size"]
     phases: dict[str, float] = {}
     compiles = CompileLog()
@@ -161,6 +163,12 @@ async def _run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     prof: dict = {}
     try:
         await dep.start()
+        if ec and dep.ec_layout.code_id != code:
+            # before the window: no run is judged against another
+            # code's parity
+            raise ValueError(
+                f"the layout stores parity of code {dep.ec_layout.code_id!r}"
+                f", the configuration names {code!r}")
         t = phase("cluster", t)
         load = Load(mix, dep, payloads, seed)
         await load.fill()
@@ -280,6 +288,9 @@ async def _run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             f"{len(lag.samples)} ticks")
     for st in probe.stalls:
         log(f"loop stall: {json.dumps(st)}")
+    if codec is not None:
+        log(f"ECCodec implementations, set-up and window: "
+            f"{codec.codec_counts}")
     log(f"window {window_s:.3f} s; codec counters {_delta(k0, k1)}; "
         f"compared: {counts.get('copies_checked', 0)} copies, "
         f"{counts.get('answers_checked', 0)} answers")

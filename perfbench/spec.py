@@ -1,7 +1,12 @@
 """One cell of BENCHMARK.json with the files it names: the configuration,
 the traffic mix (perfbench/traffic/<traffic>.json) and a reader for each
 metric the cell reports (perfbench/end_to_end/<name>.py,
-perfbench/layer_metrics/<name>.py)."""
+perfbench/layer_metrics/<name>.py).
+
+Which metrics a cell reports is BENCHMARK.json's to say: a metric with a
+`workloads` list in the cells it names, one without in every cell that
+reports the metric it `moves`.  A new cell is its files and entries there,
+its name in the lists of the metrics it reports; no code here changes."""
 
 from __future__ import annotations
 
@@ -47,5 +52,6 @@ def load_cell(root: str, name: str) -> Cell:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, name, names)]
     return Cell(name=name, chips=w["chips"], config=config,
-                mix_path=os.path.join(HERE, "traffic", w["traffic"] + ".json"),
+                mix_path=os.path.join(root, "perfbench", "traffic",
+                                      w["traffic"] + ".json"),
                 end_to_end=e2e, per_layer=per_layer)

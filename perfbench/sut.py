@@ -4,9 +4,11 @@ configuration file, and the client calls the traffic makes on it.
 `layout` "craq": `chains` CRAQ chains of `replicas` targets (chain c on
 nodes c..c+replicas-1), one object = one chunk, written with
 StorageClient.write_chunk and read with a one-IO StorageClient.batch_read.
-`layout` "ec": `nodes` single-replica EC chains, one object = one RS(k+m)
-stripe of k chunks, written with ECStorageClient.write_stripe and read
-with ECStorageClient.read_stripe_with_crcs.
+`layout` "ec": `chains` single-replica EC chains on `nodes` nodes, one
+object = one RS(k+m) stripe of k chunks (any k and m; its parity code is
+the program's for that k and m, `ECLayout.code_id`), written with
+ECStorageClient.write_stripe and read with
+ECStorageClient.read_stripe_with_crcs.
 """
 
 from __future__ import annotations

@@ -4,8 +4,9 @@ Every number here is a count of comparisons against the plain reference
 (perfbench.reference) that came out wrong; each has the limit 0, since
 the comparison is exact.  Copies are what the nodes store once the window
 has closed, read straight from each node: every replica of a CRAQ chunk,
-all k+m shards (parity included) of an RS stripe.  Answers are the bytes
-and CRC32Cs that reads returned in the window (a seeded sample).
+all k+m shards (parity included) of an RS(k+m) stripe, its parity that of
+the code the configuration names (`ec_code`).  Answers are the bytes and
+CRC32Cs that reads returned in the window (a seeded sample).
 """
 
 from __future__ import annotations
@@ -24,11 +25,29 @@ LIMITS = {
 }
 
 
+def ec_code(cfg: dict) -> str:
+    """The parity code an EC configuration names under `code`.  Without
+    the key, m = 2 means RAID-6 P and Q, what the first EC configurations
+    store; any other m has to name its code."""
+    code = cfg.get("code")
+    if code is None:
+        if cfg["m"] != 2:
+            raise ValueError(
+                f"an RS({cfg['k']}+{cfg['m']}) configuration has to name its "
+                f"parity under the key `code`, one of {reference.CODES}")
+        code = reference.RAID6
+    if code not in reference.CODES:
+        raise ValueError(f"the configuration's `code` {code!r} is none of "
+                         f"{reference.CODES}")
+    return code
+
+
 def expected_chunks(cfg: dict, content: bytes) -> list[bytes]:
     """What each stored copy of an object holds: the same bytes on every
     replica, or the stripe's data and parity shards."""
     if cfg["layout"] == "ec":
-        return reference.stripe_shards(content, cfg["k"], cfg["m"])
+        return reference.stripe_shards(content, cfg["k"], cfg["m"],
+                                       ec_code(cfg))
     return [content] * cfg["replicas"]
 
 
