@@ -28,7 +28,7 @@ import numpy as np
 
 from t3fs.client.ec_codec import ECCodec
 from t3fs.ops.msr import default_msr, msr_code_id
-from t3fs.ops.rs import default_rs
+from t3fs.ops.rs import RSCode, rs_code, stored_code
 from t3fs.storage.types import ChunkId, IOResult, ReadIO, UpdateType
 from t3fs.utils import tracing
 from t3fs.utils.serde import serde_struct
@@ -78,9 +78,10 @@ class ECLayout:
     chains: list[int] = field(default_factory=list)   # >= k+m distinct chains
     # parity format id (RSCode.code_id): persisted with the layout so a
     # change of generator coefficients fails LOUDLY at decode time instead
-    # of silently reconstructing garbage from old parity.  Dataclass default
+    # of silently reconstructing garbage from old parity; every codec call
+    # of the layout runs the code it names (`rs`).  Dataclass default
     # (= what a pre-versioning serialized layout deserializes to) is the
-    # LEGACY id; new layouts get the current id via create().
+    # LEGACY id; new layouts get rs.stored_code(k, m) via create().
     code_id: str = LEGACY_CODE_ID
     # Opt-in LRC local parities (ROADMAP item 4, "regenerating/LRC-style"):
     # "" = pure RS(k+m) (every pre-existing layout deserializes to this);
@@ -129,16 +130,18 @@ class ECLayout:
     def create(cls, k: int = 8, m: int = 2, chunk_size: int = 1 << 20,
                chains: list[int] | None = None, local_scheme: str = "",
                local_group_size: int = 3) -> "ECLayout":
-        """Layout-creation factory: stamps the CURRENT parity format id
-        (the pm-msr coupled generator has its OWN id — its parity bytes
-        are not plain RS parity)."""
+        """Layout-creation factory: stamps the parity format id a new
+        RS(k+m) layout stores (rs.stored_code: HDFS's Cauchy parity for its
+        built-in RS(6+3) and RS(10+4) policies; the pm-msr coupled
+        generator has its OWN id — its parity bytes are not plain RS
+        parity)."""
         if local_scheme == "pm-msr":
             try:
                 code_id = msr_code_id(k, m)
             except ValueError as e:
                 raise make_error(StatusCode.INVALID_ARG, str(e)) from e
         else:
-            code_id = default_rs(k, m).code_id
+            code_id = stored_code(k, m)
         return cls(k=k, m=m, chunk_size=chunk_size, chains=chains or [],
                    code_id=code_id,
                    local_scheme=local_scheme,
@@ -176,6 +179,12 @@ class ECLayout:
                 return g
         raise make_error(StatusCode.INVALID_ARG,
                          f"shard {shard} has no local group")
+
+    @property
+    def rs(self) -> RSCode:
+        """The RS code this layout's parity was written with; raises
+        EC_FORMAT_MISMATCH for an id this build cannot decode."""
+        return rs_code(self.k, self.m, self.code_id)
 
     def check_code(self, rs) -> None:
         if rs.code_id != self.code_id:
@@ -305,45 +314,31 @@ class ECStorageClient:
     # one kernel launch on the codec's own thread (XLA compile takes
     # seconds and compute releases the GIL — nothing blocks the loop).
 
-    async def _encode(self, data_shards: np.ndarray, k: int, m: int) -> np.ndarray:
-        if self.codec is not None:
-            return await self.codec.encode(data_shards, k, m)
-        return await asyncio.to_thread(default_rs(k, m).encode_ref,
-                                       data_shards)
-
-    async def _encode_verified(self, data_shards: np.ndarray, k: int, m: int
+    async def _encode_verified(self, data_shards: np.ndarray, rs: RSCode
                                ) -> tuple[np.ndarray, np.ndarray | None]:
         """Encode + shard CRCs in ONE device launch (the fused encode+CRC
         step); the numpy oracle has no fused CRC, so it returns None and
         callers fall back to the host crc32c."""
         if self.codec is not None:
-            return await self.codec.encode_verified(data_shards, k, m)
-        return await self._encode(data_shards, k, m), None
-
-    async def _reconstruct(self, present_rows: np.ndarray,
-                           present: tuple[int, ...], want: tuple[int, ...],
-                           k: int, m: int) -> np.ndarray:
-        if self.codec is not None:
-            return await self.codec.reconstruct(present_rows, present, want,
-                                                k, m)
-
-        def run():
-            shards = {idx: present_rows[i] for i, idx in enumerate(present)}
-            return default_rs(k, m).decode_ref(shards, list(want))
-        return await asyncio.to_thread(run)
+            return await self.codec.encode_verified(
+                data_shards, rs.k, rs.m, code=rs.code_id)
+        return await asyncio.to_thread(rs.encode_ref, data_shards), None
 
     async def _reconstruct_verified(self, present_rows: np.ndarray,
                                     present: tuple[int, ...],
-                                    want: tuple[int, ...], k: int, m: int
+                                    want: tuple[int, ...], rs: RSCode
                                     ) -> tuple[np.ndarray, np.ndarray | None]:
         """Decode + shard CRCs in ONE device launch (the fused
         decode+verify step); the numpy oracle has no fused CRC, so it
         returns None and callers fall back to the host crc32c."""
         if self.codec is not None:
             return await self.codec.reconstruct_verified(
-                present_rows, present, want, k, m)
-        return await self._reconstruct(present_rows, present, want,
-                                       k, m), None
+                present_rows, present, want, rs.k, rs.m, code=rs.code_id)
+
+        def run():
+            shards = {idx: present_rows[i] for i, idx in enumerate(present)}
+            return rs.decode_ref(shards, list(want))
+        return await asyncio.to_thread(run), None
 
     async def _msr_encode_verified(self, data_shards: np.ndarray, k: int,
                                    m: int
@@ -410,8 +405,8 @@ class ECStorageClient:
             layout.check_code(default_msr(k, m))
             parity, dev_crcs = await self._msr_encode_verified(arr, k, m)
         else:
-            layout.check_code(default_rs(k, m))
-            parity, dev_crcs = await self._encode_verified(arr, k, m)
+            layout.check_code(layout.rs)
+            parity, dev_crcs = await self._encode_verified(arr, layout.rs)
 
         from t3fs.ops.codec import crc32c
         contents: list[bytes] = []
@@ -705,7 +700,7 @@ class ECStorageClient:
         full-chunk content when that step produced the shard, else None.
         Want-shards already in `have` pass through without decoding."""
         msr = layout.local_scheme == "pm-msr"
-        layout.check_code(default_msr(k, m) if msr else default_rs(k, m))
+        layout.check_code(default_msr(k, m) if msr else layout.rs)
         # shards recovered directly need no decoding
         still_want = tuple(s for s in want if s not in have)
         decoded: dict[int, bytes] = {}
@@ -721,7 +716,7 @@ class ECStorageClient:
                     rows, present, still_want, k, m)
             else:
                 out, crcs = await self._reconstruct_verified(
-                    rows, present, still_want, k, m)
+                    rows, present, still_want, layout.rs)
             decoded = {s: bytes(out[i]) for i, s in enumerate(still_want)}
             if crcs is not None:
                 # fused-step layout: k survivor CRCs, then the rebuilt
@@ -827,19 +822,18 @@ class ECStorageClient:
         def rank(x: int) -> tuple:
             return (x not in zero_shards, x not in pref, x)
         present = sorted(survivors, key=rank)[:k]
-        row = default_rs(k, m).reconstruct_gfmatrix(sorted(present), [s])[0]
+        row = layout.rs.reconstruct_gfmatrix(sorted(present), [s])[0]
         return [(p, int(c)) for p, c in zip(sorted(present), row)
                 if c and p not in zero_shards]
 
     async def _repair_eval(self, rows: np.ndarray, coeffs: tuple[int, ...],
-                           k: int, m: int) -> tuple[bytes, int]:
+                           rs: RSCode) -> tuple[bytes, int]:
         if self.codec is not None:
-            out, crc = await self.codec.repair(rows, coeffs, k, m)
+            out, crc = await self.codec.repair(rows, coeffs, rs.k, rs.m)
             return bytes(out), int(crc)
         from t3fs.ops.codec import crc32c
         from t3fs.ops.repair_program import (eval_program_np,
                                              schedule_repair_program)
-        rs = default_rs(k, m)
 
         def run():
             out = eval_program_np(schedule_repair_program(coeffs), rows, rs)
@@ -891,7 +885,8 @@ class ECStorageClient:
             bufs[i, hi, : len(p)] = np.frombuffer(p, dtype=np.uint8)
         coeffs = tuple(c for _slot, c in plan)
         parts = await asyncio.gather(
-            *(self._repair_eval(bufs[i], coeffs, k, m) for i in range(r)))
+            *(self._repair_eval(bufs[i], coeffs, layout.rs)
+              for i in range(r)))
         content = b"".join(p for p, _crc in parts)
         crc = parts[0][1]
         for _p, sub_crc in parts[1:]:

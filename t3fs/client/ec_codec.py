@@ -5,20 +5,22 @@ The EC data path once encoded via `jax_codec` (the XLA bit-matmul path,
 routes ECStorageClient's encode and reconstruct through the SAME kernels
 the bench measures:
 
-  - encode: `make_rs_encode_words_pallas` — the RAID-6 SWAR word kernel
-    (P = xor-reduce, Q = g^i multiply-accumulate over uint32 words), the
-    parity half of bench.py's `make_stripe_encode_step_words`;
+  - encode: `make_rs_encode_words_pallas` — the SWAR word kernel (the
+    code's parity rows as a scheduled xtimes/xor program over uint32
+    words), the parity half of bench.py's `make_stripe_encode_step_words`;
   - reconstruct: `make_rs_reconstruct_words_pallas` — the decode-side word
-    kernel (GF(2^8) decode constants as SWAR xtimes/xor chains), with the
-    byte-plane `make_rs_reconstruct_pallas` bit-matmul reachable only as
-    the non-RAID-6 fallback;
+    kernel (GF(2^8) decode constants as the same kind of program), with
+    the byte-plane `make_rs_reconstruct_pallas` bit-matmul reachable only
+    for lengths that are no whole number of words;
   - reconstruct_verified: `make_stripe_decode_step_words` — the fused
     decode+verify step; one launch rebuilds the missing shards AND returns
     CRC32Cs of survivors + rebuilt, so degraded reads/repair pay no
     per-shard CPU crc32c after the device round trip.
 
-`jax_codec` stays as the oracle and the fallback for non-RAID-6 (k, m)
-codes (the word kernels are m=2-specific).  Platform dispatch
+Every call names its parity code (`code`, an RSCode.code_id; default
+`rs.stored_code(k, m)`, what a new layout of that shape stores), and the
+code is part of each jit-cache key.  `jax_codec` stays as the oracle and
+the fallback for lengths the word kernels cannot take.  Platform dispatch
 (interpreted Pallas as the only CPU path cost a 3-4x regression on CPU
 fabrics): a TPU gets the Pallas word kernels; any other platform gets
 the compiled XLA bit-matmul path, with T3FS_FORCE_PALLAS_INTERPRET=1
@@ -77,17 +79,23 @@ def _set_exception_safe(fut: asyncio.Future, err) -> None:
 
 from t3fs.ops.blocks import batch_ladder, pad_batch
 from t3fs.ops.blocks import pick_block as _pick_block
+from t3fs.ops.rs import rs_code, stored_code
 from t3fs.utils import tracing
 from t3fs.utils.aio import reap_task
+
+
+def _code(k: int, m: int, code: str | None) -> str:
+    return code or stored_code(k, m)
 
 
 class ECCodec:
     """Batched device codec for EC stripes with a per-shape jit cache.
 
-    kind keys: ("enc", k, m, L), ("rec", present, want, k, m, L),
-    ("recv", present, want, k, m, L) — the fused decode+verify step — and
-    ("rep", coeffs, k, m, L) — the scheduled single-row repair program;
-    requests under one key stack into a single kernel call.
+    kind keys: ("enc", k, m, L, code), ("encv", k, m, L, code),
+    ("rec", present, want, k, m, L, code), ("recv", present, want, k, m,
+    L, code) — the fused decode+verify step — and ("rep", coeffs, k, m, L)
+    — the scheduled single-row repair program; requests under one key
+    stack into a single kernel call.
     """
 
     def __init__(self, max_batch: int = 32, max_wait_us: int = 300):
@@ -97,6 +105,9 @@ class ECCodec:
         self._worker: asyncio.Task | None = None
         self._pool = ThreadPoolExecutor(1, thread_name_prefix="t3fs-ec")
         self._fns: dict[tuple, Callable] = {}
+        # extra `ec.flush` tags of an encode or decode key: its code, and
+        # the gf_ops of the word program that serves it
+        self._span_tags: dict[tuple, dict] = {}
         self._interpret: bool | None = None
         self._use_pallas: bool | None = None
         self._closed = False
@@ -112,32 +123,37 @@ class ECCodec:
 
     # --- public API (called from the event loop) ---
 
-    async def encode(self, data_shards: np.ndarray, k: int, m: int
-                     ) -> np.ndarray:
+    async def encode(self, data_shards: np.ndarray, k: int, m: int,
+                     code: str | None = None) -> np.ndarray:
         """(k, L) uint8 data shards -> (m, L) uint8 parity."""
         L = data_shards.shape[-1]
-        return await self._submit(("enc", k, m, L), data_shards)
+        return await self._submit(("enc", k, m, L, _code(k, m, code)),
+                                  data_shards)
 
-    async def encode_verified(self, data_shards: np.ndarray, k: int, m: int
+    async def encode_verified(self, data_shards: np.ndarray, k: int, m: int,
+                              code: str | None = None
                               ) -> tuple[np.ndarray, np.ndarray]:
         """(k, L) uint8 data shards -> (parity (m, L) uint8,
         crcs (k+m,) uint32): parity + CRC32C of every shard (data first,
         then parity) from the SAME device launch — the write path hands
         the CRCs to write_chunk, so no host crc32c runs per shard."""
         L = data_shards.shape[-1]
-        return await self._submit(("encv", k, m, L), data_shards)
+        return await self._submit(("encv", k, m, L, _code(k, m, code)),
+                                  data_shards)
 
     async def reconstruct(self, present_rows: np.ndarray,
                           present: tuple[int, ...], want: tuple[int, ...],
-                          k: int, m: int) -> np.ndarray:
+                          k: int, m: int, code: str | None = None
+                          ) -> np.ndarray:
         """(k, L) uint8 present shards -> (len(want), L) uint8."""
         L = present_rows.shape[-1]
-        return await self._submit(("rec", present, want, k, m, L),
-                                  present_rows)
+        return await self._submit(
+            ("rec", present, want, k, m, L, _code(k, m, code)), present_rows)
 
     async def reconstruct_verified(self, present_rows: np.ndarray,
                                    present: tuple[int, ...],
-                                   want: tuple[int, ...], k: int, m: int
+                                   want: tuple[int, ...], k: int, m: int,
+                                   code: str | None = None
                                    ) -> tuple[np.ndarray, np.ndarray]:
         """(k, L) uint8 present shards -> (rebuilt (len(want), L) uint8,
         crcs (k + len(want),) uint32): decode + CRC32C of survivors (in
@@ -145,8 +161,8 @@ class ECCodec:
         SAME device launch — the degraded-read path pays no per-shard CPU
         crc32c after the round trip."""
         L = present_rows.shape[-1]
-        return await self._submit(("recv", present, want, k, m, L),
-                                  present_rows)
+        return await self._submit(
+            ("recv", present, want, k, m, L, _code(k, m, code)), present_rows)
 
     async def repair(self, helper_rows: np.ndarray, coeffs: tuple[int, ...],
                      k: int = 8, m: int = 2
@@ -298,7 +314,8 @@ class ECCodec:
                 tracing.record_root(
                     "ec.flush", time.time() - flush_s, flush_s, op=key[0],
                     rows=len(items),
-                    queue_s=sum(t_start - it.t0 for it in items if it.t0))
+                    queue_s=sum(t_start - it.t0 for it in items if it.t0),
+                    **self._span_tags.get(key, {}))
 
     # --- kernel selection + jit cache ---
 
@@ -348,20 +365,26 @@ class ECCodec:
         self.codec_counts[codec] = self.codec_counts.get(codec, 0) + 1
         self.last_codec = codec
 
+    def _word_tags(self, key: tuple, rs, rows) -> None:
+        """Tag the key's `ec.flush` spans with the word program's cost."""
+        from t3fs.ops.pallas_codec import gf_words_program
+        self._span_tags[key]["gf_ops"] = gf_words_program(rs, rows).gf_ops
+
     def _build_encode(self, key: tuple) -> Callable:
         import jax
 
         from t3fs.ops import jax_codec
-        from t3fs.ops.rs import default_rs
 
-        _kind, k, m, L = key
-        rs = default_rs(k, m)
-        if self._use_pallas and rs.raid6 and L % 4 == 0:
+        _kind, k, m, L, code = key
+        rs = rs_code(k, m, code)
+        self._span_tags[key] = {"code": code}
+        if self._use_pallas and L % 4 == 0:
             from t3fs.ops.pallas_codec import make_rs_encode_words_pallas
             W = L // 4
             bw = _pick_block(W, 16384)
             raw = jax.jit(make_rs_encode_words_pallas(
                 rs, block_w=bw, interpret=self._interpret))
+            self._word_tags(key, rs, rs.parity_rows)
 
             def encode_words(stacked: np.ndarray) -> np.ndarray:
                 self._count("pallas-words")
@@ -371,8 +394,8 @@ class ECCodec:
                 return out.view(np.uint8).reshape(out.shape[0], m, L)
             return encode_words
 
-        # non-RAID-6 (k, m): XLA bit-matmul fallback (also the oracle)
-        raw = jax_codec.rs_encode_jit(k, m)
+        # off the TPU, or an odd length: XLA bit-matmul (also the oracle)
+        raw = jax.jit(jax_codec.make_rs_encode(rs))
 
         def encode_xla(stacked: np.ndarray) -> np.ndarray:
             self._count("xla-bitmatmul")
@@ -382,19 +405,19 @@ class ECCodec:
     def _build_encode_verified(self, key: tuple) -> Callable:
         """Fused encode+CRC: one launch returns (parity, crcs) where crcs
         covers data shards then parity — the write-path twin of
-        _build_reconstruct_verified.  Word-fused on RAID-6 512-multiple
-        chunks (bench.py's measured stripe step); otherwise an XLA-fused
-        program (still one device round trip, still no CPU crc32c)."""
-        _kind, k, m, L = key
+        _build_reconstruct_verified.  Word-fused on 512-multiple chunks
+        (bench.py's measured stripe step); otherwise an XLA-fused program
+        (still one device round trip, still no CPU crc32c)."""
+        _kind, k, m, L, code = key
         import jax
 
-        from t3fs.ops.rs import default_rs
-
-        rs = default_rs(k, m)
-        if self._use_pallas and rs.raid6 and L % 512 == 0:
+        rs = rs_code(k, m, code)
+        self._span_tags[key] = {"code": code}
+        if self._use_pallas and L % 512 == 0:
             from t3fs.ops.pallas_codec import make_stripe_encode_step_words
             step = jax.jit(make_stripe_encode_step_words(
-                L // 4, k, m, interpret=self._interpret))
+                L // 4, k, m, interpret=self._interpret, code=code))
+            self._word_tags(key, rs, rs.parity_rows)
 
             def encode_words(stacked: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
@@ -429,10 +452,14 @@ class ECCodec:
         return encode_xla
 
     def _build_reconstruct(self, key: tuple) -> Callable:
-        _kind, present, want, k, m, L = key
+        _kind, present, want, k, m, L, code = key
+        rs = rs_code(k, m, code)
+        self._span_tags[key] = {"code": code}
         if not self._use_pallas:
+            import jax
+
             from t3fs.ops import jax_codec
-            raw = jax_codec.rs_reconstruct_jit(present, want, k, m)
+            raw = jax.jit(jax_codec.make_rs_reconstruct(present, want, rs))
 
             def reconstruct_xla(stacked: np.ndarray) -> np.ndarray:
                 self._count("xla-bitmatmul")
@@ -441,19 +468,18 @@ class ECCodec:
 
         import jax
 
-        from t3fs.ops.rs import default_rs
-
-        rs = default_rs(k, m)
-        if rs.raid6 and L % 4 == 0:
-            # RAID-6 decode stays word-packed: the GF(2^8) decode constants
-            # run as SWAR xtimes/xor chains at encode-class rates (the
-            # byte-plane bit-matmul below is ~8-16 GB/s; this is the
+        if L % 4 == 0:
+            # decode stays word-packed: the GF(2^8) decode constants run as
+            # a scheduled SWAR xtimes/xor program at encode-class rates
+            # (the byte-plane bit-matmul below is ~8-16 GB/s; this is the
             # degraded-read/repair hot path)
             from t3fs.ops.pallas_codec import make_rs_reconstruct_words_pallas
             W = L // 4
             bw = _pick_block(W, 16384)
             raw = jax.jit(make_rs_reconstruct_words_pallas(
                 present, want, rs, block_w=bw, interpret=self._interpret))
+            self._word_tags(key, rs, rs.reconstruct_gfmatrix(
+                list(present), list(want)))
             nwant = len(want)
 
             def reconstruct_words(stacked: np.ndarray) -> np.ndarray:
@@ -464,7 +490,7 @@ class ECCodec:
                 return out.view(np.uint8).reshape(out.shape[0], nwant, L)
             return reconstruct_words
 
-        # non-RAID-6 (k, m) / odd lengths: byte-plane bit-matmul fallback
+        # lengths that are no whole number of words: byte-plane bit-matmul
         from t3fs.ops.pallas_codec import make_rs_reconstruct_pallas
 
         bt = _pick_block(L, 32768)
@@ -478,20 +504,22 @@ class ECCodec:
 
     def _build_reconstruct_verified(self, key: tuple) -> Callable:
         """Fused decode+verify: one launch returns (rebuilt, crcs) where
-        crcs covers survivors + rebuilt shards.  Word-fused on RAID-6
-        512-multiple chunks; otherwise an XLA-fused program (still one
-        device round trip, still no CPU crc32c)."""
-        _kind, present, want, k, m, L = key
+        crcs covers survivors + rebuilt shards.  Word-fused on 512-multiple
+        chunks; otherwise an XLA-fused program (still one device round
+        trip, still no CPU crc32c)."""
+        _kind, present, want, k, m, L, code = key
         import jax
 
-        from t3fs.ops.rs import default_rs
-
-        rs = default_rs(k, m)
+        rs = rs_code(k, m, code)
+        self._span_tags[key] = {"code": code}
         nwant = len(want)
-        if self._use_pallas and rs.raid6 and L % 512 == 0:
+        if self._use_pallas and L % 512 == 0:
             from t3fs.ops.pallas_codec import make_stripe_decode_step_words
             step = jax.jit(make_stripe_decode_step_words(
-                L // 4, present, want, k, m, interpret=self._interpret))
+                L // 4, present, want, k, m, interpret=self._interpret,
+                code=code))
+            self._word_tags(key, rs, rs.reconstruct_gfmatrix(
+                list(present), list(want)))
 
             def decode_words(stacked: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
@@ -535,9 +563,7 @@ class ECCodec:
         import jax
 
         from t3fs.ops.repair_program import schedule_repair_program
-        from t3fs.ops.rs import default_rs
 
-        rs = default_rs(k, m)
         prog = schedule_repair_program(coeffs)
         h = prog.num_helpers
         if self._use_pallas and L % 512 == 0:
@@ -557,10 +583,10 @@ class ECCodec:
             return repair_words
 
         from t3fs.ops.jax_codec import crc32c_batch_jit
-        from t3fs.ops.pallas_codec import _xtimes_u32
+        from t3fs.ops.pallas_codec import _poly_shifts, _xtimes_u32
 
-        low = rs.gf.poly & 0xFF
-        shifts = tuple(b for b in range(8) if (low >> b) & 1)
+        # every RS code of one (k, m) shares the field
+        shifts = _poly_shifts(rs_code(k, m, stored_code(k, m)))
         planes = prog.planes
         top = len(planes) - 1
         pad = (-L) % 4
@@ -682,20 +708,24 @@ class ECCodec:
     def warmup_decode(self, patterns: list[tuple[tuple[int, ...],
                                                  tuple[int, ...]]],
                       L: int, k: int = 8, m: int = 2,
-                      batch_sizes: tuple[int, ...] | None = None) -> int:
+                      batch_sizes: tuple[int, ...] | None = None,
+                      code: str | None = None) -> int:
         """Precompile the hot (present, want, L) fused decode steps
         off-path — call at server start / when a node loss is detected, so
         the FIRST degraded read doesn't eat a multi-second Mosaic compile
         on the read path."""
-        return self._warm([(("recv", tuple(present), tuple(want), k, m, L),
-                            (k, L)) for present, want in patterns],
+        code = _code(k, m, code)
+        return self._warm([(("recv", tuple(present), tuple(want), k, m, L,
+                             code), (k, L)) for present, want in patterns],
                           batch_sizes)
 
     def warmup_encode(self, L: int, k: int = 8, m: int = 2,
-                      batch_sizes: tuple[int, ...] | None = None) -> int:
+                      batch_sizes: tuple[int, ...] | None = None,
+                      code: str | None = None) -> int:
         """Precompile the fused encode+CRC step at chunk length L (the
         checkpoint writer's stripe encode)."""
-        return self._warm([(("encv", k, m, L), (k, L))], batch_sizes)
+        return self._warm([(("encv", k, m, L, _code(k, m, code)), (k, L))],
+                          batch_sizes)
 
     def warmup_repair(self, coeff_rows: list[tuple[int, ...]], L: int,
                       k: int = 8, m: int = 2,

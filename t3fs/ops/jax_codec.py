@@ -231,17 +231,6 @@ def make_rs_reconstruct(present: tuple[int, ...], want: tuple[int, ...],
     return reconstruct
 
 
-@functools.lru_cache(maxsize=8)
-def rs_encode_jit(k: int = 8, m: int = 2):
-    return jax.jit(make_rs_encode(default_rs(k, m)))
-
-
-@functools.lru_cache(maxsize=128)
-def rs_reconstruct_jit(present: tuple[int, ...], want: tuple[int, ...],
-                       k: int = 8, m: int = 2):
-    return jax.jit(make_rs_reconstruct(present, want, default_rs(k, m)))
-
-
 def make_stripe_encode_step(chunk_len: int, k: int = 8, m: int = 2,
                             seg_bytes: int = DEFAULT_SEG_BYTES):
     """The storage write-path hot op (BASELINE north star): for a batch of

@@ -51,7 +51,7 @@ def _xtimes_u8(x, shifts):
 def _make_mulc(words: bool, shifts: tuple[int, ...]):
     """Constant GF(2^8) multiply on packed lanes: XOR of the xtimes-ladder
     rungs the constant's set bits select (same chain the word kernels
-    bake; see pallas_codec._rs_reconstruct_words_kernel)."""
+    bake in ladder form; see pallas_codec._eval_gf_words)."""
     from t3fs.ops.pallas_codec import _xtimes_u32
     xt = (lambda x: _xtimes_u32(x, shifts)) if words else \
          (lambda x: _xtimes_u8(x, shifts))

@@ -35,7 +35,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from t3fs.ops.crc32c import default_matrices
-from t3fs.ops.rs import RSCode, default_rs
+from t3fs.ops.repair_program import MatrixProgram, schedule_matrix_program
+from t3fs.ops.rs import RSCode, default_rs, rs_code, stored_code
 
 DEFAULT_SEG_BYTES = 512
 # word-kernel blocks (uint32 words per grid cell) of the fused steps; each
@@ -44,6 +45,9 @@ DEFAULT_SEG_BYTES = 512
 ENCODE_BLOCK_W = 131072
 DECODE_BLOCK_W = 16384
 REPAIR_BLOCK_W = 131072
+# double-buffered in + out block bytes a word kernel may take of the 16 MiB
+# scoped VMEM: what RS(8+2) encode fills at ENCODE_BLOCK_W (_fit_block)
+VMEM_BLOCK_BYTES = 10 << 20
 
 
 def _plane_major_perm(nbytes: int) -> np.ndarray:
@@ -222,16 +226,14 @@ def make_stripe_encode_step_fast(chunk_len: int, k: int = 8, m: int = 2,
 # unpack bits (plus relayouts), measured ~8-16 GB/s on v5e.  The word path
 # keeps chunk bytes packed 4-per-lane as uint32:
 #
-#   rs_raid6_words: P = XOR fold, Q = Horner xtimes fold, all SWAR on uint32
-#                   lanes -> ~2 VPU ops/byte (vs 24).  Same math as
-#                   jax_codec.make_rs_encode_raid6 but inside a kernel, so no
-#                   XLA bitcast relayout (which pins the XLA version to
-#                   ~6 GB/s in HBM).
-#   rs_reconstruct_words: the DECODE side of the same trick — each GF(2^8)
-#                   decode coefficient becomes a host-built xtimes/xor chain
-#                   (see make_rs_reconstruct_words_pallas), so degraded reads
-#                   and repair run at encode-class rates instead of the
-#                   byte-plane kernel's 8-16 GB/s.
+#   gf_words:       a constant GF(2^8) matrix (parity rows to encode, a
+#                   reconstruction matrix to decode) as a host-scheduled
+#                   xtimes/XOR program, all SWAR on uint32 lanes -> ~2 VPU
+#                   ops/byte (vs 24); for RAID-6 P is an XOR fold and Q a
+#                   Horner fold in g.  Inside a kernel, so no XLA bitcast
+#                   relayout (which pins the XLA version to ~6 GB/s in
+#                   HBM), and degraded reads and repair run at encode-class
+#                   rates instead of the byte-plane kernel's 8-16 GB/s.
 #   crc_words:      segments are 128-word rows; bit (c,b) of each word lane
 #                   feeds one of 32 small (R,128)@(128,32) bf16 matmuls whose
 #                   weight slice is the segment matrix rows 8*(4w+c)+b.  No
@@ -250,58 +252,108 @@ def _xtimes_u32(x, shifts):
     hi = (x >> 7) & jnp.uint32(0x01010101)
     x2 = (x << 1) & jnp.uint32(0xFEFEFEFE)
     for b in shifts:
-        x2 = x2 ^ (hi << b)
+        x2 = x2 ^ (hi << b if b else hi)
     return x2
 
 
-def _rs_raid6_words_kernel(x_ref, out_ref, *, k: int, shifts: tuple[int, ...]):
+def _poly_shifts(rs: RSCode) -> tuple[int, ...]:
+    low = rs.gf.poly & 0xFF
+    return tuple(b for b in range(8) if (low >> b) & 1)
+
+
+def gf_words_program(rs: RSCode, rows) -> MatrixProgram:
+    """The scheduled program a word kernel bakes for constant GF(2^8) rows
+    (repair_program.schedule_matrix_program); its gf_ops is what ECCodec's
+    `ec.flush` spans carry."""
+    return schedule_matrix_program(
+        tuple(tuple(int(c) for c in row) for row in rows), rs.gf.poly)
+
+
+def _eval_gf_words(prog: MatrixProgram, xs: list, shifts: tuple[int, ...]
+                   ) -> list:
+    """Evaluate a MatrixProgram on packed uint32 words: xs[s] is input s,
+    one output per row, in the program's schedule (fully unrolled, the
+    constants baked in)."""
+    rows = prog.rows
+    acc: list = [None] * len(rows)
+    if prog.horner:
+        # per row: bit planes folded Horner-style from the top bit down
+        for r, row in enumerate(rows):
+            for b in range(max(row).bit_length() - 1, -1, -1):
+                if acc[r] is not None:
+                    acc[r] = _xtimes_u32(acc[r], shifts)
+                for s, c in enumerate(row):
+                    if (c >> b) & 1:
+                        acc[r] = xs[s] if acc[r] is None else acc[r] ^ xs[s]
+    else:
+        # per input: one xtimes ladder (t, x*t, x^2*t, ...) whose rungs are
+        # XORed into the rows whose coefficient has that bit set
+        for s, col in enumerate(zip(*rows)):
+            nbits = max(col).bit_length()
+            t = xs[s]
+            for b in range(nbits):
+                for r, c in enumerate(col):
+                    if (c >> b) & 1:
+                        acc[r] = t if acc[r] is None else acc[r] ^ t
+                if b + 1 < nbits:
+                    t = _xtimes_u32(t, shifts)
+    return [a if a is not None else jnp.zeros_like(xs[0]) for a in acc]
+
+
+def _gf_words_kernel(x_ref, out_ref, *, prog: MatrixProgram,
+                     shifts: tuple[int, ...]):
     x = x_ref[0]                                         # (k, R, C) uint32
-    p = x[0]                                             # (R, C): full vregs
-    q = x[0]
-    for s in range(1, k):
-        p = p ^ x[s]
-        q = _xtimes_u32(q, shifts) ^ x[s]
-    out_ref[0, 0] = p
-    out_ref[0, 1] = q
+    outs = _eval_gf_words(prog, [x[s] for s in range(x.shape[0])], shifts)
+    for r, out in enumerate(outs):                       # (R, C): full vregs
+        out_ref[0, r] = out
 
 
-def make_rs_encode_words_pallas(rs: RSCode | None = None, block_w: int = 16384,
-                                interpret: bool = False):
-    """(n, k, W) uint32 words -> (n, 2, W) uint32 parity words (RAID-6 m=2).
+def _make_gf_words_call(rs: RSCode, rows, block_w: int, interpret: bool,
+                        name: str):
+    """(n, k, W) uint32 words -> (n, len(rows), W) uint32: the constant
+    (len(rows), k) GF(2^8) matrix applied at every word position.
 
     Words are little-endian packed chunk bytes (byte j of the chunk is byte
     j%4 of word j//4), i.e. exactly numpy .view(uint32) of the byte shards.
     Internally the word axis is viewed (W//2048, 2048) so per-shard slices
     occupy full (8, 128)-lane vregs instead of single sublane rows."""
-    rs = rs or default_rs()
-    assert rs.raid6, "word kernel requires the RAID-6 m=2 code"
-    k = rs.k
-    low = rs.gf.poly & 0xFF
-    shifts = tuple(b for b in range(8) if (low >> b) & 1)
+    prog = gf_words_program(rs, rows)
+    shifts = _poly_shifts(rs)
+    k, nout = rs.k, len(prog.rows)
 
-    def encode(words: jax.Array) -> jax.Array:
+    def call(words: jax.Array) -> jax.Array:
         n, kk, W = words.shape
         assert kk == k, (words.shape, k)
         bw = min(block_w, W)
         assert W % bw == 0, (W, bw)
         COLS = 2048 if bw % 2048 == 0 else bw
-        rows = bw // COLS
+        nrows = bw // COLS
         v = words.reshape(n, k, W // COLS, COLS)
         out = pl.pallas_call(
-            functools.partial(_rs_raid6_words_kernel, k=k, shifts=shifts),
-            out_shape=jax.ShapeDtypeStruct((n, 2, W // COLS, COLS),
+            functools.partial(_gf_words_kernel, prog=prog, shifts=shifts),
+            out_shape=jax.ShapeDtypeStruct((n, nout, W // COLS, COLS),
                                            jnp.uint32),
             grid=(n, W // bw),
-            in_specs=[pl.BlockSpec((1, k, rows, COLS),
+            in_specs=[pl.BlockSpec((1, k, nrows, COLS),
                                    lambda i, j: (i, 0, j, 0))],
-            out_specs=pl.BlockSpec((1, 2, rows, COLS),
+            out_specs=pl.BlockSpec((1, nout, nrows, COLS),
                                    lambda i, j: (i, 0, j, 0)),
             interpret=interpret,
-            name="rs_encode_words",
+            name=name,
         )(v)
-        return out.reshape(n, 2, W)
+        return out.reshape(n, nout, W)
 
-    return encode
+    return call
+
+
+def make_rs_encode_words_pallas(rs: RSCode | None = None, block_w: int = 16384,
+                                interpret: bool = False):
+    """(n, k, W) uint32 words -> (n, m, W) uint32 parity words, any RS code:
+    the parity rows as a scheduled constant program (for RAID-6, P's XOR
+    fold and Q's Horner fold in g)."""
+    rs = rs or default_rs()
+    return _make_gf_words_call(rs, rs.parity_rows, block_w, interpret,
+                               "rs_encode_words")
 
 
 def _crc_words_kernel(x_ref, m_ref, out_ref):
@@ -419,22 +471,35 @@ def make_crc32c_words(chunk_words: int, block_r: int = 512,
     return crc
 
 
+def _fit_block(chunk_words: int, block_w: int, slots: int) -> int:
+    """pick_block(chunk_words, block_w), halved until the double-buffered
+    in and out blocks of `slots` shards (k inputs + rows outputs) fit the
+    10 MiB that RS(8+2) fills at 128Ki words; 16 MiB is the scoped VMEM."""
+    from t3fs.ops.blocks import pick_block
+    bw = pick_block(chunk_words, block_w)
+    while 2 * 4 * slots * bw > VMEM_BLOCK_BYTES and bw % 2 == 0 and bw > 2048:
+        bw //= 2
+    return bw
+
+
 def make_stripe_encode_step_words(chunk_words: int, k: int = 8, m: int = 2,
                                   interpret: bool = False,
-                                  block_w: int = ENCODE_BLOCK_W):
+                                  block_w: int = ENCODE_BLOCK_W,
+                                  code: str | None = None):
     """Word-packed fused stripe step — the shipping TPU write-path op:
     (n, k, chunk_words) uint32 -> parity (n, m, chunk_words) uint32,
-    crcs (n, k+m) uint32.  Input is the little-endian uint32 view of the
-    byte shards (numpy: arr.view(np.uint32)); parity output views back the
-    same way.  Replaces the reference's CPU folly::crc32c
-    (src/fbs/storage/Common.h:158); the RS data path is a t3fs addition."""
-    assert m == 2, "word path is RAID-6 (m=2); use make_stripe_encode_step_fast"
-    rs = default_rs(k, m)
-    # block_w 128Ki words (256Ki overflows the 16 MiB scoped VMEM) and
-    # block_r 2048: the fastest pair in an earlier round's v5e sweep
-    from t3fs.ops.blocks import pick_block
+    crcs (n, k+m) uint32, under `code` (default: stored_code(k, m)).
+    Input is the little-endian uint32 view of the byte shards (numpy:
+    arr.view(np.uint32)); parity output views back the same way.  Replaces
+    the reference's CPU folly::crc32c (src/fbs/storage/Common.h:158); the
+    RS data path is a t3fs addition."""
+    rs = rs_code(k, m, code or stored_code(k, m))
+    # block_w 128Ki words (256Ki overflows the 16 MiB scoped VMEM at
+    # RS(8+2)) and block_r 2048: the fastest pair in an earlier round's
+    # v5e sweep; wider stripes take smaller blocks (_fit_block)
     rs_enc = make_rs_encode_words_pallas(
-        rs, block_w=pick_block(chunk_words, block_w), interpret=interpret)
+        rs, block_w=_fit_block(chunk_words, block_w, k + m),
+        interpret=interpret)
     crc = make_crc32c_words(chunk_words, block_r=2048, interpret=interpret)
 
     def step(words: jax.Array):
@@ -454,9 +519,10 @@ def make_rs_reconstruct_pallas(present: tuple[int, ...], want: tuple[int, ...],
     """(n, k, L) uint8 present shards -> (n, |want|, L); Pallas analog of
     jax_codec.make_rs_reconstruct (decode = same bit-matmul, different matrix).
 
-    This is the byte-plane DECODE FALLBACK: it serves any (k, m) code but
-    pays the ~24-vector-ops-per-byte bit unpack.  RAID-6 (m=2) codes decode
-    through make_rs_reconstruct_words_pallas below, which stays word-packed."""
+    This is the byte-plane DECODE FALLBACK for lengths that are no whole
+    number of words: it pays the ~24-vector-ops-per-byte bit unpack.  Every
+    other length decodes through make_rs_reconstruct_words_pallas below,
+    which stays word-packed."""
     rs = rs or default_rs()
     k, w = rs.k, len(want)
     W = rs.reconstruct_bitmatrix(list(present), list(want))   # (8k, 8w)
@@ -486,38 +552,13 @@ def make_rs_reconstruct_pallas(present: tuple[int, ...], want: tuple[int, ...],
 #
 # Decode coefficients are GF(2^8) constants from RSCode.reconstruct_gfmatrix,
 # and multiplying packed words by a CONSTANT c needs no bit planes at all:
-# c*x = XOR over the set bits b of c of xtimes^b(x), so each present shard
-# feeds one shared xtimes ladder (t, x*t, x^2*t, ...) whose rungs are XORed
-# into the output accumulators the host-built chain selects.  Worst case
-# (dense c) that is 7 xtimes + 8 XORs per shard-word — the same ~2 VPU ops
-# per byte regime as the encode kernel, vs ~24 for the byte-plane unpack.
-# The chain is built host-side per (present, want) pattern; the kernel is
-# fully unrolled with the constants baked in, exactly like the encode path
-# bakes the Horner fold.
-
-
-def _rs_reconstruct_words_kernel(x_ref, out_ref, *,
-                                 coeffs: tuple[tuple[int, ...], ...],
-                                 shifts: tuple[int, ...]):
-    x = x_ref[0]                                         # (k, R, C) uint32
-    k = len(coeffs[0])
-    nwant = len(coeffs)
-    acc: list = [None] * nwant
-    for s in range(k):
-        col = [coeffs[r][s] for r in range(nwant)]
-        top = max(col)
-        if top == 0:
-            continue                                     # shard unused
-        t = x[s]                                         # xtimes ladder rung 0
-        nbits = top.bit_length()
-        for b in range(nbits):
-            for r in range(nwant):
-                if (col[r] >> b) & 1:
-                    acc[r] = t if acc[r] is None else acc[r] ^ t
-            if b + 1 < nbits:
-                t = _xtimes_u32(t, shifts)
-    for r in range(nwant):
-        out_ref[0, r] = acc[r] if acc[r] is not None else x[0] ^ x[0]
+# c*x = XOR over the set bits b of c of xtimes^b(x).  The matrix runs through
+# the same scheduled program as the encode (_eval_gf_words): a bit-plane
+# Horner fold per rebuilt shard, or one xtimes ladder per survivor shared by
+# every rebuilt shard, whichever issues fewer ops — the same ~2 VPU ops per
+# byte regime as the encode kernel, vs ~24 for the byte-plane unpack.  The
+# program is built host-side per (present, want) pattern; the kernel is
+# fully unrolled with the constants baked in.
 
 
 def make_rs_reconstruct_words_pallas(present: tuple[int, ...],
@@ -527,46 +568,15 @@ def make_rs_reconstruct_words_pallas(present: tuple[int, ...],
                                      interpret: bool = False):
     """(n, k, W) uint32 present-shard words -> (n, |want|, W) uint32 rebuilt.
 
-    Word-packed RAID-6 decode: covers every single/double-erasure
-    (present, want) pattern of the m=2 code (the decode matrix approach is
-    pattern-agnostic; only the baked-in constants change).  Words are the
-    little-endian uint32 view of the byte shards, same contract as
-    make_rs_encode_words_pallas; non-RAID-6 codes fall back to the
-    byte-plane make_rs_reconstruct_pallas."""
+    Word-packed decode of any RS code and any erasure pattern it survives
+    (the decode matrix approach is pattern-agnostic; only the baked-in
+    constants change).  Words are the little-endian uint32 view of the byte
+    shards, same contract as make_rs_encode_words_pallas."""
     rs = rs or default_rs()
-    assert rs.raid6, "word reconstruct requires the RAID-6 m=2 code"
-    k = rs.k
-    assert len(present) == k, (present, k)
-    Wm = rs.reconstruct_gfmatrix(list(present), list(want))   # (|want|, k)
-    coeffs = tuple(tuple(int(c) for c in row) for row in Wm)
-    low = rs.gf.poly & 0xFF
-    shifts = tuple(b for b in range(8) if (low >> b) & 1)
-    nwant = len(want)
-
-    def reconstruct(words: jax.Array) -> jax.Array:
-        n, kk, W = words.shape
-        assert kk == k, (words.shape, k)
-        bw = min(block_w, W)
-        assert W % bw == 0, (W, bw)
-        COLS = 2048 if bw % 2048 == 0 else bw
-        rows = bw // COLS
-        v = words.reshape(n, k, W // COLS, COLS)
-        out = pl.pallas_call(
-            functools.partial(_rs_reconstruct_words_kernel,
-                              coeffs=coeffs, shifts=shifts),
-            out_shape=jax.ShapeDtypeStruct((n, nwant, W // COLS, COLS),
-                                           jnp.uint32),
-            grid=(n, W // bw),
-            in_specs=[pl.BlockSpec((1, k, rows, COLS),
-                                   lambda i, j: (i, 0, j, 0))],
-            out_specs=pl.BlockSpec((1, nwant, rows, COLS),
-                                   lambda i, j: (i, 0, j, 0)),
-            interpret=interpret,
-            name="rs_decode_words",
-        )(v)
-        return out.reshape(n, nwant, W)
-
-    return reconstruct
+    assert len(present) == rs.k, (present, rs.k)
+    return _make_gf_words_call(
+        rs, rs.reconstruct_gfmatrix(list(present), list(want)), block_w,
+        interpret, "rs_decode_words")
 
 
 # --- word-packed sub-shard repair (reduced-read single-erasure path) --------
@@ -607,8 +617,7 @@ def make_repair_subshard_words(program, rs: RSCode | None = None,
     as the encode/reconstruct word kernels).  Each grid cell evaluates the
     scheduled Horner-over-bit-planes program on full (8, 128)-lane vregs."""
     rs = rs or default_rs()
-    low = rs.gf.poly & 0xFF
-    shifts = tuple(b for b in range(8) if (low >> b) & 1)
+    shifts = _poly_shifts(rs)
     h = program.num_helpers
     planes = program.planes
 
@@ -659,30 +668,30 @@ def make_repair_step_words(sub_words: int, program,
 def make_stripe_decode_step_words(chunk_words: int, present: tuple[int, ...],
                                   want: tuple[int, ...], k: int = 8,
                                   m: int = 2, interpret: bool = False,
-                                  block_w: int = DECODE_BLOCK_W):
+                                  block_w: int = DECODE_BLOCK_W,
+                                  code: str | None = None):
     """Word-packed fused decode+verify — the read-path mirror of
     make_stripe_encode_step_words: (n, k, chunk_words) uint32 present-shard
     words -> rebuilt (n, |want|, chunk_words) uint32,
     crcs (n, k + |want|) uint32 (CRC32C of the k survivors in `present`
-    order, then the rebuilt shards in `want` order).
+    order, then the rebuilt shards in `want` order), under `code`
+    (default: stored_code(k, m)).
 
     One device program rebuilds the missing shards AND checksums both the
     survivors and the rebuilt bytes, so a degraded read / repair pays no
     per-shard CPU crc32c after the round trip — the write path's fused
     economics (~107 GB/s two-point on v5e), now on the path that matters
     when the system is degraded and every stripe read is a decode."""
-    assert m == 2, "word path is RAID-6 (m=2); use make_rs_reconstruct_pallas"
-    rs = default_rs(k, m)
-    from t3fs.ops.blocks import pick_block
+    rs = rs_code(k, m, code or stored_code(k, m))
     # 16Ki words = one (8, 2048) tile row-block, the smallest block the
     # TPU lowering takes, and the fastest of 16Ki..128Ki on v5e for every
     # two-erasure pattern (benchmarks/codec_block_sweep.py); 128Ki, the
     # encode block, overflows the 16 MiB scoped VMEM with two data erasures
+    nwant = len(want)
     rec = make_rs_reconstruct_words_pallas(
-        present, want, rs, block_w=pick_block(chunk_words, block_w),
+        present, want, rs, block_w=_fit_block(chunk_words, block_w, k + nwant),
         interpret=interpret)
     crc = make_crc32c_words(chunk_words, block_r=2048, interpret=interpret)
-    nwant = len(want)
 
     def step(words: jax.Array):
         n = words.shape[0]

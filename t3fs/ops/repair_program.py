@@ -21,10 +21,18 @@ The program is host-built once per (coeffs) pattern and baked into the
 Pallas word kernel (pallas_codec.make_repair_subshard_words) the same way
 the reconstruct kernel bakes its constant chain; `eval_program_np` is the
 bit-exact numpy reference the differential tests pin both against.
+
+A whole constant matrix (RS parity rows for encode, a reconstruction matrix
+for decode) is scheduled by `schedule_matrix_program`: either this per-row
+Horner form, or one xtimes ladder per input shared by every row, whichever
+issues fewer VPU ops (`MatrixProgram.gf_ops`).  A dense 4x10 Cauchy decode
+takes the Horner form (<= 28 xtimes against up to 70); the RAID-6 encode
+rows are the Horner fold itself.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -124,3 +132,48 @@ def eval_program_np(prog: RepairProgram, helpers: np.ndarray,
         if s is not None:
             acc ^= s
     return acc
+
+
+def xtimes_vpu_ops(poly: int) -> int:
+    """32-bit VPU ops of one SWAR multiply-by-x (pallas_codec._xtimes_u32):
+    shift and mask twice, then a shift (none for bit 0) and an XOR per set
+    bit of the polynomial's low byte; 11 for 0x11D."""
+    low = poly & 0xFF
+    return 4 + sum(2 if b else 1 for b in range(8) if (low >> b) & 1)
+
+
+@dataclass(frozen=True)
+class MatrixProgram:
+    """Scheduled evaluation of out_r = sum_s rows[r][s] * in_s over GF(2^8)
+    on packed words.  horner: each row is its own bit-plane Horner fold (at
+    most 7 xtimes a row); else each input walks one xtimes ladder whose
+    rungs every row shares (at most 7 xtimes an input).  Both issue the
+    same XORs; gf_ops counts XORs plus xtimes times their own ops, per
+    word position."""
+
+    rows: tuple[tuple[int, ...], ...]
+    horner: bool
+    xor_ops: int
+    xtimes_ops: int
+    gf_ops: int
+
+
+def _top(coeffs) -> int:
+    """xtimes a ladder or fold over these coefficients needs; -1 if all 0."""
+    return max(coeffs).bit_length() - 1
+
+
+@functools.lru_cache(maxsize=512)
+def schedule_matrix_program(rows: tuple[tuple[int, ...], ...],
+                            poly: int) -> MatrixProgram:
+    """Pick the cheaper schedule for a constant (r, k) GF(2^8) matrix,
+    counted here on the host; Horner on a tie.  A zero row costs nothing
+    (its output is zeros)."""
+    rows = tuple(tuple(int(c) for c in row) for row in rows)
+    xors = sum(max(0, sum(c.bit_count() for c in row) - 1) for row in rows)
+    horner = sum(max(0, _top(row)) for row in rows)
+    ladder = sum(max(0, _top(col)) for col in zip(*rows))
+    xt = min(horner, ladder)
+    return MatrixProgram(rows=rows, horner=horner <= ladder, xor_ops=xors,
+                         xtimes_ops=xt,
+                         gf_ops=xors + xt * xtimes_vpu_ops(poly))

@@ -2,9 +2,10 @@
 
 The reference has NO erasure-coding data path (EC exists only as a placement
 option in deploy/data_placement/src/model/data_placement.py:484); RS(8+2)
-encode/decode is a capability t3fs adds per BASELINE.json.  Construction is
-the standard systematic one (row-reduced Vandermonde, any k of k+m rows
-invertible).  The hot path is the GF(2) expansion: for byte position j across
+encode/decode is a capability t3fs adds per BASELINE.json.  Three systematic
+constructions, any k of k+m rows invertible: RAID-6 P/Q (m = 2), row-reduced
+Vandermonde, and ISA-L/HDFS Cauchy; `stored_code` picks the one a new layout
+stores.  The XLA fallback is the GF(2) expansion: for byte position j across
 shards, parity bits = Gbits @ data bits, i.e. a (positions, 8k) @ (8k, 8m)
 matmul — MXU-shaped and batched over arbitrarily many positions.
 """
@@ -16,16 +17,31 @@ import functools
 import numpy as np
 
 from t3fs.ops.gf256 import GF256, default_field
+from t3fs.utils.status import StatusCode, make_error
 
 
 class RSCode:
-    """RS(k+m): shards 0..k-1 are data, k..k+m-1 are parity."""
+    """RS(k+m): shards 0..k-1 are data, k..k+m-1 are parity.
 
-    def __init__(self, k: int = 8, m: int = 2, field: GF256 | None = None):
+    `code` names the parity rows by format id (`code_id`, suffixed with
+    the field polynomial): `raid6-g2-11d`, `rrvand-11d` or `cauchy-11d`.
+    None takes RAID-6 for m = 2 and row-reduced Vandermonde otherwise."""
+
+    def __init__(self, k: int = 8, m: int = 2, field: GF256 | None = None,
+                 code: str | None = None):
         self.k = k
         self.m = m
         self.gf = field or default_field()
-        if m == 2 and k <= 254:
+        poly = f"{self.gf.poly:x}"
+        if code is None:
+            code = (f"raid6-g2-{poly}" if m == 2 and k <= 254
+                    else f"rrvand-{poly}")
+        # identifies the parity FORMAT on the wire/disk: decode with a
+        # different generator matrix silently corrupts, so layouts carry
+        # this id and clients cross-check it
+        self.code_id = code
+        self.raid6 = code == f"raid6-g2-{poly}"
+        if self.raid6:
             # RAID-6-style rows: P = XOR of all shards, Q = Horner chain in
             # the generator (coefficients g^(k-1-s)).  MDS for k <= 254
             # (distinct nonzero coefficients; the 2x2 minors [[1,1],[g^a,
@@ -33,20 +49,30 @@ class RSCode:
             # because encode becomes k-1 XORs + k-1 xtimes on PACKED WORDS
             # — ~8x faster than the GF(2) bit matmul on the VPU
             # (jax_codec.make_rs_encode fast path).
-            self.raid6 = True
+            if m != 2 or k > 254:
+                raise ValueError(f"{code} is RS(k+2) for k <= 254, "
+                                 f"not RS({k}+{m})")
             G = np.zeros((k + 2, k), dtype=np.uint8)
             G[:k] = np.eye(k, dtype=np.uint8)
             G[k, :] = 1
             G[k + 1, :] = [self.gf.pow(2, k - 1 - s) for s in range(k)]
             self.G = G
-            # identifies the parity FORMAT on the wire/disk: decode with a
-            # different generator matrix silently corrupts, so layouts
-            # carry this id and clients cross-check it
-            self.code_id = f"raid6-g2-{self.gf.poly:x}"
-        else:
-            self.raid6 = False
+        elif code == f"rrvand-{poly}":
             self.G = self.gf.systematic_generator(k, m)      # (k+m, k) GF(2^8)
-            self.code_id = f"rrvand-{self.gf.poly:x}"
+        elif code == f"cauchy-{poly}":
+            # ISA-L gf_gen_cauchy1_matrix (Hadoop RSUtil.genCauchyMatrix,
+            # the HDFS RS-k-m-1024k policies): parity row i = k..k+m-1,
+            # column j has 1 / (i XOR j).  Any k x k minor of [I; C] is a
+            # Cauchy minor, so the code is MDS for k + m <= 256.
+            if k + m > 256:
+                raise ValueError(f"no {code} code for RS({k}+{m})")
+            G = np.zeros((k + m, k), dtype=np.uint8)
+            G[:k] = np.eye(k, dtype=np.uint8)
+            for i in range(k, k + m):
+                G[i] = [self.gf.inv(i ^ j) for j in range(k)]
+            self.G = G
+        else:
+            raise ValueError(f"unknown RS parity code {code!r}")
         self.parity_rows = self.G[k:]                        # (m, k)
         # (8k, 8m) 0/1 matrix: unpacked data bits @ this = parity bits
         self.parity_bitmatrix = np.ascontiguousarray(
@@ -107,3 +133,31 @@ class RSCode:
 @functools.lru_cache(maxsize=None)
 def default_rs(k: int = 8, m: int = 2) -> RSCode:
     return RSCode(k, m)
+
+
+# RS shapes with m >= 3 that Hadoop ships as built-in HDFS erasure-coding
+# policies (RS-6-3-1024k, RS-10-4-1024k): their stripes store Cauchy parity
+# byte for byte as HDFS and ISA-L write it
+HDFS_CAUCHY_SHAPES = frozenset({(6, 3), (10, 4)})
+
+
+def stored_code(k: int, m: int) -> str:
+    """The parity code a new RS(k+m) layout stores, and the one ECCodec
+    serves when a call names none."""
+    if (k, m) in HDFS_CAUCHY_SHAPES:
+        return f"cauchy-{default_field().poly:x}"
+    return default_rs(k, m).code_id
+
+
+@functools.lru_cache(maxsize=None)
+def rs_code(k: int, m: int, code_id: str) -> RSCode:
+    """The cached RSCode of a stored format id; EC_FORMAT_MISMATCH for an
+    id this build cannot decode."""
+    rs = default_rs(k, m)
+    if code_id == rs.code_id:
+        return rs
+    try:
+        return RSCode(k, m, code=code_id)
+    except ValueError as e:
+        raise make_error(StatusCode.EC_FORMAT_MISMATCH,
+                         f"RS({k}+{m}) parity code {code_id!r}: {e}") from e

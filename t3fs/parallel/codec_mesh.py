@@ -28,7 +28,7 @@ from t3fs.ops.jax_codec import (
     DEFAULT_SEG_BYTES, unpack_bits, pack_bits_u32, _mod2,
     make_crc32c_raw, make_rs_encode_matmul,
 )
-from t3fs.ops.rs import default_rs
+from t3fs.ops.rs import rs_code, stored_code
 
 
 def make_mesh(n_devices: int | None = None, dp: int | None = None) -> Mesh:
@@ -99,7 +99,7 @@ def make_sharded_encode_step(mesh: Mesh, chunk_len: int, k: int = 8, m: int = 2,
     # folds into the CRC's HBM passes nearly free, while the word-SWAR
     # path mixed with the byte-wise CRC measured 3x slower end to end
     # (same reasoning as jax_codec.make_stripe_encode_step)
-    rs_encode = make_rs_encode_matmul(default_rs(k, m))
+    rs_encode = make_rs_encode_matmul(rs_code(k, m, stored_code(k, m)))
 
     def local_step(stripes: jax.Array):
         # stripes: (n_local, k, local_len); byte-concat then unpack inside the
@@ -140,7 +140,8 @@ def _crc_combine_words_setup(mesh: Mesh, chunk_words: int,
 
 def make_sharded_encode_step_words(mesh: Mesh, chunk_words: int,
                                    k: int = 8, m: int = 2,
-                                   interpret: bool = False):
+                                   interpret: bool = False,
+                                   code: str | None = None):
     """The SHIPPING word-packed kernels under the mesh (r3 verdict #4:
     the sharded path previously ran only the XLA bit-matmul codec, so
     the multi-chip story and bench.py's measured configuration were
@@ -152,18 +153,18 @@ def make_sharded_encode_step_words(mesh: Mesh, chunk_words: int,
         -> parity (n, m, chunk_words) uint32 same sharding,
            crcs (n, k+m) uint32 replicated over cp.
 
-    The RAID-6 SWAR parity is word-position-local (zero comms under
-    cp); each device CRCs its local word span via the word kernel and
-    the chunk CRC rides the same shift-weighted psum as the byte path.
-    interpret=True runs the kernels under the Pallas interpreter on the
-    CPU mesh (tests/dryrun); on real chips pass False."""
+    The SWAR parity of any RS code (`code`, default stored_code(k, m))
+    is word-position-local (zero comms under cp); each device CRCs its
+    local word span via the word kernel and the chunk CRC rides the same
+    shift-weighted psum as the byte path.  interpret=True runs the
+    kernels under the Pallas interpreter on the CPU mesh (tests/dryrun);
+    on real chips pass False."""
     from t3fs.ops.pallas_codec import make_rs_encode_words_pallas
 
-    assert m == 2, "word path is RAID-6 (m=2); use make_sharded_encode_step"
     local_words, raw_bits, crc_combine = _crc_combine_words_setup(
         mesh, chunk_words, interpret)
-    rs_enc = make_rs_encode_words_pallas(default_rs(k, m),
-                                         interpret=interpret)
+    rs_enc = make_rs_encode_words_pallas(
+        rs_code(k, m, code or stored_code(k, m)), interpret=interpret)
 
     def local_step(words: jax.Array):
         n = words.shape[0]                  # (n_local, k, local_words)
@@ -190,59 +191,45 @@ def make_sharded_reconstruct_step_words(mesh: Mesh, chunk_len: int,
                                         present: tuple[int, ...],
                                         want: tuple[int, ...],
                                         k: int = 8, m: int = 2,
-                                        interpret: bool = False):
+                                        interpret: bool = False,
+                                        code: str | None = None):
     """Word-kernel recovery path under the mesh: the SWAR word
-    reconstruct (same kernel the EC client ships for RAID-6) decodes
-    each device's local span with bytes kept packed 4-per-uint32-lane,
-    and the rebuilt shards' CRCs ride the word-kernel CRC + cp psum.
-    Non-RAID-6 codes fall back to the byte-plane bit-matmul kernel.
+    reconstruct (same kernel the EC client ships, any RS code; `code`
+    defaults to stored_code(k, m)) decodes each device's local span with
+    bytes kept packed 4-per-uint32-lane, and the rebuilt shards' CRCs
+    ride the word-kernel CRC + cp psum.
 
       survivors (n, k, chunk_len) uint8 sharded P('dp', None, 'cp')
         -> rebuilt (n, |want|, chunk_len) uint8 same sharding,
            crcs (n, |want|) uint32 replicated over cp.
     """
     from t3fs.ops.blocks import pick_block
-    from t3fs.ops.pallas_codec import (
-        make_rs_reconstruct_pallas, make_rs_reconstruct_words_pallas,
-    )
+    from t3fs.ops.pallas_codec import make_rs_reconstruct_words_pallas
 
     cp = mesh.shape["cp"]
     assert chunk_len % (4 * cp) == 0, (chunk_len, cp)
     local_len = chunk_len // cp
     local_words, raw_bits, crc_combine = _crc_combine_words_setup(
         mesh, chunk_len // 4, interpret)
-    rs = default_rs(k, m)
+    rs = rs_code(k, m, code or stored_code(k, m))
     w = len(want)
-    if rs.raid6:
-        rec_words = make_rs_reconstruct_words_pallas(
-            present, want, rs, block_w=pick_block(local_words, 16384),
-            interpret=interpret)
+    rec_words = make_rs_reconstruct_words_pallas(
+        present, want, rs, block_w=pick_block(local_words, 16384),
+        interpret=interpret)
 
-        def local_step(survivors: jax.Array):
-            n = survivors.shape[0]          # (n_local, k, local_len) uint8
-            # free little-endian reinterpret to packed uint32 words (same
-            # layout as numpy .view(np.uint32) on the host), decode in
-            # word space, reinterpret back — no unpack/repack passes
-            words = jax.lax.bitcast_convert_type(
-                survivors.reshape(n, k, local_words, 4), jnp.uint32)
-            rwords = rec_words(words)       # (n, w, local_words) uint32
-            rebuilt = jax.lax.bitcast_convert_type(
-                rwords, jnp.uint8).reshape(n, w, local_len)
-            crcs = crc_combine(
-                raw_bits(rwords.reshape(n * w, local_words)), n, w)
-            return rebuilt, crcs
-    else:
-        rec = make_rs_reconstruct_pallas(present, want, rs,
-                                         block_t=pick_block(local_len, 32768),
-                                         interpret=interpret)
-
-        def local_step(survivors: jax.Array):
-            n = survivors.shape[0]          # (n_local, k, local_len) uint8
-            rebuilt = rec(survivors)
-            words = jax.lax.bitcast_convert_type(
-                rebuilt.reshape(n * w, local_words, 4), jnp.uint32)
-            crcs = crc_combine(raw_bits(words), n, w)
-            return rebuilt, crcs
+    def local_step(survivors: jax.Array):
+        n = survivors.shape[0]              # (n_local, k, local_len) uint8
+        # free little-endian reinterpret to packed uint32 words (same
+        # layout as numpy .view(np.uint32) on the host), decode in word
+        # space, reinterpret back — no unpack/repack passes
+        words = jax.lax.bitcast_convert_type(
+            survivors.reshape(n, k, local_words, 4), jnp.uint32)
+        rwords = rec_words(words)           # (n, w, local_words) uint32
+        rebuilt = jax.lax.bitcast_convert_type(
+            rwords, jnp.uint8).reshape(n, w, local_len)
+        crcs = crc_combine(
+            raw_bits(rwords.reshape(n * w, local_words)), n, w)
+        return rebuilt, crcs
 
     mapped = jax.shard_map(
         local_step, mesh=mesh,
@@ -275,7 +262,8 @@ def make_sharded_reconstruct_step(mesh: Mesh, chunk_len: int,
     local_len, raw_local, crc_combine = _crc_combine_setup(
         mesh, chunk_len, seg_bytes)
     from t3fs.ops.jax_codec import make_rs_reconstruct
-    reconstruct = make_rs_reconstruct(present, want, default_rs(k, m))
+    reconstruct = make_rs_reconstruct(present, want,
+                                      rs_code(k, m, stored_code(k, m)))
 
     def local_step(survivors: jax.Array):
         n = survivors.shape[0]

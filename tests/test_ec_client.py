@@ -81,7 +81,8 @@ def test_ec_write_read_roundtrip_and_reconstruct(monkeypatch):
             # write_chunk checksums), the FUSED word decode+verify for the
             # degraded read (the EC client previously used
             # the slow XLA path while bench.py measured the word kernels;
-            # the byte-plane bit-matmul is now the non-RAID-6 fallback)
+            # the byte-plane bit-matmul is left for lengths that are no
+            # whole number of words)
             assert ec.codec.codec_counts.get("pallas-encode-words", 0) >= 1, \
                 ec.codec.codec_counts
             assert ec.codec.codec_counts.get("pallas-decode-words", 0) >= 1, \
@@ -355,3 +356,128 @@ def test_ec_codec_cpu_platform_dispatches_to_xla(monkeypatch):
         finally:
             await codec.close()
     asyncio.run(body())
+
+
+async def _wipe_shards(cluster, lay, inode, stripe, shards):
+    """Remove shard chunks of a stripe from their chains (lost chunks)."""
+    from t3fs.storage.types import RemoveChunksReq
+    routing = cluster.mgmtd.state.routing()
+    for shard in shards:
+        chain_id = lay.shard_chain(stripe, shard)
+        cid = (lay.data_chunk(inode, stripe, shard) if shard < lay.k
+               else lay.parity_chunk(inode, stripe, shard - lay.k))
+        head = routing.chains[chain_id].head()
+        await cluster.admin.call(
+            routing.node_address(head.node_id), "Storage.remove_chunks",
+            RemoveChunksReq(chain_id=chain_id, inode=cid.inode,
+                            begin_index=cid.index,
+                            end_index=cid.index + 1))
+
+
+def test_ec_rs10p4_cauchy_roundtrip_four_data_nodes_down(monkeypatch):
+    """HDFS's RS-10-4 shape on 14 nodes stores Cauchy parity and is served
+    by the word kernels alone: write, repair a single and a triple loss,
+    then read with the nodes of data slots 0-3 fail-stopped (every read a
+    4-of-10 decode).  Stored parity is the reference's cauchy-11d."""
+    monkeypatch.setenv("T3FS_FORCE_PALLAS_INTERPRET", "1")
+    from perfbench import reference
+    from t3fs.storage.types import ReadIO
+
+    async def body():
+        cluster = LocalCluster(num_nodes=14, replicas=1, num_chains=0,
+                               ec_chains=14, heartbeat_timeout_s=0.6)
+        await cluster.start()
+        try:
+            lay = ECLayout.create(k=10, m=4, chunk_size=4096,
+                                  chains=list(range(1, 15)))
+            assert lay.code_id == "cauchy-11d"
+            ec = ECStorageClient(cluster.sc)
+            full = bytes((7 * i + 3) % 251 for i in range(10 * 4096))
+            short = full[: 3 * 4096 + 100]
+            for stripe, data in ((0, full), (1, short)):
+                res = await ec.write_stripe(lay, 50, stripe, data)
+                assert all(r.status.code == int(StatusCode.OK) for r in res)
+            # the stored parity is HDFS's, byte for byte
+            ios = [ReadIO(chunk_id=lay.parity_chunk(50, 0, p),
+                          chain_id=lay.shard_chain(0, 10 + p))
+                   for p in range(4)]
+            _res, parity = await cluster.sc.batch_read(ios)
+            assert [bytes(p) for p in parity] == reference.stripe_shards(
+                full, 10, 4, reference.CAUCHY)[10:]
+
+            for lost in ((6,), (1, 8, 12)):
+                await _wipe_shards(cluster, lay, 50, 0, lost)
+                res = await ec.repair_stripe(lay, 50, 0, lost,
+                                             stripe_len=len(full))
+                assert all(r.status.code == int(StatusCode.OK) for r in res)
+                assert await ec.read_stripe(lay, 50, 0, len(full)) == full
+
+            routing = cluster.mgmtd.state.routing()
+            victims = {routing.chains[lay.shard_chain(0, s)].head().node_id
+                       for s in range(4)}
+            for n in victims:
+                await cluster.kill_storage_node(n)
+            for _ in range(100):
+                routing = cluster.mgmtd.state.routing()
+                if not any(t.node_id in victims for c in routing.chains.values()
+                           for t in c.serving()):
+                    break
+                await asyncio.sleep(0.1)
+            await cluster.mgmtd_client.refresh()
+            for stripe, data in ((0, full), (1, short)):
+                assert await ec.read_stripe(lay, 50, stripe, len(data)) == data
+
+            counts = ec.codec.codec_counts
+            assert counts.get("pallas-decode-words", 0) >= 1, counts
+            assert set(counts) <= {"pallas-encode-words",
+                                   "pallas-decode-words",
+                                   "pallas-repair-words"}, counts
+            await ec.close()
+        finally:
+            await cluster.stop()
+    asyncio.run(body())
+
+
+def test_ec_rrvand_4p3_layout_roundtrip_and_refuses_other_decoders(
+        monkeypatch):
+    """RS(4+3) layouts keep storing rrvand-11d and decode with their own
+    matrix on the word kernel; a decoder of another code is refused."""
+    monkeypatch.setenv("T3FS_FORCE_PALLAS_INTERPRET", "1")
+    from t3fs.ops.rs import rs_code
+    from t3fs.utils.status import StatusError
+
+    async def body():
+        cluster = LocalCluster(num_nodes=3, replicas=1, num_chains=7)
+        await cluster.start()
+        try:
+            lay = ECLayout.create(k=4, m=3, chunk_size=1024,
+                                  chains=list(range(1, 8)))
+            assert lay.code_id == "rrvand-11d"
+            ec = ECStorageClient(cluster.sc)
+            data = bytes(range(256)) * 16
+            await ec.write_stripe(lay, 60, 0, data)
+            await _wipe_shards(cluster, lay, 60, 0, (0, 2, 5))
+            assert await ec.read_stripe(lay, 60, 0, len(data)) == data
+            assert ec.codec.codec_counts.get("pallas-decode-words", 0) >= 1
+            await ec.close()
+        finally:
+            await cluster.stop()
+    asyncio.run(body())
+    lay = ECLayout.create(k=4, m=3, chunk_size=1024, chains=list(range(7)))
+    lay.check_code(rs_code(4, 3, "rrvand-11d"))
+    with pytest.raises(StatusError) as ei:
+        lay.check_code(rs_code(4, 3, "cauchy-11d"))
+    assert ei.value.status.code == int(StatusCode.EC_FORMAT_MISMATCH)
+
+
+def test_ec_layout_create_stamps_stored_code():
+    from t3fs.ops.msr import msr_code_id
+    for (k, m), code in {(10, 4): "cauchy-11d", (6, 3): "cauchy-11d",
+                         (8, 2): "raid6-g2-11d", (4, 2): "raid6-g2-11d",
+                         (4, 3): "rrvand-11d", (12, 4): "rrvand-11d"}.items():
+        lay = ECLayout.create(k=k, m=m, chunk_size=1024,
+                              chains=list(range(k + m)))
+        assert lay.code_id == code and lay.rs.code_id == code
+    msr = ECLayout.create(k=8, m=2, chunk_size=1 << 16,
+                          chains=list(range(10)), local_scheme="pm-msr")
+    assert msr.code_id == msr_code_id(8, 2)

@@ -5,8 +5,8 @@ can't).
 
 Covers: encode -> drop 2 shards -> batched reconstruct_verified -> CRC
 verify against crc32c_ref, all through the ("recv", ...) fused key with
-T3FS_FORCE_PALLAS_INTERPRET=1, plus warmup_decode and the non-RAID-6
-byte-plane fallback routing.
+T3FS_FORCE_PALLAS_INTERPRET=1, plus warmup_decode and the routing of
+other codes.
 """
 
 import asyncio
@@ -64,7 +64,7 @@ def test_ec_codec_end_to_end_fused_decode(interpret_env):
             assert codec.codec_counts.get("pallas-decode-words", 0) >= 1
             # micro-batching actually stacked concurrent same-key requests
             assert codec.batched_items >= 6
-            assert ("recv", present, lost, k, m, L) in codec._fns
+            assert ("recv", present, lost, k, m, L, rs.code_id) in codec._fns
         finally:
             await codec.close()
 
@@ -97,8 +97,9 @@ def test_ec_codec_plain_reconstruct_word_path(interpret_env):
 
 
 def test_ec_codec_non_raid6_byteplane_fallback(interpret_env):
-    """k=4, m=3 is not RAID-6: decode must fall back to the byte-plane
-    bit-matmul kernel (the word kernels are m=2-specific)."""
+    """k=4, m=3 is not RAID-6, and its layouts store rrvand-11d: decode
+    runs the word kernel (it serves every code), never the byte-plane
+    bit-matmul, which is left for lengths that are no whole words."""
     k, m, L = 4, 3, 512
     rs = RSCode(k, m)
     data = rng.integers(0, 256, (k, L), dtype=np.uint8)
@@ -113,8 +114,8 @@ def test_ec_codec_non_raid6_byteplane_fallback(interpret_env):
                                           lost, k, m)
             for j, s in enumerate(lost):
                 assert np.array_equal(out[j], full[s])
-            assert codec.codec_counts.get("pallas-bitmatmul", 0) >= 1
-            assert "pallas-rec-words" not in codec.codec_counts
+            assert codec.codec_counts.get("pallas-rec-words", 0) >= 1
+            assert "pallas-bitmatmul" not in codec.codec_counts
         finally:
             await codec.close()
 
@@ -135,7 +136,8 @@ def test_warmup_decode_precompiles_recv_keys(interpret_env):
             assert codec.warmup_decode(patterns, L, k=k, m=m,
                                        batch_sizes=(1,)) == 2
             for present, want in patterns:
-                assert ("recv", present, want, k, m, L) in codec._fns
+                assert ("recv", present, want, k, m, L,
+                        "raid6-g2-11d") in codec._fns
             # warmed compiles ran the real fn, so counts reflect them
             assert codec.codec_counts.get("pallas-decode-words", 0) >= 2
         finally:
@@ -188,3 +190,45 @@ def test_ec_codec_wait_counters(sampled):
     assert 0 < sum(s["dur_s"] for s in flushes) <= wall
     assert 0 < sum(s["tags"]["queue_s"] for s in flushes) \
         <= len(stripes) * wall
+
+
+def test_ec_flush_spans_carry_code_and_gf_ops(interpret_env):
+    """Each sampled encode or decode launch's `ec.flush` span names the
+    parity code it ran and the VPU ops per word position of the baked
+    word program (pallas_codec.gf_words_program)."""
+    from t3fs.ops.pallas_codec import gf_words_program
+    from t3fs.ops.rs import rs_code
+    from t3fs.utils import tracing
+
+    k, m, L = 10, 4, 512
+    rs = rs_code(k, m, "cauchy-11d")
+    data = rng.integers(0, 256, (k, L), dtype=np.uint8)
+    full = np.concatenate([data, rs.encode_ref(data)])
+    lost = (0, 1, 2, 3)
+    present = tuple(range(4, 14))
+
+    async def body():
+        codec = ECCodec()
+        try:
+            parity, _ = await codec.encode_verified(data, k, m)
+            rebuilt, _ = await codec.reconstruct_verified(
+                full[list(present)], present, lost, k, m)
+            return parity, rebuilt
+        finally:
+            await codec.close()
+
+    tracing.configure(tracing.TraceConfig(sample_rate=1.0, export="all"))
+    try:
+        parity, rebuilt = asyncio.run(body())
+        flushes = {s["tags"]["op"]: s["tags"] for s in tracing.BUFFER.drain()
+                   if s["name"] == "ec.flush"}
+    finally:
+        tracing.reset_tracing()
+    assert np.array_equal(parity, full[k:])
+    assert np.array_equal(rebuilt, full[list(lost)])
+    enc = gf_words_program(rs, rs.parity_rows)
+    dec = gf_words_program(rs, rs.reconstruct_gfmatrix(list(present),
+                                                       list(lost)))
+    assert flushes["encv"]["code"] == flushes["recv"]["code"] == "cauchy-11d"
+    assert flushes["encv"]["gf_ops"] == enc.gf_ops
+    assert flushes["recv"]["gf_ops"] == dec.gf_ops

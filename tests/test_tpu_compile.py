@@ -76,6 +76,25 @@ def test_decode_step_words(compile_v5e, chunk, want):
                 (N, 8, W))
 
 
+def test_rs10p4_encode_step_words_1mib(compile_v5e):
+    """HDFS's RS(10+4) Cauchy encode: 14 shards of in and out blocks."""
+    from t3fs.ops.pallas_codec import make_stripe_encode_step_words
+    W = MiB // 4
+    compile_v5e(make_stripe_encode_step_words(W, 10, 4), (N, 10, W))
+
+
+@pytest.mark.parametrize("want", [(0, 1, 2, 3), (0, 5, 10, 13), (7,)],
+                         ids=["data0123", "mixed", "one"])
+def test_rs10p4_decode_step_words(compile_v5e, want):
+    """RS(10+4) Cauchy decode at 1 MiB chunks: four data shards lost (the
+    degraded read after losing four data nodes), a mix, a single loss."""
+    from t3fs.ops.pallas_codec import make_stripe_decode_step_words
+    W = MiB // 4
+    present = tuple(s for s in range(14) if s not in want)[:10]
+    compile_v5e(make_stripe_decode_step_words(W, present, want, 10, 4),
+                (N, 10, W))
+
+
 def test_repair_step_words_1mib(compile_v5e):
     """Single-loss repair of a data shard from k survivors (dense row)."""
     from t3fs.ops.pallas_codec import make_repair_step_words
