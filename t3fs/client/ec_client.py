@@ -611,7 +611,8 @@ class ECStorageClient:
         other data) and decode the wanted shard indices (0..k+m-1 space).
         Returns (contents, crcs) aligned with `want`: crc is the DEVICE
         CRC32C of the full-chunk content when the fused decode+verify step
-        produced the shard, else None (directly-recovered / oracle path).
+        produced the shard, the stored CRC of a full want-shard the patient
+        wave read back directly, else None (oracle path).
 
         `zero_shards` lists data shards the CALLER knows were never written
         (short stripe) — only those may be substituted with zeros on
@@ -626,6 +627,7 @@ class ECStorageClient:
         k, m, cs = layout.k, layout.m, layout.chunk_size
         known = dict(known or {})
         have: dict[int, np.ndarray] = {}
+        stored: dict[int, int] = {}   # full shard read back -> stored CRC
         for j, content in known.items():
             buf = np.zeros(cs, dtype=np.uint8)
             buf[: len(content)] = np.frombuffer(content, dtype=np.uint8)
@@ -682,29 +684,33 @@ class ECStorageClient:
                         buf = np.zeros(cs, dtype=np.uint8)
                         buf[: len(p)] = np.frombuffer(p, dtype=np.uint8)
                         have[s] = buf
+                        if len(p) == cs:
+                            stored[s] = int(r.checksum)
         if len(have) < k:
             raise make_error(
                 StatusCode.TARGET_OFFLINE,
                 f"EC stripe {stripe}: only {len(have)} of {k + m} shards "
                 f"available, need {k}")
-        return await self._decode_from(layout, have, want, k, m)
+        return await self._decode_from(layout, have, want, k, m, stored)
 
     async def _decode_from(self, layout: ECLayout,
                            have: dict[int, np.ndarray],
-                           want: tuple[int, ...], k: int, m: int
+                           want: tuple[int, ...], k: int, m: int,
+                           stored: dict[int, int] | None = None
                            ) -> tuple[list[bytes], list[int | None]]:
         """Decode `want` shard indices from >= k available full-chunk-size
         buffers (`have`, keyed in 0..k+m shard space — zero holes included
         as zero buffers).  Returns (contents, crcs) aligned with `want`;
         crc is the fused decode+verify step's device CRC32C of the
         full-chunk content when that step produced the shard, else None.
-        Want-shards already in `have` pass through without decoding."""
+        Want-shards already in `have` pass through without decoding, with
+        their stored CRC from `stored` where the caller has it."""
         msr = layout.local_scheme == "pm-msr"
         layout.check_code(default_msr(k, m) if msr else layout.rs)
         # shards recovered directly need no decoding
         still_want = tuple(s for s in want if s not in have)
         decoded: dict[int, bytes] = {}
-        crc_of: dict[int, int] = {}
+        crc_of: dict[int, int] = dict(stored or {})
         if still_want:
             # recovered want-shards may serve as decode inputs; only the
             # still-missing ones must stay out of the present set
@@ -721,8 +727,8 @@ class ECStorageClient:
             if crcs is not None:
                 # fused-step layout: k survivor CRCs, then the rebuilt
                 # shards' CRCs in still_want order
-                crc_of = {s: int(crcs[k + i])
-                          for i, s in enumerate(still_want)}
+                crc_of.update((s, int(crcs[k + i]))
+                              for i, s in enumerate(still_want))
         return ([decoded[s] if s in decoded else bytes(have[s])
                  for s in want],
                 [crc_of.get(s) for s in want])

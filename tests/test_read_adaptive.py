@@ -412,6 +412,50 @@ def test_first_k_stripe_read_with_killed_shards():
     run(body())
 
 
+def test_stripe_read_reports_crc_of_shard_recovered_by_patient_wave():
+    """m shard homes dead AND a live data shard's fast read failing: the
+    fan-out drains short of k, the patient wave reads that shard back
+    directly, and the read reports its stored CRC (not None) beside the
+    device CRCs of the decoded shards."""
+    from t3fs.client.ec_client import ECLayout, ECStorageClient
+    from t3fs.ops.codec import crc32c
+    from t3fs.utils.status import make_error
+
+    async def body():
+        fab = _ec_env()
+        await fab.start()
+        ec = None
+        try:
+            sc = StorageClient(lambda: fab.routing, client=fab.client)
+            sc.cfg.max_retries = 2       # the patient wave's dead-node reads
+            lay = ECLayout.create(k=4, m=2, chunk_size=1024,
+                                  chains=fab.chain_ids)
+            ec = ECStorageClient(sc, fast_read_retries=1)
+            data = bytes((11 * i + 3) % 256 for i in range(4 * 1024))
+            res = await ec.write_stripe(lay, 34, 0, data)
+            assert all(r.status.code == int(StatusCode.OK) for r in res)
+            for j in (0, 3):
+                await fab.servers[
+                    _node_of_chain(fab, lay.shard_chain(0, j))].stop()
+            flaky = lay.shard_chain(0, 1)
+            fast_read = ec._fast.batch_read
+
+            async def failing_fast_read(ios):
+                if ios[0].chain_id == flaky:
+                    raise make_error(StatusCode.TIMEOUT, "fast read lost")
+                return await fast_read(ios)
+            ec._fast.batch_read = failing_fast_read
+            got, crcs = await ec.read_stripe_with_crcs(lay, 34, 0, len(data))
+            assert got == data
+            assert crcs == [crc32c(data[j * 1024:(j + 1) * 1024])
+                            for j in range(4)]
+        finally:
+            if ec is not None:
+                await ec.close()
+            await fab.stop()
+    run(body())
+
+
 def test_first_k_short_stripe_holes_count_free():
     """A short stripe's zero holes need no IO: with one live data shard
     straggling, holes + parity still reach k without reading them."""
