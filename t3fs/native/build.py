@@ -16,10 +16,11 @@ import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["chunk_engine.cpp", "usrbio.cpp", "aio_reader.cpp",
-            "net_pump.cpp"]
+            "net_pump.cpp", "sock_io.cpp"]
 _LIB_STEM = os.path.join(_DIR, "libt3fs_native")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
+_loader: threading.Thread | None = None
 
 import platform
 
@@ -97,6 +98,19 @@ def load_library() -> ctypes.CDLL:
         if _lib is None:
             _lib = ctypes.CDLL(build())
         return _lib
+
+
+def loaded_library() -> ctypes.CDLL | None:
+    """The library once this process has loaded it; before that None,
+    having started one load (which may have to build) on a thread of its
+    own, so a caller on an event loop never waits for a build.  A load
+    that fails leaves None for good."""
+    global _loader
+    if _lib is None and _loader is None:
+        _loader = threading.Thread(target=load_library, name="native-load",
+                                   daemon=True)
+        _loader.start()
+    return _lib
 
 
 if __name__ == "__main__":

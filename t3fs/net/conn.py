@@ -12,21 +12,31 @@ a msg or payload past ``BULK_BYTES`` gets a buffer of its own, and the
 kernel writes the part of it not yet staged straight there (see
 ``get_buffer``), so a multi-MiB body costs no pause/resume cycles of the
 transport and no copies beyond its staged prefix.
+
+A body of ``THREAD_BYTES`` or more crosses the socket on a mover of its
+connection (``_Mover``), a thread outside the interpreter, in either
+direction: the loop only hands the body over and takes it back, and
+frames below it stay on the loop.
 """
 
 from __future__ import annotations
 
 import asyncio
+import ctypes
 import itertools
 import logging
+import os
 import time
 from typing import Awaitable, Callable
+
+import numpy as np
 
 from t3fs.net.wire import (
     HEADER_SIZE, FLAG_COMPRESS, FLAG_IS_REQ, FrameError, MessagePacket,
     WireStatus, check_msg_crc, decompress_frame, maybe_compress, pack_header,
     unpack_header,
 )
+from t3fs.native.build import loaded_library
 from t3fs.net.rpcstats import RPC_STATS, SERVER_STATS
 from t3fs.ops.codec import crc32c
 from t3fs.utils import serde, tracing
@@ -41,6 +51,99 @@ log = logging.getLogger("t3fs.net")
 RX_FRAMES = CountRecorder("net.rx.frames")
 RX_DIRECT_BODIES = CountRecorder("net.rx.direct_bodies")
 RX_RECV_CALLS = CountRecorder("net.rx.recv_calls")
+# bodies a connection's mover moved, inbound (each also a direct body)
+# and outbound
+RX_THREAD_BODIES = CountRecorder("net.rx.thread_bodies")
+TX_THREAD_BODIES = CountRecorder("net.tx.thread_bodies")
+
+# bytearray(n) of what malloc gives, not zero-filled: a part the socket
+# fills whole is not written (and its pages faulted in) twice, the first
+# time on the loop
+_bytearray_uninit = ctypes.PYFUNCTYPE(
+    ctypes.py_object, ctypes.c_void_p, ctypes.c_ssize_t)(
+        ("PyByteArray_FromStringAndSize", ctypes.pythonapi))
+
+
+def _mover_lib() -> ctypes.CDLL | None:
+    """The native library with the mover's functions declared, or None
+    until this process has loaded it (loaded_library starts the load)."""
+    lib = loaded_library()
+    if lib is not None and lib.t3fs_mover_start.restype is not ctypes.c_void_p:
+        vp, u64 = ctypes.c_void_p, ctypes.c_uint64
+        lib.t3fs_mover_efd.argtypes = [vp]
+        lib.t3fs_mover_recv.argtypes = [vp, vp, u64]
+        lib.t3fs_mover_recv.restype = None
+        lib.t3fs_mover_send.argtypes = [vp, vp, u64, vp, u64]
+        lib.t3fs_mover_send.restype = None
+        lib.t3fs_mover_result.argtypes = [vp]
+        lib.t3fs_mover_stop.argtypes = [vp]
+        lib.t3fs_mover_stop.restype = None
+        lib.t3fs_mover_start.argtypes = [ctypes.c_int]
+        lib.t3fs_mover_start.restype = vp
+    return lib
+
+
+class _Mover:
+    """The loop's end of a mover (t3fs/native/sock_io.cpp): a thread of
+    one connection, outside the interpreter, that moves one body at a time
+    between the socket and memory, waiting on the socket with poll.  It
+    works on a dup of the socket, so it never touches a descriptor number
+    the transport closed and the process reused.  The loop hears of a
+    finished body through the mover's eventfd and gives its result (0, -1
+    for the end of the stream, else an errno) to the body's callback.
+    stop() shuts the socket down, which ends a body in flight, and joins
+    the thread: a body that was in flight gets STOPPED."""
+
+    STOPPED = -2
+
+    def __init__(self, lib: ctypes.CDLL, sock, loop: asyncio.AbstractEventLoop):
+        self._lib, self._loop, self._pid = lib, loop, os.getpid()
+        self._h = None
+        self._h = lib.t3fs_mover_start(os.dup(sock.fileno()))
+        if not self._h:
+            raise OSError("mover thread did not start")
+        self._efd = lib.t3fs_mover_efd(self._h)
+        loop.add_reader(self._efd, self._finished)
+        # the buffers the thread works on, kept alive, and the callback
+        self._job: tuple | None = None
+
+    def recv(self, part: bytearray, k: int, done: Callable[[int], None]) -> None:
+        """Fill part[k:] from the socket."""
+        buf = np.frombuffer(part, np.uint8)
+        self._job = (buf, done)
+        self._lib.t3fs_mover_recv(self._h, buf.ctypes.data + k, buf.nbytes - k)
+
+    def send(self, front: bytes, payload, done: Callable[[int], None]) -> None:
+        """Send front, then payload (any contiguous buffer)."""
+        a, b = np.frombuffer(front, np.uint8), np.frombuffer(payload, np.uint8)
+        self._job = (a, b, done)
+        self._lib.t3fs_mover_send(self._h, a.ctypes.data, a.nbytes,
+                                  b.ctypes.data, b.nbytes)
+
+    def _finished(self) -> None:
+        self._done(self._lib.t3fs_mover_result(self._h))
+
+    def _done(self, rc: int) -> None:
+        # the buffers' exports end here, before the body goes on (a
+        # bytearray with an export cannot be resized)
+        job, self._job = self._job, None
+        if job is not None:
+            done = job[-1]
+            del job
+            done(rc)
+
+    def stop(self) -> None:
+        self._loop.remove_reader(self._efd)
+        h, self._h = self._h, None
+        self._lib.t3fs_mover_stop(h)
+        self._done(self.STOPPED)
+
+    def __del__(self):
+        # dropped without stop(): its connection's loop closed under it.
+        # The dup would keep the socket open, the peer never seeing EOF.
+        # (Not in a forked child: the thread lives in the parent only.)
+        if self._h and os.getpid() == self._pid:
+            self._lib.t3fs_mover_stop(self._h)
 
 # handler(body, payload, conn) -> (rsp_body, rsp_payload)
 Handler = Callable[[object, bytes, "Connection"], Awaitable[tuple[object, bytes]]]
@@ -64,6 +167,10 @@ class Connection(asyncio.BufferedProtocol):
     # several per recv_into.  Twice BULK_BYTES, so a part that waits
     # staged always fits once the staged bytes move to the front
     STAGE_BYTES = 2 * BULK_BYTES
+    # a body with this much or more still to cross the socket crosses it
+    # on a mover (_Mover), off the loop: past the cost of the hand-over
+    # (two loop wake-ups), below the 1 MiB shards of an RS stripe
+    THREAD_BYTES = 512 << 10
 
     def __init__(self, dispatcher: dict[str, Handler] | None = None,
                  name: str = "?",
@@ -105,6 +212,13 @@ class Connection(asyncio.BufferedProtocol):
         # a frame is being verified off the loop: reading is paused and
         # later frames wait staged, so dispatch keeps the wire's order
         self._held = False
+        # the transport's socket (None: no real socket, as in
+        # t3fs/testing/conn_sim.py), and the "rx" and "tx" movers, each
+        # started on the first body it moves; while _rx_busy the rx mover
+        # fills _part and reading is paused
+        self._sock = None
+        self._movers: dict[str, _Mover] = {}
+        self._rx_busy = False
 
     @property
     def closed(self) -> bool:
@@ -149,6 +263,7 @@ class Connection(asyncio.BufferedProtocol):
         self._lost = asyncio.get_running_loop().create_future()
         self._stage = bytearray(self.STAGE_BYTES)
         self._stage_mv = memoryview(self._stage)
+        self._sock = transport.get_extra_info("socket")
         if self.name.endswith("<-"):
             # accepted by Server, which names it "srv<-": add the peer
             self.name += str(transport.get_extra_info("peername"))
@@ -156,6 +271,9 @@ class Connection(asyncio.BufferedProtocol):
     def connection_lost(self, exc: Exception | None) -> None:
         if not self._lost.done():
             self._lost.set_result(None)
+        movers, self._movers = self._movers, {}
+        for mover in movers.values():
+            mover.stop()
         waiter, self._drain_waiter = self._drain_waiter, None
         if waiter is not None and not waiter.done():
             if exc is None:
@@ -214,6 +332,24 @@ class Connection(asyncio.BufferedProtocol):
             mcrc = crc32c(msg) if msg else 0
         return pack_header(len(msg), len(payload), flags, mcrc), msg, payload
 
+    def _mover(self, kind: str) -> _Mover | None:
+        """The connection's "rx" or "tx" mover, started on the first body
+        it moves; None with no real socket, once the connection is lost,
+        or while the native library is not loaded."""
+        mover = self._movers.get(kind)
+        if (mover is None and self._sock is not None and not self._closed
+                and not self._lost.done()):
+            lib = _mover_lib()
+            if lib is not None:
+                try:
+                    mover = _Mover(lib, self._sock,
+                                   asyncio.get_running_loop())
+                except OSError as e:
+                    log.warning("conn %s: no %s mover: %s", self.name, kind, e)
+                    return None
+                self._movers[kind] = mover
+        return mover
+
     async def _send_frame(self, packet: MessagePacket, payload: bytes, flags: int) -> None:
         head, msg, payload = await self._prep_frame(packet, payload, flags)
         # frame atomicity: header+payload must hit the stream without
@@ -222,6 +358,11 @@ class Connection(asyncio.BufferedProtocol):
             if self._closed:
                 raise make_error(StatusCode.RPC_SEND_FAILED, "connection closed")
             try:
+                mover = (self._mover("tx")
+                         if len(payload) >= self.THREAD_BYTES else None)
+                if mover is not None:
+                    await self._send_moved(mover, head + msg, payload)
+                    return
                 # ONE buffer -> ONE send syscall: separate write() calls
                 # each attempt an immediate send when the transport buffer
                 # is empty, tripling the syscall count per frame (profiled
@@ -239,6 +380,39 @@ class Connection(asyncio.BufferedProtocol):
             except OSError as e:
                 raise make_error(StatusCode.RPC_SEND_FAILED,
                                  f"send on {self.name}: {e}") from None
+
+    async def _send_moved(self, mover: _Mover, front: bytes, payload) -> None:
+        """One frame through the tx mover, under the send lock: the
+        transport's buffer is sent first, and no transport.write runs
+        until the mover has sent the frame's last byte."""
+        if self.transport.get_write_buffer_size():
+            # pause_writing while any byte is buffered, resume at none
+            self.transport.set_write_buffer_limits(high=0)
+            try:
+                await self._drain()
+            finally:
+                self.transport.set_write_buffer_limits()
+        if self._lost.done():
+            raise ConnectionResetError("connection lost")
+        done = asyncio.get_running_loop().create_future()
+        mover.send(front, payload, done.set_result)
+        # the lock is held until the mover lets go of the socket, even by
+        # a caller cancelled meanwhile: a frame written now would land
+        # inside this one
+        cancelled = False
+        while True:
+            try:
+                rc = await asyncio.shield(done)
+                break
+            except asyncio.CancelledError:
+                cancelled = True
+        if cancelled:
+            raise asyncio.CancelledError
+        if rc:
+            self._abort()     # a frame cut short: nothing can follow it
+            raise ConnectionResetError(
+                os.strerror(rc) if rc > 0 else "closed mid-frame")
+        TX_THREAD_BODIES.add()
 
     def _stamp_trace(self, packet: MessagePacket) -> None:
         """Propagate the active span's context onto the envelope.  When no
@@ -348,13 +522,15 @@ class Connection(asyncio.BufferedProtocol):
                 n = self._frame[1 + len(self._parts)]   # msg_len, payload_len
                 staged = self._hi - self._lo
                 if n > self.BULK_BYTES:
-                    part = bytearray(n)
+                    part = _bytearray_uninit(None, n)
                     k = min(staged, n)
                     memoryview(part)[:k] = self._stage_mv[self._lo:self._lo + k]
                     self._lo += k
                     if k < n:
                         RX_DIRECT_BODIES.add()
                         self._part, self._part_n = part, k
+                        if n - k >= self.THREAD_BYTES:
+                            self._receive_moved()
                         break
                     self._parts.append(part)
                 elif n <= staged:
@@ -380,6 +556,44 @@ class Connection(asyncio.BufferedProtocol):
             n = self._hi - self._lo
             self._stage_mv[:n] = self._stage_mv[self._lo:self._hi]
             self._lo, self._hi = 0, n
+
+    def _receive_moved(self) -> None:
+        """Hand the rest of _part to the rx mover, where there is one:
+        reading pauses (so the mover alone reads the socket, and reads no
+        byte past the part) until _received takes the part back."""
+        mover = self._mover("rx")
+        if mover is None:
+            return
+        self.transport.pause_reading()
+        self._rx_busy = True
+        mover.recv(self._part, self._part_n, self._received)
+
+    def _received(self, rc: int) -> None:
+        self._rx_busy = False
+        if self._closed:
+            return
+        if rc:
+            # the end of the stream or a reset mid-body, as on the loop
+            # path: close, failing the pending calls
+            self._abort()
+            return
+        RX_THREAD_BODIES.add()
+        self._parts.append(self._part)
+        self._part = None
+        self._read_on()
+
+    def _read_on(self) -> None:
+        """After a pause (a frame verified off the loop, a body the rx
+        mover read): dispatch what is staged, then read on, unless that
+        paused reading again."""
+        self._parse()
+        if self._closed or self._held or self._rx_busy:
+            return
+        if self._lost.done():
+            # EOF came while reading was paused
+            self._spawn(self.close(), f"close-{self.name}")
+        else:
+            self.transport.resume_reading()
 
     def _complete_frame(self) -> None:
         (head_ts, _, _, flags, msg_crc), (msg, payload) = \
@@ -423,15 +637,8 @@ class Connection(asyncio.BufferedProtocol):
             return
         finally:
             self._held = False
-        if self._closed:
-            return
-        self._parse()
-        if self._closed or self._held:
-            return
-        if self._lost.done():
-            await self.close()      # EOF came while the frame was held
-        else:
-            self.transport.resume_reading()
+        if not self._closed:
+            self._read_on()
 
     def _abort(self) -> None:
         """A frame the connection cannot get past: stop reading now, and

@@ -96,3 +96,46 @@ def test_storage_bench_trace_ab():
     # the bench must leave the process untraced
     from t3fs.utils.tracing import get_config
     assert get_config().sample_rate == 0.0
+
+
+def test_host_probe_loop_stacks_and_thread_cpu():
+    """The host probe's loop-stack sampler: shares per innermost frame
+    of the sampled thread, only inside the window asked for; and the
+    per-thread CPU split it reads from /proc."""
+    import threading
+    import time
+
+    from benchmarks.perfbench_host_probe import LoopStacks, _thread_cpu_s
+
+    stop = threading.Event()
+    ident: list[int] = []
+
+    def spin_here():
+        ident.append(threading.get_ident())
+        while not stop.is_set():
+            sum(range(1000))
+
+    t = threading.Thread(target=spin_here)
+    t.start()
+    try:
+        while not ident:
+            time.sleep(0.001)
+        s = LoopStacks(0.001)
+        s.start(ident[0])
+        lo = time.time()
+        time.sleep(0.3)
+        hi = time.time()
+        s.stop()
+    finally:
+        stop.set()
+        t.join(timeout=5)
+    assert not t.is_alive()
+    got = s.shares(lo, hi)
+    assert got["samples"] > 10 and got["idle_select_share"] == 0
+    assert sum(v for _, v in got["leaf_share"]) <= 100.0 + 1e-9
+    assert got["leaf_share"][0][0].startswith(
+        "test_benchmarks.py:test_host_probe_loop_stacks_and_thread_cpu."
+        "<locals>.spin_here:")
+    assert s.shares(hi + 10, hi + 20) == {"samples": 0}
+    user, system = _thread_cpu_s(threading.get_native_id())
+    assert user >= 0 and system >= 0

@@ -1,10 +1,12 @@
 """Connection's inbound frame reader (asyncio BufferedProtocol): frames cut
 out of a staging buffer under any segmentation, large bodies read straight
 into a bytearray of their own, corrupt headers refused before anything is
-allocated from them, the send side's drain, and the write path storing a
-body that arrives as a bytearray without copying it back to bytes."""
+allocated from them, the send side's drain, the write path storing a body
+that arrives as a bytearray without copying it back to bytes, and bodies
+of THREAD_BYTES or more crossing real sockets on a connection's movers."""
 
 import asyncio
+import glob
 import os
 import socket
 import struct
@@ -243,12 +245,17 @@ def test_corrupt_header_refused_before_any_allocation(kind, monkeypatch):
         allocated.append(args)
         return bytearray(*args)
 
+    def tracking_uninit(ptr, n):
+        allocated.append((n,))
+        return bytearray(n)
+
     async def body():
         rx = _Rx()
         t = _attach(rx)     # allocates the staging buffer
-        # from here on, every bytearray the reader makes goes through this
+        # from here on, every bytearray the reader makes goes through these
         monkeypatch.setattr(conn_mod, "bytearray", tracking_bytearray,
                             raising=False)
+        monkeypatch.setattr(conn_mod, "_bytearray_uninit", tracking_uninit)
         fut = asyncio.get_running_loop().create_future()
         rx._waiters[99] = fut
         direct0 = conn_mod.RX_DIRECT_BODIES._value
@@ -391,3 +398,313 @@ def test_engine_put_takes_a_bytearray(engine_kind, tmp_path):
         assert e.get_meta(cid).length == len(data)
     finally:
         e.close()
+
+
+# ---- bodies of THREAD_BYTES or more cross the socket on movers ----
+
+THREAD = Connection.THREAD_BYTES
+
+
+@pytest.fixture(scope="module")
+def native():
+    """Movers run in the native library: load it (building it if need
+    be) before a test's loop starts, as a storage node does at start."""
+    from t3fs.native import load_library
+    return load_library()
+
+
+def _movers_alive() -> int:
+    n = 0
+    for path in glob.glob("/proc/self/task/*/comm"):
+        try:
+            with open(path) as f:
+                n += f.read().strip() == "t3fs-mover"
+        except OSError:
+            pass            # the thread ended meanwhile
+    return n
+
+
+async def _until(pred, timeout: float = 5.0) -> bool:
+    for _ in range(int(timeout / 0.005)):
+        if pred():
+            return True
+        await asyncio.sleep(0.005)
+    return pred()
+
+
+def _tcp_pair(buf: int = 0) -> tuple[socket.socket, socket.socket]:
+    """Two ends of a loopback TCP connection; buf > 0 pins both ends'
+    kernel buffers that small, so a large body stalls mid-way."""
+    with socket.socket() as ls:
+        if buf:
+            ls.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, buf)
+            ls.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, buf)
+        ls.bind(("127.0.0.1", 0))
+        ls.listen()
+        a = socket.socket()
+        if buf:
+            a.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, buf)
+            a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, buf)
+        a.connect(ls.getsockname())
+        b, _ = ls.accept()
+    return a, b
+
+
+def _counts() -> tuple[int, int]:
+    return (conn_mod.RX_THREAD_BODIES._value,
+            conn_mod.TX_THREAD_BODIES._value)
+
+
+async def _echo_server():
+    from t3fs.net.server import Server
+
+    async def echo(body, payload, conn):
+        return {"n": len(payload), "crc": crc32c(bytes(payload))}, payload
+    srv = Server()
+    srv.dispatcher["E.echo"] = echo
+    await srv.start()
+    return srv
+
+
+@pytest.mark.parametrize("size", [1 << 20, 4 << 20, THREAD - 1],
+                         ids=["1MiB", "4MiB", "THREAD_BYTES-1"])
+def test_body_crosses_real_socket_both_ways(size, native):
+    """An echo over loopback TCP: the request and the reply body each
+    cross on a mover at 1 and 4 MiB (bytes and CRC32C equal both ways);
+    one byte under THREAD_BYTES both stay on the loop."""
+    from t3fs.net.client import Client
+
+    async def body():
+        srv = await _echo_server()
+        cli = Client()
+        try:
+            data = os.urandom(size)
+            rx0, tx0 = _counts()
+            rsp, got = await asyncio.wait_for(
+                cli.call(srv.address, "E.echo", None, data), 10)
+            assert rsp == {"n": size, "crc": crc32c(data)}
+            assert type(got) is bytearray and bytes(got) == data
+            assert crc32c(bytes(got)) == crc32c(data)
+            got.extend(b"!")    # the mover left no export on the buffer
+            moved = 2 if size >= THREAD else 0
+            # the server's send is counted once its mover reports it
+            assert await _until(lambda: _counts() == (rx0 + moved,
+                                                      tx0 + moved))
+        finally:
+            await cli.close()
+            await srv.stop()
+    asyncio.run(body())
+
+
+@pytest.mark.parametrize("buf", [0, 64 << 10], ids=["autotuned", "64KiB"])
+def test_small_frames_queued_behind_a_moved_body_keep_their_order(
+        buf, native, monkeypatch):
+    """Frames on either side of a body the movers carry arrive in the
+    order sent, whole.  The peer starts reading only once all are
+    queued: with 64 KiB socket buffers part of the 230 KiB frame before
+    the body is then still in the transport's buffer when the body's
+    turn comes, and the mover waits for it to go out first."""
+    async def body():
+        loop = asyncio.get_running_loop()
+        a, b = _tcp_pair(buf)
+        rx, tx = _Rx(), Connection(name="tx")
+        await loop.connect_accepted_socket(lambda: tx, b)
+        # what the transport still buffers when the body's turn comes,
+        # and when the body goes to the mover
+        buffered: dict[str, int] = {}
+        turn, post = Connection._send_moved, conn_mod._Mover.send
+
+        async def spy_turn(self, *args):
+            buffered["turn"] = self.transport.get_write_buffer_size()
+            return await turn(self, *args)
+
+        def spy_post(self, *args):
+            buffered["post"] = tx.transport.get_write_buffer_size()
+            return post(self, *args)
+        monkeypatch.setattr(Connection, "_send_moved", spy_turn)
+        monkeypatch.setattr(conn_mod._Mover, "send", spy_post)
+        big = os.urandom(4 << 20)
+        small = [os.urandom(i) for i in range(40)]
+        small[4] = os.urandom(230 << 10)
+        rx0, tx0 = _counts()
+        # the send lock takes them in this order; the small frames
+        # behind the body wait for its mover
+        sends = asyncio.gather(*(tx.post("S.x", i, big if i == 5 else small[i])
+                                 for i in range(40)))
+        await asyncio.sleep(0.05)
+        await loop.connect_accepted_socket(lambda: rx, a)
+        await asyncio.wait_for(sends, 10)
+        assert await _until(lambda: len(rx.got) == 40)
+        assert [b for _, b, _ in rx.got] == list(range(40))
+        for i, (_, _, got) in enumerate(rx.got):
+            assert bytes(got) == (big if i == 5 else small[i])
+        assert _counts() == (rx0 + 1, tx0 + 1)
+        assert buffered["post"] == 0
+        if buf:
+            assert buffered["turn"] > 0
+        await tx.close()
+        await rx.close()
+    asyncio.run(body())
+
+
+async def _stalled(side: str, loop):
+    """A Connection with a body in flight on its side's mover that makes
+    no progress: the raw peer sends part of a 4 MiB body and stops (rx),
+    or reads nothing of one (tx).  Returns (conn, peer, call task or
+    None, pending waiter)."""
+    a, b = _tcp_pair(buf=64 << 10 if side == "tx" else 0)
+    conn = Connection(name=side)
+    await loop.connect_accepted_socket(lambda: conn, a)
+    pending = loop.create_future()
+    conn._waiters[10 ** 9] = pending
+    call = None
+    if side == "rx":
+        data, msg_len, _ = await _frame("S.cut", "x" * 40,
+                                        os.urandom(4 << 20))
+        await asyncio.to_thread(b.sendall,
+                                data[:HEADER_SIZE + msg_len + (1 << 20)])
+        assert await _until(lambda: conn._rx_busy)
+    else:
+        call = asyncio.ensure_future(
+            conn.call("S.x", 1, payload=os.urandom(4 << 20), timeout=20))
+        assert await _until(lambda: "tx" in conn._movers)
+        await asyncio.sleep(0.05)
+        assert not call.done(), "the peer reads nothing: the send stalls"
+    return conn, b, call, pending
+
+
+@pytest.mark.parametrize("side", ["rx", "tx"])
+@pytest.mark.parametrize("how", ["eof", "reset"])
+def test_peer_gone_mid_moved_body_fails_pending_calls(side, how, native):
+    async def body():
+        loop = asyncio.get_running_loop()
+        rx0, tx0 = _counts()
+        conn, peer, call, pending = await _stalled(side, loop)
+        if how == "reset":
+            peer.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                            struct.pack("ii", 1, 0))
+            peer.close()
+        else:
+            peer.shutdown(socket.SHUT_WR)   # FIN: the end of the stream
+        futs = [pending] + ([call] if call is not None else [])
+        for fut in futs:
+            with pytest.raises(StatusError) as ei:
+                await asyncio.wait_for(fut, 5)
+            assert ei.value.code == StatusCode.RPC_SEND_FAILED
+        assert await _until(lambda: conn.closed and not conn._movers)
+        assert _counts() == (rx0, tx0)
+        peer.close()
+    asyncio.run(body())
+
+
+@pytest.mark.parametrize("side", ["rx", "tx"])
+def test_close_mid_moved_body_returns_and_stops_its_movers(side, native):
+    async def body():
+        loop = asyncio.get_running_loop()
+        before = _movers_alive()
+        conn, peer, call, pending = await _stalled(side, loop)
+        # the thread names itself once it runs
+        assert await _until(lambda: _movers_alive() == before + 1)
+        await asyncio.wait_for(conn.close(), 5)
+        assert not conn._movers and _movers_alive() == before
+        for fut in [pending] + ([call] if call is not None else []):
+            with pytest.raises(StatusError) as ei:
+                await asyncio.wait_for(fut, 5)
+            assert ei.value.code == StatusCode.RPC_SEND_FAILED
+        peer.close()
+    asyncio.run(body())
+
+
+def test_cancelled_moved_send_finishes_its_frame_before_the_next(native):
+    """A caller cancelled while the tx mover sends its body: the send lock
+    stays held until the mover has sent the last byte, so the frame
+    queued behind it follows it whole."""
+    async def body():
+        loop = asyncio.get_running_loop()
+        a, b = _tcp_pair(buf=64 << 10)
+        tx = Connection(name="tx")
+        await loop.connect_accepted_socket(lambda: tx, a)
+        big = os.urandom(4 << 20)
+        first = asyncio.ensure_future(tx.post("S.x", 0, big))
+        assert await _until(lambda: "tx" in tx._movers)
+        await asyncio.sleep(0.05)
+        assert not first.done(), "the peer reads nothing: the send stalls"
+        first.cancel()
+        second = asyncio.ensure_future(tx.post("S.x", 1, b"small"))
+        await asyncio.sleep(0.05)
+        assert not first.done() and not second.done()
+        rx = _Rx()
+        await loop.connect_accepted_socket(lambda: rx, b)   # reads now
+        await asyncio.wait([first, second], timeout=10)
+        assert first.cancelled()
+        await second
+        assert await _until(lambda: len(rx.got) == 2)
+        assert [m[1] for m in rx.got] == [0, 1]
+        assert bytes(rx.got[0][2]) == big and rx.got[1][2] == b"small"
+        await tx.close()
+        await rx.close()
+    asyncio.run(body())
+
+
+def test_eight_connections_move_4mib_bodies_both_ways_at_once(native):
+    """Sixteen 4 MiB echoes over eight connections at once, every body
+    on a mover, finish well inside the timeout: movers of one
+    connection never wait on another's."""
+    from t3fs.net.client import Client
+
+    async def body():
+        srv = await _echo_server()
+        clients = [Client() for _ in range(8)]
+        before = _movers_alive()
+        try:
+            datas = [os.urandom(4 << 20) for _ in range(16)]
+            rx0, tx0 = _counts()
+            got = await asyncio.wait_for(asyncio.gather(*(
+                clients[i % 8].call(srv.address, "E.echo", None, d)
+                for i, d in enumerate(datas))), 60)
+            for (rsp, payload), d in zip(got, datas):
+                assert rsp["crc"] == crc32c(d) and bytes(payload) == d
+            assert await _until(lambda: _counts() == (rx0 + 32, tx0 + 32))
+            # a reader and a writer on each end of each connection
+            assert await _until(lambda: _movers_alive() == before + 32)
+        finally:
+            for c in clients:
+                await c.close()
+            await srv.stop()
+        assert _movers_alive() == before
+    asyncio.run(body())
+
+
+def test_craq_write_crosses_every_hop_on_a_mover(native):
+    """A 4 MiB CRAQ write through a 3-replica chain: client -> head ->
+    middle -> tail, each body received (and sent) on a mover; every
+    replica stores the same bytes and CRC32C."""
+    from t3fs.client.storage_client import StorageClient
+    from t3fs.storage.types import ChunkId, ChunkState
+    from t3fs.testing.fabric import StorageFabric
+
+    chunk = 4 << 20
+
+    async def body():
+        fab = StorageFabric(num_nodes=3, replicas=3)
+        await fab.start()
+        try:
+            sc = StorageClient(lambda: fab.routing, client=fab.client)
+            cid = ChunkId(43, 0)
+            data = os.urandom(chunk)
+            rx0, tx0 = _counts()
+            r = await sc.write_chunk(fab.chain_id, cid, 0, data,
+                                     chunk_size=chunk)
+            assert r.status.code == int(StatusCode.OK), r.status
+            assert _counts()[0] - rx0 == 3
+            assert await _until(lambda: _counts()[1] - tx0 == 3)
+            targets = [t for n in fab.nodes for t in n.targets.values()]
+            assert len(targets) == 3
+            for t in targets:
+                meta = t.engine.get_meta(cid)
+                assert meta.state == ChunkState.COMMIT
+                assert meta.checksum == crc32c(data)
+                assert t.engine.read(cid) == data
+        finally:
+            await fab.stop()
+    asyncio.run(body())
