@@ -1,20 +1,20 @@
 # Developer targets.  `make sanitize` is the reference-parity TSan/ASan
 # pass over the native code (reference: tsan_suppressions.txt + CI TSan
 # suites): builds libt3fs_native with each sanitizer and runs the suites
-# that exercise the three native components (chunk engine WAL/snapshot,
-# usrbio shm rings, io_uring reader) with the sanitizer runtime
-# preloaded into python.
+# that exercise the four native components (chunk engine WAL/snapshot,
+# usrbio shm rings, io_uring reader, socket movers) with the sanitizer
+# runtime preloaded into python.
 
 PY ?= python
 TSAN_RT := $(shell g++ -print-file-name=libtsan.so)
 ASAN_RT := $(shell g++ -print-file-name=libasan.so)
 # "device"-codec params lazily import jax, whose nanobind bindings trip
 # the preloaded sanitizer runtimes — the sanitizer pass targets the
-# NATIVE code (engine WAL, usrbio rings, io_uring reader), so those
-# params are excluded (they run in the normal suite).
+# NATIVE code (engine WAL, usrbio rings, io_uring reader, socket
+# movers), so those params are excluded (they run in the normal suite).
 SAN_TESTS := tests/test_native_engine.py tests/test_usrbio.py \
              tests/test_engine_differential.py tests/test_chunk_engine.py \
-             tests/test_storage_service.py tests/test_native_net.py
+             tests/test_storage_service.py tests/test_frame_reader.py
 SAN_FILTER := -k "not device"
 
 .PHONY: test lint sanitize sanitize-thread sanitize-address chip-smoke \

@@ -19,8 +19,7 @@ Phases (all timed separately, MB/s of logical stripe data):
 
 With --device the bench also runs a decode microbench on synthetic
 survivors: the fused word-packed decode+verify launch
-(make_stripe_decode_step_words) plus, under --decode-ab, the byte-plane
-bit-matmul kernel for the A/B recorded in docs/codec_economics.md.
+(make_stripe_decode_step_words) and the word reconstruct alone.
 Regardless of flags the bench ends with a one-line JSON decode metric
 (rs{k}+{m}_reconstruct GB/s + degraded-read MB/s) for log scraping.
 """
@@ -151,7 +150,7 @@ async def _run(args, cluster: LocalCluster, k: int, m: int,
         assert d == payloads[s % len(payloads)], f"post-repair stripe {s}"
 
     # which codec implementation actually served the calls (pallas-words /
-    # pallas-bitmatmul / xla-bitmatmul), plus batching effectiveness
+    # xla-bitmatmul / ...), plus batching effectiveness
     codec_stats = None
     if ec.codec is not None:
         codec_stats = {
@@ -193,9 +192,9 @@ def _decode_microbench(args) -> dict | None:
     """Kernel-level decode throughput on synthetic survivors (no cluster
     IO in the way).  Times the fused word-packed decode+verify launch —
     reconstruct of the 2 lost shards AND CRC32C of all k+|want| shards
-    in ONE kernel pass — and, with --decode-ab, the byte-plane
-    bit-matmul reconstruct for comparison.  GB/s counts survivor bytes
-    in per launch (n*k*L), the same convention as the encode bench.
+    in ONE kernel pass — and the word reconstruct alone.  GB/s counts
+    survivor bytes in per launch (n*k*L), the same convention as the
+    encode bench.
     Runs only with --device, which main() holds to a TPU."""
     if not args.device:
         return None
@@ -203,21 +202,19 @@ def _decode_microbench(args) -> dict | None:
 
     from t3fs.ops.blocks import pick_block
     from t3fs.ops.pallas_codec import (
-        make_rs_reconstruct_pallas, make_rs_reconstruct_words_pallas,
-        make_stripe_decode_step_words,
+        make_rs_reconstruct_words_pallas, make_stripe_decode_step_words,
     )
-    from t3fs.ops.rs import default_rs
+    from t3fs.ops.rs import rs_code, stored_code
 
     k, m = args.k, args.m
-    rs_code = default_rs(k, m)
+    rs = rs_code(k, m, stored_code(k, m))
     L = args.chunk_size - args.chunk_size % 512
     n = max(1, args.decode_batch)
-    present = tuple(range(2, k + m))     # drop shards 0 and 1 (double erasure)
+    present = tuple(range(2, k + 2))     # drop shards 0 and 1 (double erasure)
     want = (0, 1)
     rng = np.random.default_rng(7)
-    survivors = rng.integers(0, 256, (n, k, L), dtype=np.uint8)
-    words = np.ascontiguousarray(survivors).view(np.uint32).reshape(
-        n, k, L // 4)
+    words = rng.integers(0, 256, (n, k, L), dtype=np.uint8).view(
+        np.uint32).reshape(n, k, L // 4)
     iters = 20
 
     def gbps(fn, x):
@@ -230,17 +227,12 @@ def _decode_microbench(args) -> dict | None:
         return round(n * k * L / dt / 1e9, 3)
 
     res: dict = {"L": L, "batch": n}
-    if rs_code.raid6:
-        fused = jax.jit(make_stripe_decode_step_words(
-            L // 4, present, want, k=k, m=m))
-        res["fused_decode_verify_GB_s"] = gbps(fused, words)
-        rec_w = jax.jit(make_rs_reconstruct_words_pallas(
-            present, want, rs_code, block_w=pick_block(L // 4, 16384)))
-        res["word_reconstruct_GB_s"] = gbps(rec_w, words)
-    if args.decode_ab or not rs_code.raid6:
-        rec_b = jax.jit(make_rs_reconstruct_pallas(
-            present, want, rs_code, block_t=pick_block(L, 32768)))
-        res["byteplane_reconstruct_GB_s"] = gbps(rec_b, survivors)
+    fused = jax.jit(make_stripe_decode_step_words(
+        L // 4, present, want, k=k, m=m, code=rs.code_id))
+    res["fused_decode_verify_GB_s"] = gbps(fused, words)
+    rec_w = jax.jit(make_rs_reconstruct_words_pallas(
+        present, want, rs, block_w=pick_block(L // 4, 16384)))
+    res["word_reconstruct_GB_s"] = gbps(rec_w, words)
     return res
 
 
@@ -254,9 +246,6 @@ def parse_args(argv=None):
     ap.add_argument("--concurrency", type=int, default=4)
     ap.add_argument("--device", action="store_true",
                     help="RS encode/decode on the accelerator")
-    ap.add_argument("--decode-ab", action="store_true",
-                    help="with --device: also time the byte-plane "
-                         "reconstruct kernel for the word-vs-byte A/B")
     ap.add_argument("--decode-batch", type=int, default=4,
                     help="stripes per launch in the decode microbench")
     ap.add_argument("--json", action="store_true")

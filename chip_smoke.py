@@ -22,8 +22,8 @@ decodes and repairs on the same device.  Phases, in order:
   pm-msr    64 stripes at 64 KiB chunks under local_scheme="pm-msr"; one
             node wiped and every stripe's lost shard repaired from
             projections that read 0.5625x of a full-k repair
-  codecs    which implementation served each codec call; the XLA and
-            byte-plane fallbacks must not appear on this path
+  codecs    which implementation served each codec call; the XLA
+            fallbacks must not appear on this path
 
 Each phase prints one line (with its wall seconds: set-up evidence, not a
 metric); any failure exits non-zero.  The last line of a passing run is
@@ -47,7 +47,7 @@ import numpy as np
 
 MiB = 1 << 20
 # codec implementations that must not serve a call on the chip's path
-FALLBACK_CODECS = ("xla-bitmatmul", "pallas-bitmatmul", "xla-repair-words")
+FALLBACK_CODECS = ("xla-bitmatmul", "xla-repair-words")
 
 
 @dataclass

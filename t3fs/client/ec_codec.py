@@ -9,9 +9,7 @@ the bench measures:
     code's parity rows as a scheduled xtimes/xor program over uint32
     words), the parity half of bench.py's `make_stripe_encode_step_words`;
   - reconstruct: `make_rs_reconstruct_words_pallas` — the decode-side word
-    kernel (GF(2^8) decode constants as the same kind of program), with
-    the byte-plane `make_rs_reconstruct_pallas` bit-matmul reachable only
-    for lengths that are no whole number of words;
+    kernel (GF(2^8) decode constants as the same kind of program);
   - reconstruct_verified: `make_stripe_decode_step_words` — the fused
     decode+verify step; one launch rebuilds the missing shards AND returns
     CRC32Cs of survivors + rebuilt, so degraded reads/repair pay no
@@ -19,13 +17,15 @@ the bench measures:
 
 Every call names its parity code (`code`, an RSCode.code_id; default
 `rs.stored_code(k, m)`, what a new layout of that shape stores), and the
-code is part of each jit-cache key.  `jax_codec` stays as the oracle and
-the fallback for lengths the word kernels cannot take.  Platform dispatch
-(interpreted Pallas as the only CPU path cost a 3-4x regression on CPU
-fabrics): a TPU gets the Pallas word kernels; any other platform gets
-the compiled XLA bit-matmul path, with T3FS_FORCE_PALLAS_INTERPRET=1
-flipping the suite onto interpreted Pallas so the shipping kernels stay
-covered without hardware.
+code is part of each jit-cache key.  Each operation has one device
+formulation, the word kernels above, and one other: `jax_codec`'s XLA
+bit-matmul, which serves every platform but the TPU (interpreted Pallas as
+the only CPU path cost a 3-4x regression on CPU fabrics), every length the
+word kernels cannot take (encode and reconstruct: no whole number of
+words; the fused verified steps: no whole number of 512-byte segments),
+and is the oracle.  T3FS_FORCE_PALLAS_INTERPRET=1 flips the suite onto
+interpreted Pallas so the shipping kernels stay covered without
+hardware.
 
 Concurrent stripe operations MICRO-BATCH into one device call (same
 pattern as storage/codec_backend.py batches CRCs): encode/reconstruct
@@ -113,7 +113,7 @@ class ECCodec:
         self._closed = False
         # observability: which codec implementation served each call
         # ("pallas-words" | "pallas-rec-words" | "pallas-decode-words" |
-        #  "pallas-bitmatmul" | "xla-bitmatmul" | ...); warmup compiles
+        #  "xla-bitmatmul" | ...); warmup compiles
         # count too (they run the same fns on the same codec thread)
         self.codec_counts: dict[str, int] = {}
         self.last_codec: str | None = None
@@ -452,27 +452,16 @@ class ECCodec:
         return encode_xla
 
     def _build_reconstruct(self, key: tuple) -> Callable:
+        import jax
+
+        from t3fs.ops import jax_codec
+
         _kind, present, want, k, m, L, code = key
         rs = rs_code(k, m, code)
         self._span_tags[key] = {"code": code}
-        if not self._use_pallas:
-            import jax
-
-            from t3fs.ops import jax_codec
-            raw = jax.jit(jax_codec.make_rs_reconstruct(present, want, rs))
-
-            def reconstruct_xla(stacked: np.ndarray) -> np.ndarray:
-                self._count("xla-bitmatmul")
-                return np.asarray(raw(stacked))
-            return reconstruct_xla
-
-        import jax
-
-        if L % 4 == 0:
+        if self._use_pallas and L % 4 == 0:
             # decode stays word-packed: the GF(2^8) decode constants run as
             # a scheduled SWAR xtimes/xor program at encode-class rates
-            # (the byte-plane bit-matmul below is ~8-16 GB/s; this is the
-            # degraded-read/repair hot path)
             from t3fs.ops.pallas_codec import make_rs_reconstruct_words_pallas
             W = L // 4
             bw = _pick_block(W, 16384)
@@ -490,17 +479,13 @@ class ECCodec:
                 return out.view(np.uint8).reshape(out.shape[0], nwant, L)
             return reconstruct_words
 
-        # lengths that are no whole number of words: byte-plane bit-matmul
-        from t3fs.ops.pallas_codec import make_rs_reconstruct_pallas
+        # off the TPU, or an odd length: XLA bit-matmul (also the oracle)
+        raw = jax.jit(jax_codec.make_rs_reconstruct(present, want, rs))
 
-        bt = _pick_block(L, 32768)
-        raw = jax.jit(make_rs_reconstruct_pallas(
-            present, want, rs, block_t=bt, interpret=self._interpret))
-
-        def reconstruct(stacked: np.ndarray) -> np.ndarray:
-            self._count("pallas-bitmatmul")
+        def reconstruct_xla(stacked: np.ndarray) -> np.ndarray:
+            self._count("xla-bitmatmul")
             return np.asarray(raw(stacked))
-        return reconstruct
+        return reconstruct_xla
 
     def _build_reconstruct_verified(self, key: tuple) -> Callable:
         """Fused decode+verify: one launch returns (rebuilt, crcs) where

@@ -16,7 +16,7 @@ import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["chunk_engine.cpp", "usrbio.cpp", "aio_reader.cpp",
-            "net_pump.cpp", "sock_io.cpp"]
+            "sock_io.cpp"]
 _LIB_STEM = os.path.join(_DIR, "libt3fs_native")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None

@@ -50,20 +50,6 @@ class Client:
             conn = self._conns.get(address)
             if conn is not None and not conn.closed:
                 return conn
-            from t3fs.net.native_conn import native_connect, native_enabled
-            if native_enabled():
-                try:
-                    conn = await asyncio.wait_for(
-                        native_connect(address, self.dispatcher,
-                                       f"cli->{address}",
-                                       self.compress_threshold),
-                        self.connect_timeout)
-                except (OSError, asyncio.TimeoutError) as e:
-                    raise make_error(StatusCode.RPC_CONNECT_FAILED,
-                                     f"connect {address}: {e}") from None
-                self._conns[address] = conn
-                self._epochs[address] = self._epochs.get(address, 0) + 1
-                return conn
             host, port = address.rsplit(":", 1)
             try:
                 _, conn = await asyncio.wait_for(

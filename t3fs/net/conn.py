@@ -309,9 +309,7 @@ class Connection(asyncio.BufferedProtocol):
 
     async def _prep_frame(self, packet: MessagePacket, payload: bytes,
                           flags: int) -> tuple[bytes, bytes, bytes]:
-        """Serde + (optional) compression + envelope CRC + header —
-        everything byte-identical between the asyncio and native
-        transports, shared so the wire formats can never diverge.
+        """Serde + (optional) compression + envelope CRC + header.
         Returns (header, msg, payload)."""
         msg = serde.dumps(packet)
         if self.compress_threshold > 0:
@@ -367,9 +365,8 @@ class Connection(asyncio.BufferedProtocol):
                 # each attempt an immediate send when the transport buffer
                 # is empty, tripling the syscall count per frame (profiled
                 # at ~30% of client CPU on the multi-process path).  Big
-                # payloads, and forwarded RX buffers (a bytearray, or the
-                # native pump's memoryview), are worth a copy-free second
-                # write.
+                # payloads, and forwarded RX buffers (a bytearray or a
+                # memoryview), are worth a copy-free second write.
                 if payload and (len(payload) > self.BULK_BYTES
                                 or not isinstance(payload, bytes)):
                     self.transport.write(head + msg)
@@ -648,12 +645,11 @@ class Connection(asyncio.BufferedProtocol):
             self._spawn(self.close(), f"close-{self.name}")
 
     def _dispatch_packet(self, packet: MessagePacket, payload: bytes,
-                         head_ts: float = 0.0) -> None:
-        """Post-decode dispatch shared by the asyncio reader and the
-        native-pump path: spawn the handler for requests (stamping the
-        receive time), wake the waiter for responses.  head_ts is when
-        the reader had the frame's header (0: the native pump, which
-        reads frames off the loop)."""
+                         head_ts: float) -> None:
+        """Post-decode dispatch, shared by the in-line and the off-loop
+        verify: spawn the handler for requests (stamping the receive
+        time), wake the waiter for responses.  head_ts is when the reader
+        had the frame's header."""
         if packet.is_req:
             self._spawn(self._handle_request(packet, payload, time.time(),
                                              head_ts),
@@ -664,10 +660,9 @@ class Connection(asyncio.BufferedProtocol):
                 fut.set_result((packet, payload))
 
     async def _handle_request(self, packet: MessagePacket, payload: bytes,
-                              recv_ts: float = 0.0,
-                              head_ts: float = 0.0) -> None:
+                              recv_ts: float, head_ts: float) -> None:
         rsp = MessagePacket(uuid=packet.uuid, method=packet.method, is_req=False)
-        rsp.ts_server_received = recv_ts or time.time()
+        rsp.ts_server_received = recv_ts
         rsp.ts_server_started = time.time()   # gap = server-side queueing
         rsp_payload = b""
         handler = self.dispatcher.get(packet.method)
@@ -682,8 +677,7 @@ class Connection(asyncio.BufferedProtocol):
                 addr=self.local_address,
                 wire_s=max(0.0, rsp.ts_server_received - packet.ts_client_called),
                 queue_s=rsp.ts_server_started - rsp.ts_server_received)
-            if head_ts:
-                scope.span.set_tag("frame_s", rsp.ts_server_received - head_ts)
+            scope.span.set_tag("frame_s", rsp.ts_server_received - head_ts)
         else:
             scope = tracing.server_scope(packet.method, 0, 0)   # no-op
         with scope as sp:

@@ -187,11 +187,12 @@ class BufferRegistry:
     @rpc_method
     async def read(self, body: RemoteBuf, payload: bytes, conn):
         """Peer pulls bytes from our registered buffer (RDMA READ analog).
-        The VIEW ships directly — on the native transport the pump pins
-        it and sends from the registered memory without a staging copy
-        (send-from-pool, r4 verdict missing #3); concurrent mutation of
-        the region during the pull is the caller's race to manage,
-        exactly as with a real one-sided READ."""
+        The VIEW ships directly, without a staging copy: the connection
+        writes a payload that is not bytes apart from the frame's header,
+        and one of THREAD_BYTES or more leaves from the registered memory
+        on its tx mover; concurrent mutation of the region during the
+        pull is the caller's race to manage, exactly as with a real
+        one-sided READ."""
         return None, self.local_view(body)
 
     @rpc_method
@@ -211,8 +212,7 @@ class BufferRegistry:
 
         WRITE regions scatter straight from the frame payload into the
         registered (arena / pool) memory as memoryview slices — no per-IO
-        staging bytes; on the native transport the frame payload itself is
-        a pump-buffer view, so the path is copy-free end to end.  Per-op
+        staging bytes.  Per-op
         failures (stale rkey, bounds, unknown buf) are result codes; the
         frame only fails as a whole for a malformed payload length."""
         descs = unpack_buf_descs(body.descs)

@@ -1,27 +1,30 @@
-"""Fused Pallas TPU kernels for the codec hot path (CRC32C + RS encode/decode).
+"""Pallas TPU kernels of the codec hot path: RS encode/decode, repair and
+CRC32C on word-packed chunk bytes.
 
-Why: the portable XLA path (jax_codec.py) materializes the 8x bit-plane
-expansion in HBM and pays lane-padding on the tiny (64->16) RS matmul —
-measured ~10 GB/s on v5e.  These kernels unpack bits **in VMEM** and feed the
-MXU bf16 matmuls directly, so HBM traffic is just bytes-in/bytes-out:
+Chunk bytes stay packed four to a uint32 lane (the little-endian
+``.view(np.uint32)`` of the byte shards) from HBM through VMEM and back:
 
-  rs_encode:  read (k, T) data bytes -> bit planes (8k, T) in VMEM ->
-              Bt @ bits matmul -> mod 2 -> packed (m, T) parity bytes out.
-  crc_seg:    read (R, B) segment rows -> plane-major bits (R, 8B) in VMEM ->
-              bits @ Lseg matmul -> mod 2 -> (R, 32) segment CRCs out.
-              (per-segment position weighting happens in a tiny XLA einsum
-              with the combine stack, exactly as in jax_codec.make_crc32c_raw)
+  gf_words:    a constant GF(2^8) matrix (an RS code's parity rows to
+               encode, a reconstruction matrix to decode) applied at every
+               word position as a host-scheduled SWAR xtimes/XOR program
+               (repair_program.schedule_matrix_program), about 2 VPU ops
+               per byte; `rs_encode_words` and `rs_decode_words`.
+  repair:      one scheduled repair row over h helper sub-shards (a Horner
+               ladder over bit planes; all-ones rows are a pure XOR fold).
+  crc_words:   512-byte segments as 128-word rows; bit (c, b) of each lane
+               feeds one small int8 (R, 128) @ (128, 32) matmul whose weight
+               slice is the segment matrix rows 8*(4w+c)+b; one more matmul
+               combines the segment CRCs (`crc32c_seg_words`).
+  fused steps: encode + CRC of every shard (the write path), decode + CRC
+               of survivors and rebuilt shards (degraded reads), repair +
+               CRC of the rebuilt sub-shard, each one device program.
 
-Plane-major trick: instead of interleaving bits LSB-first per byte (index
-j*8+b, which needs an in-VMEM transpose), we stack whole planes (index
-b*J+j) and permute the constant matrix rows on the host to match.  The 0/1
-matmuls run in bf16 with f32 accumulation — sums are bounded by K (<= 8192)
-so f32 accumulation is exact; mod 2 recovers the GF(2) result.
-
-Matrix conventions come from rs.RSCode.parity_bitmatrix (8k, 8m) and
-Crc32cMatrix.segment_matrix (8B, 32); cf. reference CPU analog
-folly::crc32c at src/fbs/storage/Common.h:158 (the reference has no RS
-data path at all — SURVEY.md preamble).
+Off the TPU, and for lengths these kernels cannot take, callers use the
+XLA bit-matmul programs of jax_codec.py; rs.RSCode and crc32c_ref are the
+oracles.  Matrix conventions come from rs.RSCode and
+Crc32cMatrix.segment_matrix; cf. reference CPU analog folly::crc32c at
+src/fbs/storage/Common.h:158 (the reference has no RS data path at all —
+SURVEY.md preamble).
 """
 
 from __future__ import annotations
@@ -38,7 +41,6 @@ from t3fs.ops.crc32c import default_matrices
 from t3fs.ops.repair_program import MatrixProgram, schedule_matrix_program
 from t3fs.ops.rs import RSCode, default_rs, rs_code, stored_code
 
-DEFAULT_SEG_BYTES = 512
 # word-kernel blocks (uint32 words per grid cell) of the fused steps; each
 # is capped to a divisor of the chunk.  benchmarks/codec_block_sweep.py
 # measures the alternatives on the chip (PERF.md, PR 21)
@@ -50,195 +52,14 @@ REPAIR_BLOCK_W = 131072
 VMEM_BLOCK_BYTES = 10 << 20
 
 
-def _plane_major_perm(nbytes: int) -> np.ndarray:
-    """Permutation p with p[b*nbytes + j] = j*8 + b (plane-major -> LSB-first)."""
-    b, j = np.meshgrid(np.arange(8), np.arange(nbytes), indexing="ij")
-    return (j * 8 + b).reshape(-1)
-
-
-def _unpack_planes(x: jax.Array) -> jax.Array:
-    """int32 (R, T) 0..255 -> bf16 bit planes (8R, T), index b*R + r."""
-    planes = [(x >> b) & 1 for b in range(8)]
-    out = jnp.concatenate(planes, axis=0)
-    return out.astype(jnp.bfloat16)
-
-
-# --- RS encode kernel -------------------------------------------------------
-
-def _rs_kernel(x_ref, bt_ref, out_ref, *, k: int, m: int):
-    x = x_ref[0].astype(jnp.int32)                       # (k, T)
-    bits = _unpack_planes(x)                             # (8k, T) bf16, b*k+i
-    acc = jax.lax.dot_general(
-        bt_ref[...], bits, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (8m, T)
-    pbits = acc.astype(jnp.int32) & 1                    # (8m, T), b*m+j
-    T = x.shape[-1]
-    pb = pbits.reshape(8, m, T)
-    out = jnp.zeros((m, T), dtype=jnp.int32)
-    for b in range(8):
-        out = out | (pb[b] << b)
-    out_ref[0] = out.astype(jnp.uint8)
-
-
-def make_rs_encode_pallas(rs: RSCode | None = None, block_t: int = 32768,
-                          interpret: bool = False):
-    """(n, k, L) uint8 -> (n, m, L) uint8 parity; L % block_t == 0."""
-    rs = rs or default_rs()
-    k, m = rs.k, rs.m
-    # parity_bitmatrix is (8k, 8m) with LSB-first interleaved indices on both
-    # sides; permute both to plane-major and transpose -> (8m, 8k).
-    pk = _plane_major_perm(k)
-    pm = _plane_major_perm(m)
-    Bt = rs.parity_bitmatrix[np.ix_(pk, pm)].T.astype(np.float32)
-    Btj = jnp.asarray(Bt, dtype=jnp.bfloat16)
-
-    def encode(data: jax.Array) -> jax.Array:
-        n, kk, L = data.shape
-        assert kk == k and L % block_t == 0, (data.shape, block_t)
-        grid = (n, L // block_t)
-        return pl.pallas_call(
-            functools.partial(_rs_kernel, k=k, m=m),
-            out_shape=jax.ShapeDtypeStruct((n, m, L), jnp.uint8),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, k, block_t), lambda i, j: (i, 0, j)),
-                pl.BlockSpec((8 * m, 8 * k), lambda i, j: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, m, block_t), lambda i, j: (i, 0, j)),
-            interpret=interpret,
-        )(data, Btj)
-
-    return encode
-
-
-# --- CRC segment kernel -----------------------------------------------------
-
-def _crc_seg_kernel(x_ref, l_ref, out_ref):
-    x = x_ref[...].astype(jnp.int32)                     # (R, B)
-    R, B = x.shape
-    bits = _unpack_planes(x)                             # (8R, B) -> want (R, 8B)
-    # plane-major per ROW: rearrange (8, R, B) -> (R, 8, B) -> (R, 8B)
-    bits = bits.reshape(8, R, B).swapaxes(0, 1).reshape(R, 8 * B)
-    acc = jax.lax.dot_general(
-        bits, l_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (R, 32)
-    out_ref[...] = acc.astype(jnp.int32) & 1
-
-
-def _norm_block_r(block_r: int) -> int:
-    """Mosaic requires the second-minor block dim be a multiple of the 8-row
-    sublane granule (or equal the array dim); interpret mode accepted any
-    value, which hid this until the first real-hardware run (r5).  Round up
-    so tiny test/bench block sizes still compile on the chip."""
-    return -(-block_r // 8) * 8
-
-
-def make_crc_seg_pallas(seg_bytes: int = DEFAULT_SEG_BYTES, block_r: int = 256,
-                        interpret: bool = False):
-    """(R, seg_bytes) uint8 segment rows -> (R, 32) int32 0/1 raw segment CRCs.
-
-    block_r is rounded up to a multiple of 8 (_norm_block_r); R must be a
-    multiple of the NORMALIZED block_r — callers that pad should run their
-    block_r through _norm_block_r first (the assembled wrappers below do).
-    CRC of a zero row is 0, so padding is harmless to downstream combines."""
-    block_r = _norm_block_r(block_r)
-    mats = default_matrices()
-    Lseg = mats.segment_matrix(seg_bytes)                 # (8B, 32) LSB-first
-    perm = _plane_major_perm(seg_bytes)
-    Lp = jnp.asarray(Lseg[perm].astype(np.float32), dtype=jnp.bfloat16)
-
-    def seg_crc(rows: jax.Array) -> jax.Array:
-        R, B = rows.shape
-        assert B == seg_bytes and R % block_r == 0, (rows.shape, block_r)
-        return pl.pallas_call(
-            _crc_seg_kernel,
-            out_shape=jax.ShapeDtypeStruct((R, 32), jnp.int32),
-            grid=(R // block_r,),
-            in_specs=[
-                pl.BlockSpec((block_r, seg_bytes), lambda i: (i, 0)),
-                pl.BlockSpec((8 * seg_bytes, 32), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_r, 32), lambda i: (i, 0)),
-            interpret=interpret,
-        )(rows, Lp)
-
-    return seg_crc
-
-
-# --- assembled fast paths ---------------------------------------------------
-
-def make_crc32c_raw_fast(padded_len: int, seg_bytes: int = DEFAULT_SEG_BYTES,
-                         block_r: int = 256, interpret: bool = False):
-    """Drop-in for jax_codec.make_crc32c_raw: (n, padded_len) uint8 ->
-    (n, 32) int32 0/1 raw CRC, but with the segment stage in Pallas."""
-    assert padded_len % seg_bytes == 0
-    block_r = _norm_block_r(block_r)
-    nseg = padded_len // seg_bytes
-    mats = default_matrices()
-    Pj = jnp.asarray(mats.combine_stack(nseg, seg_bytes).astype(np.int32))
-    seg = make_crc_seg_pallas(seg_bytes, block_r, interpret)
-
-    def raw(chunks: jax.Array) -> jax.Array:
-        n = chunks.shape[0]
-        rows = chunks.reshape(n * nseg, seg_bytes)
-        R = rows.shape[0]
-        pad = (-R) % block_r
-        if pad:
-            rows = jnp.pad(rows, ((0, pad), (0, 0)))
-        seg_crc = seg(rows)[:R].reshape(n, nseg, 32)
-        return jnp.einsum("skl,nsl->nk", Pj, seg_crc) & 1
-
-    return raw
-
-
-def make_stripe_encode_step_fast(chunk_len: int, k: int = 8, m: int = 2,
-                                 seg_bytes: int = DEFAULT_SEG_BYTES,
-                                 interpret: bool = False):
-    """Pallas-backed version of jax_codec.make_stripe_encode_step:
-    (n, k, chunk_len) uint8 -> parity (n, m, chunk_len), crcs (n, k+m) uint32.
-
-    CRCs the data and parity shards separately (same kernel) instead of
-    concatenating the 80 MiB byte tensor — saves a full HBM round trip."""
-    from t3fs.ops.jax_codec import pack_bits_u32
-
-    assert chunk_len % seg_bytes == 0
-    rs = default_rs(k, m)
-    block_t = min(32768, chunk_len)
-    rs_enc = make_rs_encode_pallas(rs, block_t=block_t, interpret=interpret)
-    raw = make_crc32c_raw_fast(chunk_len, seg_bytes, interpret=interpret)
-    affine = np.uint32(default_matrices().affine_const(chunk_len))
-
-    def step(stripes: jax.Array):
-        n = stripes.shape[0]
-        parity = rs_enc(stripes)
-        dcrc = pack_bits_u32(raw(stripes.reshape(n * k, chunk_len))) ^ affine
-        pcrc = pack_bits_u32(raw(parity.reshape(n * m, chunk_len))) ^ affine
-        crcs = jnp.concatenate(
-            [dcrc.reshape(n, k), pcrc.reshape(n, m)], axis=1)
-        return parity, crcs
-
-    return step
-
-
-# --- word-packed kernels (the shipping fast path) ---------------------------
+# --- word-packed GF(2^8) matrix and CRC kernels ----------------------------
 #
-# The byte-plane kernels above are VPU-bound: ~24 vector ops per byte just to
-# unpack bits (plus relayouts), measured ~8-16 GB/s on v5e.  The word path
-# keeps chunk bytes packed 4-per-lane as uint32:
-#
-#   gf_words:       a constant GF(2^8) matrix (parity rows to encode, a
-#                   reconstruction matrix to decode) as a host-scheduled
-#                   xtimes/XOR program, all SWAR on uint32 lanes -> ~2 VPU
-#                   ops/byte (vs 24); for RAID-6 P is an XOR fold and Q a
-#                   Horner fold in g.  Inside a kernel, so no XLA bitcast
-#                   relayout (which pins the XLA version to ~6 GB/s in
-#                   HBM), and degraded reads and repair run at encode-class
-#                   rates instead of the byte-plane kernel's 8-16 GB/s.
-#   crc_words:      segments are 128-word rows; bit (c,b) of each word lane
-#                   feeds one of 32 small (R,128)@(128,32) bf16 matmuls whose
-#                   weight slice is the segment matrix rows 8*(4w+c)+b.  No
-#                   transposes, no concat: extract -> MXU -> accumulate.
-#                   f32 accumulation is exact (counts <= 4096 < 2^24).
+#   gf_words:       all SWAR on uint32 lanes, ~2 VPU ops/byte; for RAID-6 P
+#                   is an XOR fold and Q a Horner fold in g.  Inside a
+#                   kernel, so no XLA bitcast relayout (which pins the XLA
+#                   version to ~6 GB/s in HBM).
+#   crc_words:      no transposes, no concat: extract -> MXU -> accumulate.
+#                   int32 accumulation is exact (counts <= 128).
 #
 # Combine across segments is ONE bf16 matmul (n, S*32) @ (S*32, 32) built from
 # the combine stack — counts <= S*32 < 2^24 so f32 accumulation stays exact.
@@ -354,6 +175,14 @@ def make_rs_encode_words_pallas(rs: RSCode | None = None, block_w: int = 16384,
     rs = rs or default_rs()
     return _make_gf_words_call(rs, rs.parity_rows, block_w, interpret,
                                "rs_encode_words")
+
+
+def _norm_block_r(block_r: int) -> int:
+    """Mosaic requires the second-minor block dim be a multiple of the 8-row
+    sublane granule (or equal the array dim); interpret mode accepts any
+    value.  Round up so tiny test/bench block sizes still compile on the
+    chip."""
+    return -(-block_r // 8) * 8
 
 
 def _crc_words_kernel(x_ref, m_ref, out_ref):
@@ -513,41 +342,6 @@ def make_stripe_encode_step_words(chunk_words: int, k: int = 8, m: int = 2,
     return step
 
 
-def make_rs_reconstruct_pallas(present: tuple[int, ...], want: tuple[int, ...],
-                               rs: RSCode | None = None, block_t: int = 32768,
-                               interpret: bool = False):
-    """(n, k, L) uint8 present shards -> (n, |want|, L); Pallas analog of
-    jax_codec.make_rs_reconstruct (decode = same bit-matmul, different matrix).
-
-    This is the byte-plane DECODE FALLBACK for lengths that are no whole
-    number of words: it pays the ~24-vector-ops-per-byte bit unpack.  Every
-    other length decodes through make_rs_reconstruct_words_pallas below,
-    which stays word-packed."""
-    rs = rs or default_rs()
-    k, w = rs.k, len(want)
-    W = rs.reconstruct_bitmatrix(list(present), list(want))   # (8k, 8w)
-    pk = _plane_major_perm(k)
-    pw = _plane_major_perm(w)
-    Wt = jnp.asarray(W[np.ix_(pk, pw)].T.astype(np.float32), dtype=jnp.bfloat16)
-
-    def reconstruct(shards: jax.Array) -> jax.Array:
-        n, kk, L = shards.shape
-        assert kk == k and L % block_t == 0, (shards.shape, block_t)
-        return pl.pallas_call(
-            functools.partial(_rs_kernel, k=k, m=w),
-            out_shape=jax.ShapeDtypeStruct((n, w, L), jnp.uint8),
-            grid=(n, L // block_t),
-            in_specs=[
-                pl.BlockSpec((1, k, block_t), lambda i, j: (i, 0, j)),
-                pl.BlockSpec((8 * w, 8 * k), lambda i, j: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, w, block_t), lambda i, j: (i, 0, j)),
-            interpret=interpret,
-        )(shards, Wt)
-
-    return reconstruct
-
-
 # --- word-packed reconstruct (the decode-side analog of the word encode) ----
 #
 # Decode coefficients are GF(2^8) constants from RSCode.reconstruct_gfmatrix,
@@ -556,8 +350,7 @@ def make_rs_reconstruct_pallas(present: tuple[int, ...], want: tuple[int, ...],
 # the same scheduled program as the encode (_eval_gf_words): a bit-plane
 # Horner fold per rebuilt shard, or one xtimes ladder per survivor shared by
 # every rebuilt shard, whichever issues fewer ops — the same ~2 VPU ops per
-# byte regime as the encode kernel, vs ~24 for the byte-plane unpack.  The
-# program is built host-side per (present, want) pattern; the kernel is
+# byte regime as the encode kernel.  The program is built host-side per (present, want) pattern; the kernel is
 # fully unrolled with the constants baked in.
 
 

@@ -71,8 +71,8 @@ def _bind():
     lib.t3fs_ce_punch_freed.argtypes = [C.c_void_p, C.c_uint64]
     lib.t3fs_crc32c.restype = C.c_uint32
     # c_void_p, not c_char_p: accepts bytes AND ctypes views over
-    # writable buffers, so zero-copy RX payloads (memoryview over the
-    # net pump's buffer) CRC without a copy
+    # writable buffers, so RX payloads (an RX frame's bytearray, a
+    # memoryview slice of it) CRC without a copy
     lib.t3fs_crc32c.argtypes = [C.c_void_p, C.c_uint64, C.c_uint32]
     lib.t3fs_crc32c_combine.restype = C.c_uint32
     lib.t3fs_crc32c_combine.argtypes = [C.c_uint32, C.c_uint32, C.c_uint64]
@@ -90,9 +90,9 @@ def native_lib():
 
 def _c_buffer(data) -> tuple[object, int]:
     """(c_void_p argument, length) for any bytes-like input: bytes and
-    writable buffers (an RX frame's bytearray, the net pump's zero-copy
-    memoryviews) pass WITHOUT a staging copy; only a readonly view that
-    is not bytes is copied."""
+    writable buffers (an RX frame's bytearray, memoryviews over one)
+    pass WITHOUT a staging copy; only a readonly view that is not bytes
+    is copied."""
     if isinstance(data, bytes):
         return data, len(data)
     mv = data if isinstance(data, memoryview) else memoryview(data)

@@ -80,14 +80,14 @@ def test_ec_write_read_roundtrip_and_reconstruct(monkeypatch):
             # encode+CRC for the writes (stored CRCs ride along as
             # write_chunk checksums), the FUSED word decode+verify for the
             # degraded read (the EC client previously used
-            # the slow XLA path while bench.py measured the word kernels;
-            # the byte-plane bit-matmul is left for lengths that are no
-            # whole number of words)
+            # the slow XLA path while bench.py measured the word kernels),
+            # and no other codec
             assert ec.codec.codec_counts.get("pallas-encode-words", 0) >= 1, \
                 ec.codec.codec_counts
             assert ec.codec.codec_counts.get("pallas-decode-words", 0) >= 1, \
                 ec.codec.codec_counts
-            assert "pallas-bitmatmul" not in ec.codec.codec_counts, \
+            assert set(ec.codec.codec_counts) == {
+                "pallas-encode-words", "pallas-decode-words"}, \
                 ec.codec.codec_counts
             await ec.close()
         finally:

@@ -16,7 +16,7 @@ import pytest
 
 from t3fs.client.ec_codec import ECCodec
 from t3fs.ops.crc32c import crc32c_ref
-from t3fs.ops.rs import RSCode, default_rs
+from t3fs.ops.rs import RSCode, default_rs, rs_code, stored_code
 
 rng = np.random.default_rng(13)
 
@@ -73,7 +73,7 @@ def test_ec_codec_end_to_end_fused_decode(interpret_env):
 
 def test_ec_codec_plain_reconstruct_word_path(interpret_env):
     """reconstruct() (no CRCs) routes through the word SWAR kernel on
-    RAID-6 — 'pallas-rec-words', never the byte-plane bit-matmul."""
+    RAID-6 — 'pallas-rec-words' and no other codec."""
     k, m, L = 8, 2, 1024
     rs = default_rs(k, m)
     data = rng.integers(0, 256, (k, L), dtype=np.uint8)
@@ -88,18 +88,17 @@ def test_ec_codec_plain_reconstruct_word_path(interpret_env):
                                           lost, k, m)
             for j, s in enumerate(lost):
                 assert np.array_equal(out[j], full[s])
-            assert codec.codec_counts.get("pallas-rec-words", 0) >= 1
-            assert "pallas-bitmatmul" not in codec.codec_counts
+            assert set(codec.codec_counts) == {"pallas-rec-words"}, \
+                codec.codec_counts
         finally:
             await codec.close()
 
     asyncio.run(body())
 
 
-def test_ec_codec_non_raid6_byteplane_fallback(interpret_env):
+def test_ec_codec_non_raid6_reconstruct_word_path(interpret_env):
     """k=4, m=3 is not RAID-6, and its layouts store rrvand-11d: decode
-    runs the word kernel (it serves every code), never the byte-plane
-    bit-matmul, which is left for lengths that are no whole words."""
+    runs the word kernel, which serves every code."""
     k, m, L = 4, 3, 512
     rs = RSCode(k, m)
     data = rng.integers(0, 256, (k, L), dtype=np.uint8)
@@ -114,8 +113,55 @@ def test_ec_codec_non_raid6_byteplane_fallback(interpret_env):
                                           lost, k, m)
             for j, s in enumerate(lost):
                 assert np.array_equal(out[j], full[s])
-            assert codec.codec_counts.get("pallas-rec-words", 0) >= 1
-            assert "pallas-bitmatmul" not in codec.codec_counts
+            assert set(codec.codec_counts) == {"pallas-rec-words"}, \
+                codec.codec_counts
+        finally:
+            await codec.close()
+
+    asyncio.run(body())
+
+
+@pytest.mark.parametrize("k,m,code", [(8, 2, None), (10, 4, "cauchy-11d")],
+                         ids=["rs8p2", "rs10p4-cauchy"])
+@pytest.mark.parametrize("op", ["encode", "reconstruct", "encode_verified",
+                                "reconstruct_verified"])
+def test_odd_length_ops_take_the_xla_program(interpret_env, op, k, m, code):
+    """A length that is no whole number of words (L = 1022) takes the XLA
+    bit-matmul program in every operation, also on a codec that runs the
+    Pallas word kernels: bytes and CRCs equal the oracles', and no Pallas
+    codec served the call."""
+    L = 1022
+    rs = rs_code(k, m, code or stored_code(k, m))
+    data = rng.integers(0, 256, (k, L), dtype=np.uint8)
+    full = np.concatenate([data, rs.encode_ref(data)], axis=0)
+    lost = (1, k + m - 1) if m == 2 else (0, 3, k, k + m - 1)
+    present = tuple(i for i in range(k + m) if i not in lost)[:k]
+
+    def crcs_of(slots) -> list[int]:
+        return [crc32c_ref(full[s].tobytes()) for s in slots]
+
+    async def body():
+        codec = ECCodec()
+        try:
+            if op == "encode":
+                parity = await codec.encode(data, k, m, code)
+                assert np.array_equal(parity, full[k:])
+            elif op == "encode_verified":
+                parity, crcs = await codec.encode_verified(data, k, m, code)
+                assert np.array_equal(parity, full[k:])
+                assert [int(c) for c in crcs] == crcs_of(range(k + m))
+            elif op == "reconstruct":
+                out = await codec.reconstruct(full[list(present)], present,
+                                              lost, k, m, code)
+                assert np.array_equal(out, full[list(lost)])
+            else:
+                out, crcs = await codec.reconstruct_verified(
+                    full[list(present)], present, lost, k, m, code)
+                assert np.array_equal(out, full[list(lost)])
+                assert [int(c) for c in crcs] == crcs_of(present + lost)
+            assert codec._use_pallas                 # the env took effect
+            assert set(codec.codec_counts) == {"xla-bitmatmul"}, \
+                codec.codec_counts
         finally:
             await codec.close()
 

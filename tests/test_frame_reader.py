@@ -64,7 +64,7 @@ class _Rx(Connection):
         super().__init__(name="rx")
         self.got: list[tuple[str, object, object]] = []
 
-    def _dispatch_packet(self, packet, payload, head_ts=0.0):
+    def _dispatch_packet(self, packet, payload, head_ts):
         self.got.append((packet.method, packet.body, payload))
 
 

@@ -3,11 +3,15 @@ emulation (reference analogs: tests/common/net/TestEcho.cc, TestProcessor.cc,
 tests/common/net/ib/TestRDMA.cc)."""
 
 import asyncio
+import os
 from dataclasses import dataclass
 
 import pytest
 
+from t3fs.native import load_library
 from t3fs.net import Server, Client, rpc_method, service
+from t3fs.net import conn as conn_mod
+from t3fs.net.conn import Connection
 from t3fs.net.rdma import BufferRegistry, RemoteBuf, remote_read, remote_write
 from t3fs.utils.serde import serde_struct
 from t3fs.utils.status import StatusCode, StatusError, make_error
@@ -141,6 +145,102 @@ def test_remote_buf_write_back(loop_run):
             handle = bufs.register(8)
             await client.call(server.address, "Pusher.push", handle)
             assert bytes(bufs.local_view(handle)) == b"X" * 8
+        finally:
+            await client.close()
+            await server.stop()
+    loop_run(body())
+
+
+def test_concurrent_calls_share_one_connection(loop_run):
+    """200 concurrent 64 KiB echoes multiplex one client connection, and
+    each caller gets its own reply back."""
+    async def body(server, client):
+        payloads = [os.urandom(64 << 10) for _ in range(200)]
+
+        async def one(i):
+            rsp, payload = await client.call(server.address, "Echo.echo",
+                                             NetEchoReq(n=i),
+                                             payload=payloads[i])
+            assert rsp.n == i + 1 and payload == payloads[i]
+        await asyncio.gather(*[one(i) for i in range(len(payloads))])
+        assert len(client._conns) == 1 and len(server._conns) == 1
+        assert client.epoch(server.address) == 1
+    loop_run(_with_cluster(body))
+
+
+def test_call_after_server_restart_reconnects(loop_run):
+    """Stopping the server fails a call in flight with a transport status
+    and a call made while it is down with a connect failure; the next call
+    reconnects to a server revived on the same port."""
+    async def body():
+        server = Server()
+        server.add_service(EchoService())
+        await server.start()
+        address = server.address
+        client = Client(connect_timeout=2.0)
+        revived = None
+        try:
+            rsp, _ = await client.call(address, "Echo.echo", NetEchoReq(n=0))
+            assert rsp.n == 1
+            in_flight = asyncio.ensure_future(
+                client.call(address, "Echo.slow", timeout=10.0))
+            await asyncio.sleep(0.1)
+            await server.stop()
+            with pytest.raises(StatusError) as ei:
+                await in_flight
+            assert ei.value.code == StatusCode.RPC_SEND_FAILED
+            with pytest.raises(StatusError) as ei:
+                await client.call(address, "Echo.echo", NetEchoReq(n=0))
+            assert ei.value.code == StatusCode.RPC_CONNECT_FAILED
+            host, port = address.rsplit(":", 1)
+            revived = Server(host=host, port=int(port))
+            revived.add_service(EchoService())
+            await revived.start()
+            rsp, _ = await client.call(address, "Echo.echo", NetEchoReq(n=7))
+            assert rsp.n == 8
+            assert client.epoch(address) == 2
+        finally:
+            await client.close()
+            if revived is not None:
+                await revived.stop()
+    loop_run(body())
+
+
+@pytest.mark.parametrize("n", [4 << 10, Connection.THREAD_BYTES, 4 << 20],
+                         ids=["4KiB", "THREAD_BYTES", "4MiB"])
+def test_remote_buf_read_write_sizes(loop_run, n):
+    """remote_read and remote_write move the exact bytes of a region; from
+    THREAD_BYTES on, each payload leaves on its connection's tx mover, and
+    a payload far past the staged prefix comes in on an rx mover."""
+    load_library()          # movers run in the native library
+
+    def thread_bodies() -> tuple[int, int]:
+        return (conn_mod.RX_THREAD_BODIES._value,
+                conn_mod.TX_THREAD_BODIES._value)
+
+    async def body():
+        reg = BufferRegistry()
+        server = Server()
+        server.add_service(reg)
+        await server.start()
+        client = Client()
+        try:
+            data = os.urandom(n)
+            conn = await client._get_conn(server.address)
+            rx0, tx0 = thread_bodies()
+            got = await remote_read(conn, reg.register(data))
+            assert bytes(got) == data
+            handle = reg.register(n)
+            back = bytes(reversed(data))
+            await remote_write(conn, handle, back)
+            assert bytes(reg.local_view(handle)) == back
+            rx, tx = thread_bodies()
+            if n < Connection.THREAD_BYTES:
+                assert (rx - rx0, tx - tx0) == (0, 0)
+            else:
+                assert tx - tx0 == 2
+            if n >= 2 * Connection.THREAD_BYTES:
+                assert rx - rx0 == 2
         finally:
             await client.close()
             await server.stop()
